@@ -5,87 +5,16 @@ retired-name mentions in CHANGES.md, the direct wall-clock reads in the
 serving modules that own the clock seam) so the engines themselves stay
 allowlist-free: a rule reports everything it sees, and the config is
 the single audited place where exceptions live.
-
-Python 3.10 (the CI floor) has no ``tomllib``, and this repo adds no
-dependencies, so a minimal TOML-subset parser backs it up.  The subset
-is exactly what ``analysis.toml`` uses: ``[dotted.section]`` headers,
-``key = "string"``, ``key = ["list", "of", "strings"]``, ``key = 123``,
-``key = true/false``, and ``#`` comments.
 """
 
 from __future__ import annotations
 
 import fnmatch
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 
-try:
-    import tomllib  # Python >= 3.11
-except ModuleNotFoundError:  # pragma: no cover - exercised on the 3.10 CI leg
-    tomllib = None
-
 CONFIG_NAME = "analysis.toml"
-
-
-def _parse_toml_subset(text: str) -> dict:
-    """Parse the TOML subset ``analysis.toml`` is written in.
-
-    Fallback for Python 3.10 where ``tomllib`` is absent; intentionally
-    strict — anything outside the subset raises so a config typo fails
-    the analysis run instead of silently allowlisting nothing.
-    """
-    root: dict = {}
-    table = root
-    pending: tuple[str, int, list[str]] | None = None  # multi-line array
-    for lineno, raw in enumerate(text.splitlines(), start=1):
-        line = raw.split("#", 1)[0].strip() if not raw.strip().startswith("#") \
-            else ""
-        if not line:
-            continue
-        if pending is not None:
-            key, start, parts = pending
-            parts.append(line)
-            if line.endswith("]"):
-                table[key] = _parse_value(" ".join(parts), start)
-                pending = None
-            continue
-        if line.startswith("[") and line.endswith("]"):
-            table = root
-            for part in line[1:-1].strip().split("."):
-                table = table.setdefault(part.strip(), {})
-            continue
-        if "=" not in line:
-            raise ValueError(f"{CONFIG_NAME}:{lineno}: not key = value: {raw!r}")
-        key, _, value = line.partition("=")
-        key, value = key.strip(), value.strip()
-        if value.startswith("[") and not value.endswith("]"):
-            pending = (key, lineno, [value])
-            continue
-        table[key] = _parse_value(value, lineno)
-    if pending is not None:
-        raise ValueError(
-            f"{CONFIG_NAME}:{pending[1]}: unterminated array for "
-            f"{pending[0]!r}")
-    return root
-
-
-def _parse_value(value: str, lineno: int):
-    if value.startswith("[") and value.endswith("]"):
-        inner = value[1:-1].strip()
-        if not inner:
-            return []
-        return [_parse_value(item.strip(), lineno)
-                for item in inner.split(",") if item.strip()]
-    if value.startswith('"') and value.endswith('"') and len(value) >= 2:
-        return value[1:-1]
-    if value in ("true", "false"):
-        return value == "true"
-    try:
-        return int(value)
-    except ValueError:
-        raise ValueError(
-            f"{CONFIG_NAME}:{lineno}: unsupported value {value!r} "
-            "(subset: string, int, bool, list of those)") from None
 
 
 @dataclass
@@ -106,11 +35,7 @@ class AnalysisConfig:
         path = Path(root) / CONFIG_NAME
         if not path.is_file():
             return cls()
-        text = path.read_text(encoding="utf-8")
-        if tomllib is not None:
-            data = tomllib.loads(text)
-        else:
-            data = _parse_toml_subset(text)
+        data = tomllib.loads(path.read_text(encoding="utf-8"))
         allow: dict[str, list[str]] = {}
         options: dict[str, dict] = {}
         for rule, table in data.get("rules", {}).items():

@@ -66,9 +66,8 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(val):
-    import jax.core as jcore
-    closed = getattr(jcore, "ClosedJaxpr", ())
-    if isinstance(val, closed):
+    from jax.extend import core as jcore
+    if isinstance(val, jcore.ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, jcore.Jaxpr):
         yield val
@@ -97,9 +96,15 @@ def _aval_bytes(aval) -> int:
     return int(np.prod(aval.shape, dtype=np.int64)) * aval.dtype.itemsize
 
 
+def _dim(d) -> int:
+    """A BlockSpec block dimension as an int (JAX wraps them in
+    ``Blocked``, which carries ``block_size``)."""
+    return int(getattr(d, "block_size", d))
+
+
 def _block_bytes(bm) -> int:
-    shape = tuple(int(d) for d in bm.block_shape)
-    return int(np.prod(shape, dtype=np.int64)) * bm.array_shape_dtype.dtype.itemsize
+    shape = tuple(_dim(d) for d in bm.block_shape)
+    return int(np.prod(shape, dtype=np.int64)) * bm.array_aval.dtype.itemsize
 
 
 class TracedKernel:
@@ -126,6 +131,12 @@ class TracedKernel:
     @property
     def x_block(self):
         return self.in_blocks[0]
+
+    def batch_tile(self, model) -> int:
+        """The x block's extent on the batch axis the model names
+        (``batch_axis``: 1 for the node-major ``(N_o, B, P)`` whole-
+        network kernels, 0 — the default — for batch-major x)."""
+        return _dim(self.x_block.block_shape[model.get("batch_axis", 0)])
 
     @property
     def weight_blocks(self):
@@ -171,7 +182,7 @@ def _check_tiling(spec, batch, kernels, model):
             "the kernel wrapper and the residency_model hook have drifted; "
             "re-mirror the tuner invocation in the autotune module"))
     for k in kernels:
-        bb = int(k.x_block.block_shape[0])
+        bb = k.batch_tile(model)
         if bb != int(model["block_b"]):
             findings.append(Finding(
                 "audit-tile-mismatch", _loc(spec, batch), 0,
@@ -204,7 +215,7 @@ def _check_intermediates(spec, batch, kernels, model):
     findings = []
     per_cap = model["per_sample_bytes"] * (1 + DRIFT_TOLERANCE)
     for k in kernels:
-        bb = max(1, int(k.x_block.block_shape[0]))
+        bb = max(1, k.batch_tile(model))
         largest, largest_eqn = 0, None
         for eqn in _iter_eqns(k.kernel_jaxpr):
             for v in eqn.outvars:
@@ -267,8 +278,8 @@ def _check_int8_discipline(spec, batch, kernels):
     for k in kernels:
         int_inputs, scale_rows = [], []
         for bm in k.weight_blocks:
-            dt = bm.array_shape_dtype.dtype
-            shape = bm.array_shape_dtype.shape
+            dt = bm.array_aval.dtype
+            shape = bm.array_aval.shape
             if jnp.issubdtype(dt, jnp.integer):
                 int_inputs.append(bm)
             elif dt == jnp.float32 and len(shape) == 2 and shape[0] == 1:
@@ -298,7 +309,7 @@ def _check_int8_discipline(spec, batch, kernels):
                 f"operand, traced {len(scale_rows)} — per-tensor scales "
                 "ship as a single (1, n_tensors) fp32 input"))
             continue
-        n_scales = int(scale_rows[0].array_shape_dtype.shape[-1])
+        n_scales = int(scale_rows[0].array_aval.shape[-1])
         reads = k.scalar_f32_read_count()
         if n_scales != len(int_inputs) or reads != len(int_inputs):
             findings.append(Finding(

@@ -117,14 +117,14 @@ class PathSpec:
         """
         if self.per_sample_bytes is not None:
             return int(self.per_sample_bytes(cfg, params))
-        from repro.kernels.autotune import _SUBLANE
         from repro.kernels.fused_jedinet.autotune import (
-            full_forward_tiled_bytes_per_sample, mlp_widths)
+            full_forward_tiled_bytes_per_sample, mlp_widths,
+            sender_tile_candidates)
         return full_forward_tiled_bytes_per_sample(
             cfg.n_objects, cfg.n_features,
             mlp_widths(params["fr"]), mlp_widths(params["fo"]),
             mlp_widths(params["phi"]),
-            block_s=min(_SUBLANE, cfg.n_objects))
+            block_s=sender_tile_candidates(cfg.n_objects)[0])
 
     def reserved_vmem_bytes(self, cfg, params) -> int:
         """VMEM the path's weights occupy before any batch row arrives,

@@ -15,9 +15,20 @@ import jax.numpy as jnp
 # input/output double buffering and the broadcast weight blocks.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
-# fp32 sublane count — tiles that are a multiple of this pack the
-# (8, 128) native tile exactly when the batch axis lands on a sublane.
+# fp32 sublane count.  The JEDI kernels put the batch on the sublane
+# axis, where Mosaic accepts only blocks of whole (8, 128) tiles, so
+# every batch tile is a multiple of this.
 _SUBLANE = 8
+
+# Lane count of one vreg: a VMEM array's minor dimension occupies whole
+# 128-lane tiles, so a width-20 activation costs as much VMEM as 128.
+_LANE = 128
+
+
+def lanes(width: int) -> int:
+    """``width`` rounded up to whole 128-lane tiles — the VMEM footprint
+    of one row of a ``(rows, width)`` array."""
+    return -(-int(width) // _LANE) * _LANE
 
 
 def effective_budget(budget_bytes: int, reserved_bytes: int) -> int:
@@ -65,26 +76,26 @@ def pick_block_b(batch: int, per_sample_bytes: int,
                  budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
     """Largest useful batch tile whose working set fits the VMEM budget.
 
-    Never constrained to divide ``batch`` — pad with :func:`pad_batch`
+    Always a whole number of sublane tiles (a multiple of 8), and never
+    constrained to divide ``batch`` — pad with :func:`pad_batch`
     instead.  Three cases:
 
-    * whole batch fits the budget -> one grid step, zero padding;
+    * the batch, rounded up to a sublane tile, fits the budget -> one
+      grid step;
     * otherwise take the budget-limited grid-step count and BALANCE the
-      tile to it (``ceil(batch / steps)``), which minimizes padded rows
-      for that step count (e.g. B=256 at budget-tile 96: 3 steps of 88
-      pads 8 rows, vs 3 steps of 96 padding 32);
-    * sublane-align the balanced tile when that still fits the budget.
+      tile to it (``ceil(batch / steps)`` rounded up to a sublane tile),
+      which minimizes padded rows for that step count (e.g. B=256 at
+      budget-tile 96: 3 steps of 88 pads 8 rows, vs 3 steps of 96
+      padding 32);
+    * one sublane tile is the floor, even where it busts the budget.
     """
-    bb = max(1, min(batch, budget_bytes // max(per_sample_bytes, 1)))
-    if bb >= batch:
-        return batch
-    steps = -(-batch // bb)
-    bb = -(-batch // steps)
-    if bb > _SUBLANE:
-        aligned = -(-bb // _SUBLANE) * _SUBLANE
-        if aligned * per_sample_bytes <= budget_bytes:
-            bb = aligned
-    return bb
+    cap = max(_SUBLANE, budget_bytes // max(per_sample_bytes, 1)
+              // _SUBLANE * _SUBLANE)
+    whole = padded_batch(max(int(batch), 1), _SUBLANE)
+    if whole <= cap:
+        return whole
+    steps = -(-whole // cap)
+    return padded_batch(-(-whole // steps), _SUBLANE)
 
 
 def bucket_ladder(max_batch: int, per_sample_bytes: int,

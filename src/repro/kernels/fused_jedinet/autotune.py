@@ -21,13 +21,19 @@ Three estimators:
   accumulator is live, so the per-sample set shrinks ~``N_o/block_s``
   and ``block_b`` grows by the ratio.
 
-:func:`pick_block_b_s` searches the 2D ``(block_b, block_s)`` space:
-smaller sender tiles buy larger batch tiles (weight HBM traffic
-amortizes over more jets per step), so the picker maximizes ``block_b``
-and breaks ties toward the larger ``block_s`` (fewer sender steps, less
-remainder padding).  For batches small enough that the whole batch fits
-at every ``block_s``, the tie-break degenerates to ``block_s = N_o`` —
-the untiled kernel, with zero sender-loop overhead.
+The whole-network models bill every row at whole 128-lane tiles
+(:func:`~repro.kernels.autotune.lanes`): that is what the arrays occupy
+in VMEM, and it bounds the rows one grid step holds — which is also
+what bounds the kernel's compile time, since Mosaic emits code per vreg.
+
+:func:`pick_block_b_s` searches the 2D ``(block_b, block_s)`` space.
+Sender tiles divide N_o (:func:`sender_tile_candidates`), so no tile
+needs a clamp or a mask.  Every candidate does the same slab work in
+total; what differs is the fixed cost per grid step and the receiver
+projection each step recomputes, so the picker minimizes the number of
+grid steps and breaks ties toward the larger ``block_s``.  For batches
+small enough that the whole batch fits untiled, that is ``block_s =
+N_o`` — one sender step, no sender-loop overhead.
 """
 
 from __future__ import annotations
@@ -37,6 +43,7 @@ from repro.kernels.autotune import (  # noqa: F401
     VMEM_BUDGET_BYTES,
     _SUBLANE,
     effective_budget,
+    lanes,
     mlp_widths,
     pad_batch,
     padded_batch,
@@ -78,6 +85,13 @@ def full_forward_bytes_per_sample(n_objects: int, n_features: int,
         block_s=n_objects, acc_bytes=acc_bytes)
 
 
+#: Slab-sized values Mosaic keeps live at once along the f_R chain
+#: (pre-activation, activation, the next matmul's output, ...).  The
+#: v5e compiler's scoped-VMEM stack for this kernel measured 3.0-3.9
+#: slabs plus the other terms below (jedinet-30p, block_s 10 and 30).
+SLAB_LIVE_COPIES = 4
+
+
 def full_forward_tiled_bytes_per_sample(n_objects: int, n_features: int,
                                         fr_widths: list[int],
                                         fo_widths: list[int],
@@ -92,21 +106,22 @@ def full_forward_tiled_bytes_per_sample(n_objects: int, n_features: int,
     plus this step's sender-chunk slice, and — only after the last
     sender tile — C and the f_O / phi_O activations.  The tail
     intermediates share the budget because they coexist with the
-    accumulator and x.  ``block_s = N_o`` reproduces the untiled
-    estimate exactly.
+    accumulator and x.  Every row is billed at whole 128-lane tiles,
+    and the slab :data:`SLAB_LIVE_COPIES` times.
+    ``block_s = N_o`` reproduces the untiled estimate exactly.
     """
     n_o = n_objects
     block_s = max(1, min(int(block_s), n_o))
-    h1 = fr_widths[0]
-    slab = n_o * block_s * max(fr_widths + [_SUBLANE])
+    h1 = lanes(fr_widths[0])
+    slab = SLAB_LIVE_COPIES * n_o * block_s * lanes(max(fr_widths))
     u_r = n_o * h1
     u_s = block_s * h1
-    x_tile = n_o * n_features
-    xs_tile = block_s * n_features
-    ebar_acc = n_o * fr_widths[-1]
-    c_tile = n_o * (n_features + fr_widths[-1])
-    fo_acts = n_o * max(fo_widths + [_SUBLANE])
-    phi_acts = max(phi_widths + [_SUBLANE])
+    x_tile = n_o * lanes(n_features)
+    xs_tile = block_s * lanes(n_features)
+    ebar_acc = n_o * lanes(fr_widths[-1])
+    c_tile = n_o * lanes(n_features + fr_widths[-1])
+    fo_acts = n_o * lanes(max(fo_widths))
+    phi_acts = lanes(max(phi_widths))
     return (slab + u_r + u_s + x_tile + xs_tile + ebar_acc + c_tile
             + fo_acts + phi_acts) * acc_bytes
 
@@ -120,16 +135,11 @@ def fits_vmem(per_sample_bytes: int,
 
 
 def sender_tile_candidates(n_objects: int) -> list[int]:
-    """Sender-axis tile sizes worth searching: sublane-aligned doublings
-    (8, 16, 32, ...) strictly below N_o, plus N_o itself (the untiled
-    degenerate).  Ascending."""
-    cands = []
-    b = _SUBLANE
-    while b < n_objects:
-        cands.append(b)
-        b *= 2
-    cands.append(n_objects)
-    return cands
+    """Sender-axis tile sizes worth searching: the divisors of N_o, so
+    every sender step covers a whole tile (no clamped remainder, no
+    bounds mask).  Ascending; the last is N_o itself, the untiled
+    degenerate."""
+    return [d for d in range(1, n_objects + 1) if n_objects % d == 0]
 
 
 def pick_block_b_s(batch: int, n_objects: int, n_features: int,
@@ -141,11 +151,11 @@ def pick_block_b_s(batch: int, n_objects: int, n_features: int,
 
     For each candidate sender tile the per-sample live set is modeled
     (:func:`full_forward_tiled_bytes_per_sample`) and the shared picker
-    chooses the batch tile; the winner maximizes ``block_b`` (weight
-    traffic amortizes over the largest batch tile), ties broken toward
-    the LARGER ``block_s`` (fewer sender grid steps, less remainder
-    padding — and for small batches this degenerates to
-    ``block_s = N_o``, the untiled kernel).
+    chooses the batch tile.  The winner needs the fewest grid steps
+    (``batch tiles x sender tiles``): the slab work is the same for
+    every candidate, while each step pays a fixed cost and recomputes
+    the receiver projection.  Ties go to the LARGER ``block_s``; for
+    small batches that is ``block_s = N_o``, the untiled kernel.
 
     ``reserved_bytes`` (e.g. the weight blocks' VMEM residency,
     :func:`~repro.kernels.autotune.weight_vmem_bytes`) is subtracted
@@ -158,35 +168,33 @@ def pick_block_b_s(batch: int, n_objects: int, n_features: int,
         per = full_forward_tiled_bytes_per_sample(
             n_objects, n_features, fr_widths, fo_widths, phi_widths, bs)
         bb = pick_block_b(batch, per, budget)
-        # pick_block_b floors block_b at 1 even when ONE sample busts the
-        # budget, so a non-fitting candidate can tie with fitting ones at
-        # small batches (and the larger-block_s tie-break would then pick
-        # the very configuration fits_vmem rejects) — skip it.
-        if per > budget:
-            if fallback is None:          # smallest live set, if nothing fits
+        # pick_block_b floors block_b at one sublane tile even when that
+        # busts the budget, so a non-fitting candidate could still win on
+        # steps — skip it, keeping the smallest live set as the fallback.
+        if bb * per > budget:
+            if fallback is None:
                 fallback = (bb, bs)
             continue
-        if best is None or (bb, bs) > (best[0], best[1]):
-            best = (bb, bs)
-    return best if best is not None else fallback
+        steps = (padded_batch(batch, bb) // bb) * (n_objects // bs)
+        if best is None or (steps, -bs) < (best[0], -best[2]):
+            best = (steps, bb, bs)
+    return (best[1], best[2]) if best is not None else fallback
 
 
 def modeled_residency(cfg, params, batch: int, *,
                       block_b: int | None = None,
                       block_s: int | None = None,
                       budget_bytes: int = VMEM_BUDGET_BYTES) -> dict:
-    """The tiling decision :func:`ops.fused_forward_full` will make for
-    ``batch`` samples, as data — the modeled-residency introspection
-    hook the kernel-contract auditor (``repro.analysis.kernel_audit``)
-    cross-checks against the *traced* ``pallas_call``.
+    """The tiling decision for ``batch`` samples, as data: THE one place
+    it is made — :func:`ops.fused_forward_full` builds its BlockSpecs
+    from this dict, and the kernel-contract auditor
+    (``repro.analysis.kernel_audit``) cross-checks it against the
+    *traced* ``pallas_call``.  Pinned knobs (tests) are honored; a
+    pinned ``block_s`` rounds down to a divisor of N_o.
 
-    Mirrors the wrapper's tuner invocation EXACTLY (including the
-    pinned-knob branches): any drift between this mirror and the real
-    BlockSpecs/grid is precisely the silent-bug class the auditor
-    exists to catch, so keep the two in lockstep.
-
-    Returns ``{kernel, block_b, block_s, grid, per_sample_bytes,
-    reserved_bytes, effective_budget, weight_residency_bytes, fits}``;
+    Returns ``{kernel, block_b, block_s, batch_axis, grid,
+    per_sample_bytes, reserved_bytes, effective_budget,
+    weight_residency_bytes, fits}``;
     ``weight_residency_bytes`` is the VMEM the weight blocks (and, for
     quantized params, the dequant-scale vector) occupy at the dtypes the
     kernel ships — what the traced input BlockSpecs must add up to.
@@ -201,7 +209,7 @@ def modeled_residency(cfg, params, batch: int, *,
             batch, n_o, n_f, fr_w, fo_w, phi_w,
             budget_bytes=budget_bytes, reserved_bytes=reserved)
     elif block_b is None:
-        block_s = min(int(block_s), n_o)
+        block_s = sender_tile(block_s, n_o)
         per = full_forward_tiled_bytes_per_sample(
             n_o, n_f, fr_w, fo_w, phi_w, block_s)
         block_b = pick_block_b(batch, per,
@@ -211,7 +219,7 @@ def modeled_residency(cfg, params, batch: int, *,
                                budget_bytes=budget_bytes,
                                reserved_bytes=reserved)
     else:
-        block_s = min(int(block_s), n_o)
+        block_s = sender_tile(block_s, n_o)
     per = full_forward_tiled_bytes_per_sample(
         n_o, n_f, fr_w, fo_w, phi_w, block_s)
     budget = effective_budget(budget_bytes, reserved)
@@ -219,8 +227,9 @@ def modeled_residency(cfg, params, batch: int, *,
         "kernel": "fused_jedinet.full",
         "block_b": int(block_b),
         "block_s": int(block_s),
+        "batch_axis": 1,                  # x is node-major (N_o, B, P)
         "grid": (padded_batch(batch, block_b) // block_b,
-                 -(-n_o // block_s)),
+                 n_o // block_s),
         "per_sample_bytes": int(per),
         "reserved_bytes": int(reserved),
         "effective_budget": int(budget),
@@ -273,3 +282,12 @@ def pick_block_s(block_b: int, n_objects: int, n_features: int,
         if max(int(block_b), 1) * per <= budget:
             best = bs
     return best
+
+
+def sender_tile(block_s: int | None, n_objects: int) -> int:
+    """A pinned sender tile as the kernel runs it: the largest divisor
+    of N_o not above ``block_s`` (``None`` means untiled)."""
+    if block_s is None:
+        return n_objects
+    return max(d for d in sender_tile_candidates(n_objects)
+               if d <= max(int(block_s), 1))

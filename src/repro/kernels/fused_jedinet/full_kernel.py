@@ -21,25 +21,26 @@ N_o=50 already forces tiny batch tiles and past N_o~100 cannot hold even
 ONE sample — exactly the regime real-time track-graph building targets
 (Neu et al., 2307.07289; JEDI-linear, 2508.15468).  The kernel therefore
 grids over (batch tiles, sender tiles): each program step computes the
-``(block_b, N_o, block_s, H1)`` slab of the grid for one chunk of
+``(N_o, block_s, block_b, H1)`` slab of the grid for one chunk of
 ``block_s`` senders and folds its sender-sum into an fp32 VMEM scratch
-accumulator ``acc[block_b, N_o, D_e]`` that persists across the sender
+accumulator ``acc[N_o, block_b, D_e]`` that persists across the sender
 steps.  Only after the LAST sender tile does the trailing network
-(f_O, node-sum, phi_O) run and write logits.  The live set shrinks from
-``O(block_b * N_o^2 * H1)`` to ``O(block_b * N_o * block_s * H1)``, so
-``block_b`` grows by ~``N_o / block_s`` — weight traffic amortizes over
-much larger batch tiles — and N_o=128 graphs fit where the untiled
-working-set model rejects even ``block_b = 1``.
+(f_O, node-sum, phi_O) run and write logits.  ``block_s`` divides N_o
+(``autotune.sender_tile_candidates``), so every sender step is a whole
+tile: no clamped remainder, no bounds mask.  The grid includes each
+node's self-edge; the tail subtracts it once, from the same per-node
+projections.  ``block_s = N_o`` is the untiled kernel (one sender step).
 
-Each sender chunk is SLICED out of the batch tile's resident x block in
-VMEM (``block_s`` need not divide N_o: the remainder tile's slice start
-clamps and the mask drops the re-covered columns), so x crosses HBM
-once per batch tile — the docstring's traffic claim stays exact.  The
-diagonal (self-edge) mask and the clamp mask are applied PER TILE
-before the accumulate, so the summand set stays identical to the
-strength-reduced reference — no subtractive cancellation, fp32
-agreement < 1e-4.  ``block_s = N_o`` degenerates to the old untiled
-kernel (one sender step, mask = 1 - eye).
+Node-major layout
+-----------------
+The wrapper hands the kernel x as ``(N_o, B, P)``: nodes and senders on
+the untiled leading axes, the batch on sublanes, features on lanes.
+Every per-node or per-edge tensor is then a stack of ``(block_b, width)``
+tiles, so the kernel's reshapes only merge or split leading axes
+(``block_b`` is a multiple of 8), each matmul is one 2-D
+``(rows, width) @ (width, width')`` MXU call, the sender chunk is a ref
+slice along a leading axis, and both reductions (sender-sum, node-sum)
+are adds of whole tiles.  x crosses HBM once per batch tile.
 
 In-kernel int8 weights
 ----------------------
@@ -55,17 +56,19 @@ scale fold, exactly as in the fp path.
 Precision co-design (the paper tunes FPGA word lengths; we tune the MXU
 input dtype): every matmul casts its operands to ``compute_dtype`` and
 accumulates in fp32 via ``preferred_element_type``; biases, activations
-and both reductions (sender-sum, node-sum) stay fp32.
+and both reductions (sender-sum, node-sum) stay fp32.  fp32 operands
+run at ``Precision.HIGHEST``: the MXU's default would round them to one
+bf16 pass.
 
 The two beyond-paper transformations of the edge kernel (bilinear
-first-layer split; dense grid + diagonal/bounds masking instead of a
+first-layer split; dense grid + diagonal correction instead of a
 gather) are inherited — see kernel.py's docstring and EXPERIMENTS.md
 §Perf.
 
 Grid: ``(batch tiles, sender tiles)``, sender innermost; weights and
 scales broadcast to every step.  ``(block_b, block_s)`` come from the 2D
 working-set autotuner (autotune.pick_block_b_s), which models the TILED
-live set.
+live set at whole 128-lane rows.
 """
 
 from __future__ import annotations
@@ -77,6 +80,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.autotune import pad_batch
 from repro.nn.core import ACTIVATIONS
 
 
@@ -85,105 +89,116 @@ def _is_int(w) -> bool:
 
 
 def _mmq(h, w, scale, compute_dtype):
-    """Matmul with fp32 accumulation; int weights fold ``scale`` AFTER.
+    """2-D matmul with fp32 accumulation; int weights fold ``scale`` AFTER.
 
     ``h`` casts to the weight's compute representation (int8 weights
     upcast to ``compute_dtype`` — their integer values are exact in
     fp32/bf16 up to +-127, so the MXU sees the same operands an int8
     datapath would); the per-tensor dequant scale multiplies the fp32
     ACCUMULATOR, not the weight, so the weight block in VMEM stays
-    1 byte/element.
+    1 byte/element.  fp32 operands are multiplied at full precision.
     """
     wv = w[...]
     if _is_int(wv):
         wv = wv.astype(compute_dtype)
+    precision = (jax.lax.Precision.HIGHEST if wv.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
     out = jax.lax.dot_general(
-        h.astype(wv.dtype), wv,
-        (((h.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        h.astype(wv.dtype), wv, (((1,), (0,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
     if scale is not None:
         out = out * scale
     return out
 
 
+def _unpack_weights(wrefs, quantized: bool, n_fr: int, n_fo: int):
+    """Split a kernel's weight refs ``[scales?, w1r, w1s, b1, (w, b)*]``
+    into the first f_R layer ``(w1r, s1r, w1s, s1s, b1)`` and the
+    remaining f_R, f_O and phi_O layers as ``(w, b, scale)`` triples.
+
+    Each dequant scale is read here, once per weight tensor, in weight
+    order (both halves of the split w1 share w1's scale)."""
+    if quantized:
+        scales_ref, wrefs = wrefs[0], wrefs[1:]
+
+        def scale(k):
+            return scales_ref[0, k]
+    else:
+        def scale(k):
+            return None
+    first = (wrefs[0], scale(0), wrefs[1], scale(1), wrefs[2])
+    layers = [(w, b, scale(2 + i))
+              for i, (w, b) in enumerate(zip(wrefs[3::2], wrefs[4::2]))]
+    n_rest = n_fr - 1
+    return (first, layers[:n_rest], layers[n_rest:n_rest + n_fo],
+            layers[n_rest + n_fo:])
+
+
+def _mlp(h, layers, act, compute_dtype):
+    """Dense layers on ``(rows, width)``; activation between layers, the
+    last layer linear."""
+    for i, (w, b, scale) in enumerate(layers):
+        h = _mmq(h, w, scale, compute_dtype) + b[...]
+        if i < len(layers) - 1:
+            h = act(h)
+    return h
+
+
+def _edge_mlp(h, fr_rest, act, compute_dtype):
+    """f_R after its (split) first layer: ``h`` is that layer's
+    pre-activation; the f_R output layer is linear."""
+    if fr_rest:
+        h = _mlp(act(h), fr_rest, act, compute_dtype)
+    return h
+
+
+def _readout(x2, ebar, n_o: int, fo, phi, act, compute_dtype):
+    """C = [x ‖ Ebar] -> f_O -> node-sum -> phi_O, for node-major rows
+    ``(N_o * block_b, .)``; returns ``(block_b, n_targets)`` logits."""
+    o = _mlp(jnp.concatenate([x2, ebar], axis=-1), fo, act, compute_dtype)
+    o_sum = jnp.sum(o.reshape(n_o, -1, o.shape[-1]), axis=0)
+    return _mlp(o_sum, phi, act, compute_dtype)
+
+
 def _tiled_forward_kernel(x_ref, *rest_refs, activation: str,
-                          n_fr: int, n_fo: int, n_phi: int, n_o: int,
-                          block_s: int, quantized: bool, compute_dtype):
+                          n_fr: int, n_fo: int, n_o: int, block_s: int,
+                          quantized: bool, compute_dtype):
     """rest_refs = [scales?] + [w1r, w1s, b1, (fr w/b)*, (fo w/b)*,
     (phi w/b)*] + [out_ref, acc_ref].
 
-    ``x_ref``   — (block_b, N_o, P): the full receiver view, resident
-                  across sender steps (its index map ignores j), so x
-                  crosses HBM ONCE per batch tile.  Each sender step
-                  slices its ``block_s`` chunk out of this block in
-                  VMEM — no second x operand, no sender-padded copy.
-                  The slice start clamps at ``N_o - block_s`` for the
-                  remainder tile; the mask excludes the senders the
-                  clamp re-covers (``send >= j*block_s``).
-    ``acc_ref`` — (block_b, N_o, D_e) fp32 VMEM scratch: the Ebar
+    ``x_ref``   — (N_o, block_b, P) fp32: every node of the batch tile,
+                  resident across sender steps (its index map ignores
+                  j), so x crosses HBM ONCE per batch tile.  Each sender
+                  step slices its ``block_s`` nodes out of this block.
+    ``acc_ref`` — (N_o, block_b, D_e) fp32 VMEM scratch: the Ebar
                   accumulator, carried across the sender steps of one
                   batch tile.
     Weight refs arrive pre-cast to the compute dtype (or int8 when
     ``quantized``); biases are fp32.
     """
     out_ref, acc_ref = rest_refs[-2], rest_refs[-1]
-    wref = list(rest_refs[:-2])
-    if quantized:
-        scales_ref, wref = wref[0], wref[1:]
-
-        def s(k):
-            return scales_ref[0, k]
-    else:
-        def s(k):
-            return None
+    (w1r, s1r, w1s, s1s, b1), fr_rest, fo, phi = _unpack_weights(
+        rest_refs[:-2], quantized, n_fr, n_fo)
     act = ACTIVATIONS[activation]
-
-    w1r, w1s, b1 = wref[0], wref[1], wref[2]
-    fr_rest = wref[3:3 + 2 * (n_fr - 1)]
-    fo_w = wref[3 + 2 * (n_fr - 1):3 + 2 * (n_fr - 1) + 2 * n_fo]
-    phi_w = wref[3 + 2 * (n_fr - 1) + 2 * n_fo:]
-    # scale index of each weight tensor, in ref order (biases carry none)
-    k_fr = list(range(n_fr + 1))                       # w1r, w1s, w2..
-    k_fo = [n_fr + 1 + i for i in range(n_fo)]
-    k_phi = [n_fr + 1 + n_fo + i for i in range(n_phi)]
-
     j = pl.program_id(1)
-    n_sj = pl.num_programs(1)
+    _, bb, p = x_ref.shape
 
-    x = x_ref[...]                                      # (bb, N_o, P) cdt
-    # this step's sender chunk, sliced from the resident receiver block;
-    # the start clamps for the remainder tile (block_s ∤ N_o) and the
-    # mask below drops the rows the clamp re-reads from the previous tile
-    start = jnp.minimum(j * block_s, n_o - block_s)
-    xs = jax.lax.dynamic_slice_in_dim(x, start, block_s, axis=1)
+    x2 = x_ref[...].reshape(n_o * bb, p)
+    xs = x_ref[pl.ds(j * block_s, block_s)].reshape(block_s * bb, p)
 
     # --- f_R layer 1, bilinear split: receiver projection over ALL N_o
-    # rows (cheap: N_o*P*H1, recomputed per sender step so no second
-    # scratch), sender projection over THIS tile only.
-    u_r = _mmq(x, w1r, s(k_fr[0]), compute_dtype)       # (bb, N_o, H1) fp32
-    u_s = _mmq(xs, w1s, s(k_fr[1]), compute_dtype)      # (bb, bs, H1) fp32
+    # nodes (recomputed per sender step, so no second scratch), sender
+    # projection over THIS tile only.
+    u_r = _mmq(x2, w1r, s1r, compute_dtype)             # (N_o*bb, H1)
+    u_s = _mmq(xs, w1s, s1s, compute_dtype)             # (bs*bb, H1)
+    h1 = u_r.shape[-1]
 
     # --- dense receiver x sender-tile slab (regular access, no gather)
-    h = u_r[:, :, None, :] + u_s[:, None, :, :] + b1[...]
-    if n_fr > 1:                                        # f_R output is linear
-        h = act(h)                                      # (bb, N_o, bs, H1)
-
-    # --- remaining f_R layers on the slab
-    for li in range(n_fr - 1):
-        h = _mmq(h, fr_rest[2 * li], s(k_fr[2 + li]), compute_dtype) \
-            + fr_rest[2 * li + 1][...]
-        if li < n_fr - 2:
-            h = act(h)
-
-    # --- masked accumulate: zero the self-edge diagonal cell AND any
-    # sender column the clamped remainder slice re-covers from the
-    # previous tile, BEFORE the sum — every sender contributes exactly
-    # once and the summand set stays identical to the reference (no
-    # subtractive cancellation).
-    recv = jax.lax.broadcasted_iota(jnp.int32, (n_o, block_s), 0)
-    send = jax.lax.broadcasted_iota(jnp.int32, (n_o, block_s), 1) + start
-    mask = ((recv != send) & (send >= j * block_s)).astype(h.dtype)
-    contrib = jnp.sum(h * mask[None, :, :, None], axis=2)   # (bb, N_o, D_e)
+    h = u_r.reshape(n_o, 1, bb, h1) + u_s.reshape(1, block_s, bb, h1)
+    h = _edge_mlp(h.reshape(n_o * block_s * bb, h1) + b1[...],
+                  fr_rest, act, compute_dtype)           # (N_o*bs*bb, D_e)
+    d_e = h.shape[-1]
+    contrib = jnp.sum(h.reshape(n_o, block_s, bb, d_e), axis=1)
 
     @pl.when(j == 0)
     def _init():
@@ -191,23 +206,17 @@ def _tiled_forward_kernel(x_ref, *rest_refs, activation: str,
 
     acc_ref[...] += contrib
 
-    # --- after the LAST sender tile: C = [x ‖ Ebar], f_O, node-sum,
-    # phi_O — all still in VMEM, once per batch tile.
-    @pl.when(j == n_sj - 1)
+    # --- after the LAST sender tile: drop the self-edges the dense grid
+    # summed, then C = [x ‖ Ebar], f_O, node-sum, phi_O — all still in
+    # VMEM, once per batch tile.
+    @pl.when(j == pl.num_programs(1) - 1)
     def _tail():
-        h = jnp.concatenate([x.astype(jnp.float32), acc_ref[...]], axis=-1)
-        for li in range(n_fo):
-            h_ = _mmq(h, fo_w[2 * li], s(k_fo[li]), compute_dtype) \
-                + fo_w[2 * li + 1][...]
-            h_ = act(h_) if li < n_fo - 1 else h_       # (bb, N_o, D_o)
-            h = h_
-        h = jnp.sum(h, axis=1)                          # (bb, D_o) fp32
-        for li in range(n_phi):
-            h_ = _mmq(h, phi_w[2 * li], s(k_phi[li]), compute_dtype) \
-                + phi_w[2 * li + 1][...]
-            h_ = act(h_) if li < n_phi - 1 else h_
-            h = h_
-        out_ref[...] = h.astype(out_ref.dtype)          # (bb, n_targets)
+        u_self = _mmq(x2, w1s, s1s, compute_dtype)
+        self_edge = _edge_mlp(u_r + u_self + b1[...], fr_rest, act,
+                              compute_dtype)
+        ebar = acc_ref[...].reshape(n_o * bb, d_e) - self_edge
+        logits = _readout(x2, ebar, n_o, fo, phi, act, compute_dtype)
+        out_ref[...] = logits.astype(out_ref.dtype)     # (bb, n_targets)
 
 
 def flatten_mlp(params, dtype):
@@ -229,28 +238,53 @@ def mlp_scales(params) -> list:
     return [lp["w_scale"] for lp in params["layers"]]
 
 
+def node_major(x, compute_dtype, block_b: int):
+    """(B, N_o, P) -> fp32 (N_o, B', P), the layout the whole-network
+    kernels read: B padded to a ``block_b`` multiple, values rounded to
+    ``compute_dtype`` (x feeds the MXU and, through C = [x ‖ Ebar], the
+    f_O input at that precision)."""
+    x = x.astype(compute_dtype).astype(jnp.float32)
+    return jnp.transpose(pad_batch(x, block_b), (1, 0, 2))
+
+
+def check_scales(weights, scales):
+    """The per-tensor dequant scales as the kernels read them: one
+    ``(1, n_weight_tensors)`` fp32 row, or ``None`` for fp weights."""
+    if not any(_is_int(w) for w in weights):
+        return None
+    n_w = len(weights) // 2 + 1                  # +1: w1 split in two
+    if scales is None:
+        raise ValueError(
+            "int8 weight arrays need their dequant scales: pass "
+            "scales=[s_w1r, s_w1s, s_w2, ...] (one per weight tensor)")
+    scales = jnp.asarray(scales, jnp.float32).reshape(1, -1)
+    if scales.shape[1] != n_w:
+        raise ValueError(
+            f"got {scales.shape[1]} scales for {n_w} weight tensors")
+    return scales
+
+
 def fused_forward_full_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
                                    activation: str, n_targets: int,
                                    block_b: int, block_s: int | None = None,
+                                   compute_dtype=jnp.float32,
                                    scales=None, interpret: bool = False):
-    """x: (B, N_o, P) compute-dtype -> logits (B, n_targets) fp32.
+    """x: (N_o, B, P) fp32 node-major (:func:`node_major`) -> logits
+    (B, n_targets) fp32.
 
-    ``B % block_b == 0`` (callers pad via autotune.pad_batch).
+    ``B % block_b == 0`` (callers pad via :func:`node_major`).
     ``fr_arrays = [w1r, w1s, b1, w2, b2, ...]`` from split_first_layer.
-    ``block_s`` tiles the sender axis (default N_o = untiled).
-    ``scales`` — fp32 vector of per-weight-tensor dequant scales, in
-    weight order [w1r, w1s, w2.., fo.., phi..], required iff any weight
-    array is an integer dtype (in-kernel int8 dequant).
+    ``block_s`` tiles the sender axis and must divide N_o (default N_o =
+    untiled).  ``scales`` — fp32 vector of per-weight-tensor dequant
+    scales, in weight order [w1r, w1s, w2.., fo.., phi..], required iff
+    any weight array is an integer dtype (in-kernel int8 dequant).
     """
-    bsz, n_o, p = x.shape
-    block_s = n_o if block_s is None else min(int(block_s), n_o)
+    n_o, bsz, p = x.shape
+    block_s = n_o if block_s is None else int(block_s)
     n_fr = 1 + (len(fr_arrays) - 3) // 2
     n_fo = len(fo_arrays) // 2
-    n_phi = len(phi_arrays) // 2
     weights = [*fr_arrays, *fo_arrays, *phi_arrays]
-    quantized = any(_is_int(w) for w in weights)
     d_e = fr_arrays[-2].shape[-1] if n_fr > 1 else fr_arrays[0].shape[-1]
-    compute_dtype = x.dtype
 
     if bsz % block_b != 0:
         from repro.kernels.fused_jedinet import autotune as fj_autotune
@@ -264,28 +298,20 @@ def fused_forward_full_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
             f"(block_b={block_b}, block_s={block_s}) at modeled {modeled} "
             f"VMEM bytes/sample — pad the batch with autotune.pad_batch(x, "
             f"{block_b}) (kernel wrappers do this automatically)")
-    if quantized:
-        n_w = len(weights) // 2 + 1                  # +1: w1 split in two
-        if scales is None:
-            raise ValueError(
-                "int8 weight arrays need their dequant scales: pass "
-                "scales=[s_w1r, s_w1s, s_w2, ...] (one per weight tensor)")
-        scales = jnp.asarray(scales, jnp.float32).reshape(1, -1)
-        if scales.shape[1] != n_w:
-            raise ValueError(
-                f"got {scales.shape[1]} scales for {n_w} weight tensors")
-
-    n_sj = -(-n_o // block_s)
-    grid = (bsz // block_b, n_sj)
+    if n_o % block_s != 0:
+        raise ValueError(
+            f"sender tile block_s={block_s} does not divide N_o={n_o}; "
+            "pick one of autotune.sender_tile_candidates(N_o)")
+    scales = check_scales(weights, scales)
 
     def wmap(ndim):
         def m(i, j):
             return (0,) * ndim
         return m
 
-    in_specs = [pl.BlockSpec((block_b, n_o, p), lambda i, j: (i, 0, 0))]
+    in_specs = [pl.BlockSpec((n_o, block_b, p), lambda i, j: (0, i, 0))]
     operands = [x]
-    if quantized:
+    if scales is not None:
         in_specs.append(pl.BlockSpec(scales.shape, wmap(scales.ndim)))
         operands.append(scales)
     for w in weights:
@@ -294,14 +320,14 @@ def fused_forward_full_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
 
     kernel = functools.partial(
         _tiled_forward_kernel, activation=activation, n_fr=n_fr, n_fo=n_fo,
-        n_phi=n_phi, n_o=n_o, block_s=block_s, quantized=quantized,
-        compute_dtype=compute_dtype)
+        n_o=n_o, block_s=block_s, quantized=scales is not None,
+        compute_dtype=jnp.dtype(compute_dtype))
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bsz // block_b, n_o // block_s),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_b, n_targets), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_targets), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_b, n_o, d_e), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_o, block_b, d_e), jnp.float32)],
         interpret=interpret,
     )(*operands)

@@ -54,6 +54,23 @@ def is_quantized_params(params) -> bool:
     return all(flags) and bool(flags)
 
 
+def whole_network_operands(params, cfg):
+    """``(fr_arrays, fo_arrays, phi_arrays, scales)`` for the whole-
+    network kernels: f_R's first layer split into receiver/sender halves,
+    weights cast to the compute dtype (int8 weights verbatim), biases
+    fp32, and — for int8 params — the per-tensor dequant scales in
+    weight order (both halves of the split w1 share w1's scale)."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    fr = K.split_first_layer(params["fr"], cfg.n_features, dtype=cdt)
+    scales = None
+    if is_quantized_params(params):
+        s_fr = FK.mlp_scales(params["fr"])
+        scales = [s_fr[0], s_fr[0], *s_fr[1:],
+                  *FK.mlp_scales(params["fo"]), *FK.mlp_scales(params["phi"])]
+    return ([fr[0], fr[1], fr[2], *fr[3]], FK.flatten_mlp(params["fo"], cdt),
+            FK.flatten_mlp(params["phi"], cdt), scales)
+
+
 @partial(jax.jit, static_argnames=("cfg", "interpret", "block_b"))
 def fused_edge_block(params_fr, cfg, x, *, interpret: bool = False,
                      block_b: int | None = None):
@@ -92,50 +109,19 @@ def fused_forward_full(params, cfg, x, *, interpret: bool = False,
     ``params`` may be raw fp32/bf16 MLPs or int8-quantized ones
     (``quantize_params_int8``); quantized layers keep their int8 weights
     all the way into VMEM.  ``(block_b, block_s)`` default to the 2D
-    working-set autotuner; pass either explicitly to pin it (tests).
+    working-set autotuner, decided once in
+    ``autotune.modeled_residency``; pass either explicitly to pin it
+    (tests).  A pinned ``block_s`` rounds down to a divisor of N_o.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
-    quantized = is_quantized_params(params)
-    fr = K.split_first_layer(params["fr"], cfg.n_features, dtype=cdt)
-    fr_arrays = [fr[0], fr[1], fr[2], *fr[3]]
-    fo_arrays = FK.flatten_mlp(params["fo"], cdt)
-    phi_arrays = FK.flatten_mlp(params["phi"], cdt)
-    scales = None
-    if quantized:
-        s_fr = FK.mlp_scales(params["fr"])
-        # w1 splits into (w1r, w1s): both halves share w1's tensor scale
-        scales = [s_fr[0], s_fr[0], *s_fr[1:],
-                  *FK.mlp_scales(params["fo"]), *FK.mlp_scales(params["phi"])]
-
-    if block_b is None or block_s is None:
-        fr_w = autotune.mlp_widths(params["fr"])
-        fo_w = autotune.mlp_widths(params["fo"])
-        phi_w = autotune.mlp_widths(params["phi"])
-        reserved = autotune.weight_vmem_bytes(params, cfg.compute_dtype)
-        if block_b is None and block_s is None:
-            block_b, block_s = autotune.pick_block_b_s(
-                x.shape[0], cfg.n_objects, cfg.n_features,
-                fr_w, fo_w, phi_w, reserved_bytes=reserved)
-        elif block_b is None:
-            # block_s pinned: tune the batch tile UNDER it — reusing the
-            # jointly-tuned block_b of a different sender tile could bust
-            # the budget (the pinned pair was never validated together)
-            per = autotune.full_forward_tiled_bytes_per_sample(
-                cfg.n_objects, cfg.n_features, fr_w, fo_w, phi_w,
-                min(int(block_s), cfg.n_objects))
-            block_b = autotune.pick_block_b(
-                x.shape[0], per,
-                autotune.effective_budget(autotune.VMEM_BUDGET_BYTES,
-                                          reserved))
-        else:
-            # block_b pinned: largest sender tile that fits beside it
-            block_s = autotune.pick_block_s(
-                block_b, cfg.n_objects, cfg.n_features,
-                fr_w, fo_w, phi_w, reserved_bytes=reserved)
-    bsz = x.shape[0]
-    xp = autotune.pad_batch(x.astype(cdt), block_b)
+    fr_arrays, fo_arrays, phi_arrays, scales = whole_network_operands(
+        params, cfg)
+    tiles = autotune.modeled_residency(cfg, params, x.shape[0],
+                                       block_b=block_b, block_s=block_s)
+    block_b, block_s = tiles["block_b"], tiles["block_s"]
     out = FK.fused_forward_full_kernel_call(
-        xp, fr_arrays, fo_arrays, phi_arrays,
+        FK.node_major(x, cdt, block_b), fr_arrays, fo_arrays, phi_arrays,
         activation=cfg.activation, n_targets=cfg.n_targets,
-        block_b=block_b, block_s=block_s, scales=scales, interpret=interpret)
-    return out[:bsz]
+        block_b=block_b, block_s=block_s, compute_dtype=cdt, scales=scales,
+        interpret=interpret)
+    return out[:x.shape[0]]

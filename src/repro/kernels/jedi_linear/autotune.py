@@ -22,8 +22,8 @@ from __future__ import annotations
 # Re-exported so kernel wrappers and tests have one import surface.
 from repro.kernels.autotune import (  # noqa: F401
     VMEM_BUDGET_BYTES,
-    _SUBLANE,
     effective_budget,
+    lanes,
     mlp_widths,
     pad_batch,
     padded_batch,
@@ -44,18 +44,19 @@ def linear_forward_bytes_per_sample(n_objects: int, n_features: int,
     (each (N_o, H1) fp32), the (1, H1) sender pool, the per-NODE f_R
     activations (the widest (N_o, width) tensor — no edge grid), the x
     tile, the Ebar result, C = [x ‖ Ebar] and the f_O / phi_O
-    activations.  Every term is linear in N_o — the whole point.
+    activations.  Every term is linear in N_o — the whole point.  Rows
+    are billed at whole 128-lane tiles, as they sit in VMEM.
     """
     n_o = n_objects
-    h1 = fr_widths[0]
+    h1 = lanes(fr_widths[0])
     u_proj = 2 * n_o * h1
     pooled = h1
-    fr_acts = n_o * max(fr_widths + [_SUBLANE])
-    x_tile = n_o * n_features
-    ebar = n_o * fr_widths[-1]
-    c_tile = n_o * (n_features + fr_widths[-1])
-    fo_acts = n_o * max(fo_widths + [_SUBLANE])
-    phi_acts = max(phi_widths + [_SUBLANE])
+    fr_acts = n_o * lanes(max(fr_widths))
+    x_tile = n_o * lanes(n_features)
+    ebar = n_o * lanes(fr_widths[-1])
+    c_tile = n_o * lanes(n_features + fr_widths[-1])
+    fo_acts = n_o * lanes(max(fo_widths))
+    phi_acts = lanes(max(phi_widths))
     return (u_proj + pooled + fr_acts + x_tile + ebar + c_tile
             + fo_acts + phi_acts) * acc_bytes
 
@@ -63,11 +64,11 @@ def linear_forward_bytes_per_sample(n_objects: int, n_features: int,
 def modeled_residency(cfg, params, batch: int, *,
                       block_b: int | None = None,
                       budget_bytes: int = VMEM_BUDGET_BYTES) -> dict:
-    """The tiling decision :func:`ops.jedi_linear_forward_full` will make
-    for ``batch`` samples, as data — the modeled-residency introspection
-    hook the kernel-contract auditor (``repro.analysis.kernel_audit``)
-    cross-checks against the traced ``pallas_call``.  Mirrors the
-    wrapper's tuner invocation exactly; same contract as
+    """The tiling decision for ``batch`` samples, as data: the one place
+    it is made — :func:`ops.jedi_linear_forward_full` builds its
+    BlockSpecs from it, and the kernel-contract auditor
+    (``repro.analysis.kernel_audit``) cross-checks it against the traced
+    ``pallas_call``; same contract as
     ``fused_jedinet.autotune.modeled_residency``."""
     fr_w = mlp_widths(params["fr"])
     fo_w = mlp_widths(params["fo"])
@@ -82,6 +83,7 @@ def modeled_residency(cfg, params, batch: int, *,
         "kernel": "jedi_linear.full",
         "block_b": int(block_b),
         "block_s": None,
+        "batch_axis": 1,                  # x is node-major (N_o, B, P)
         "grid": (padded_batch(batch, block_b) // block_b,),
         "per_sample_bytes": int(per),
         "reserved_bytes": int(reserved),

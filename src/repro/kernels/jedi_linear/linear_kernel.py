@@ -24,7 +24,9 @@ and — for int8 weights (``core/int8_path.py``) — the per-tensor dequant
 scale folded into the ACCUMULATED fp32 result, so quantized weights
 travel HBM -> VMEM at 1 byte/element exactly as in the fused_jedinet
 kernels.  The (N_o-1)-fold recombination and both reductions (sender
-pool, node-sum) stay fp32.
+pool, node-sum) stay fp32.  x arrives node-major, ``(N_o, B, P)``, as
+in the fused_jedinet kernel (see its "Node-major layout"): every matmul
+is 2-D and both reductions add whole ``(block_b, width)`` tiles.
 
 Grid: ``(batch tiles,)``; weights and scales broadcast to every step.
 ``block_b`` comes from the linear working-set model via the shared
@@ -39,93 +41,64 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_jedinet.full_kernel import _is_int, _mmq
+from repro.kernels.fused_jedinet.full_kernel import (
+    _edge_mlp, _mmq, _readout, _unpack_weights, check_scales)
 from repro.nn.core import ACTIVATIONS
 
 
 def _linear_forward_kernel(x_ref, *rest_refs, activation: str,
-                           n_fr: int, n_fo: int, n_phi: int, n_o: int,
+                           n_fr: int, n_fo: int, n_o: int,
                            quantized: bool, compute_dtype):
     """rest_refs = [scales?] + [w1r, w1s, b1, (fr w/b)*, (fo w/b)*,
     (phi w/b)*] + [out_ref].
 
-    ``x_ref`` — (block_b, N_o, P): the batch tile, read once; both
-    projections and the pool are computed from this resident block.
+    ``x_ref`` — (N_o, block_b, P) fp32: the batch tile, node-major, read
+    once; both projections and the pool are computed from this block.
     Weight refs arrive pre-cast to the compute dtype (or int8 when
-    ``quantized``); biases are fp32.  Scale-index bookkeeping matches
-    the fused_jedinet kernel: w1's split halves share w1's scale.
+    ``quantized``); biases are fp32.  Scale bookkeeping matches the
+    fused_jedinet kernel: w1's split halves share w1's scale.
     """
     out_ref = rest_refs[-1]
-    wref = list(rest_refs[:-1])
-    if quantized:
-        scales_ref, wref = wref[0], wref[1:]
-
-        def s(k):
-            return scales_ref[0, k]
-    else:
-        def s(k):
-            return None
+    (w1r, s1r, w1s, s1s, b1), fr_rest, fo, phi = _unpack_weights(
+        rest_refs[:-1], quantized, n_fr, n_fo)
     act = ACTIVATIONS[activation]
-
-    w1r, w1s, b1 = wref[0], wref[1], wref[2]
-    fr_rest = wref[3:3 + 2 * (n_fr - 1)]
-    fo_w = wref[3 + 2 * (n_fr - 1):3 + 2 * (n_fr - 1) + 2 * n_fo]
-    phi_w = wref[3 + 2 * (n_fr - 1) + 2 * n_fo:]
-    # scale index of each weight tensor, in ref order (biases carry none)
-    k_fr = list(range(n_fr + 1))                       # w1r, w1s, w2..
-    k_fo = [n_fr + 1 + i for i in range(n_fo)]
-    k_phi = [n_fr + 1 + n_fo + i for i in range(n_phi)]
-
-    x = x_ref[...]                                     # (bb, N_o, P) cdt
+    _, bb, p = x_ref.shape
+    x2 = x_ref[...].reshape(n_o * bb, p)
 
     # --- f_R layer 1, pooled: two per-node projections, one global
     # sender pool, per-node recombination.  All fp32 after _mmq.
-    u_r = _mmq(x, w1r, s(k_fr[0]), compute_dtype)      # (bb, N_o, H1)
-    u_s = _mmq(x, w1s, s(k_fr[1]), compute_dtype)      # (bb, N_o, H1)
-    pooled = jnp.sum(u_s, axis=1, keepdims=True)       # (bb, 1, H1)
-    h = (n_o - 1) * (u_r + b1[...]) + (pooled - u_s)
-    if n_fr > 1:                                       # f_R output is linear
-        h = act(h)
+    u_r = _mmq(x2, w1r, s1r, compute_dtype)            # (N_o*bb, H1)
+    u_s = _mmq(x2, w1s, s1s, compute_dtype)            # (N_o*bb, H1)
+    h1 = u_r.shape[-1]
+    u_s = u_s.reshape(n_o, bb, h1)
+    pooled = jnp.sum(u_s, axis=0, keepdims=True)       # (1, bb, H1)
+    h = ((n_o - 1) * (u_r.reshape(n_o, bb, h1) + b1[...])
+         + (pooled - u_s))
 
-    # --- remaining f_R layers run per NODE: (bb, N_o, width), no grid
-    for li in range(n_fr - 1):
-        h = _mmq(h, fr_rest[2 * li], s(k_fr[2 + li]), compute_dtype) \
-            + fr_rest[2 * li + 1][...]
-        if li < n_fr - 2:
-            h = act(h)
-
-    # --- C = [x ‖ Ebar], f_O, node-sum, phi_O — all in the same step
-    h = jnp.concatenate([x.astype(jnp.float32), h], axis=-1)
-    for li in range(n_fo):
-        h_ = _mmq(h, fo_w[2 * li], s(k_fo[li]), compute_dtype) \
-            + fo_w[2 * li + 1][...]
-        h = act(h_) if li < n_fo - 1 else h_           # (bb, N_o, D_o)
-    h = jnp.sum(h, axis=1)                             # (bb, D_o) fp32
-    for li in range(n_phi):
-        h_ = _mmq(h, phi_w[2 * li], s(k_phi[li]), compute_dtype) \
-            + phi_w[2 * li + 1][...]
-        h = act(h_) if li < n_phi - 1 else h_
-    out_ref[...] = h.astype(out_ref.dtype)             # (bb, n_targets)
+    # --- remaining f_R layers run per NODE, then C = [x ‖ Ebar], f_O,
+    # node-sum, phi_O — all in the same step
+    ebar = _edge_mlp(h.reshape(n_o * bb, h1), fr_rest, act, compute_dtype)
+    logits = _readout(x2, ebar, n_o, fo, phi, act, compute_dtype)
+    out_ref[...] = logits.astype(out_ref.dtype)        # (bb, n_targets)
 
 
 def jedi_linear_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
                             activation: str, n_targets: int, block_b: int,
-                            scales=None, interpret: bool = False):
-    """x: (B, N_o, P) compute-dtype -> logits (B, n_targets) fp32.
+                            compute_dtype=jnp.float32, scales=None,
+                            interpret: bool = False):
+    """x: (N_o, B, P) fp32 node-major (``full_kernel.node_major``) ->
+    logits (B, n_targets) fp32.
 
-    ``B % block_b == 0`` (callers pad via autotune.pad_batch).
+    ``B % block_b == 0`` (callers pad via ``node_major``).
     ``fr_arrays = [w1r, w1s, b1, w2, b2, ...]`` from split_first_layer.
     ``scales`` — fp32 vector of per-weight-tensor dequant scales, in
     weight order [w1r, w1s, w2.., fo.., phi..], required iff any weight
     array is an integer dtype (in-kernel int8 dequant).
     """
-    bsz, n_o, p = x.shape
+    n_o, bsz, p = x.shape
     n_fr = 1 + (len(fr_arrays) - 3) // 2
     n_fo = len(fo_arrays) // 2
-    n_phi = len(phi_arrays) // 2
     weights = [*fr_arrays, *fo_arrays, *phi_arrays]
-    quantized = any(_is_int(w) for w in weights)
-    compute_dtype = x.dtype
 
     if bsz % block_b != 0:
         from repro.kernels.jedi_linear import autotune as jl_autotune
@@ -139,27 +112,16 @@ def jedi_linear_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
             f"block_b={block_b} at modeled {modeled} VMEM bytes/sample — "
             f"pad the batch with autotune.pad_batch(x, {block_b}) (kernel "
             f"wrappers do this automatically)")
-    if quantized:
-        n_w = len(weights) // 2 + 1                  # +1: w1 split in two
-        if scales is None:
-            raise ValueError(
-                "int8 weight arrays need their dequant scales: pass "
-                "scales=[s_w1r, s_w1s, s_w2, ...] (one per weight tensor)")
-        scales = jnp.asarray(scales, jnp.float32).reshape(1, -1)
-        if scales.shape[1] != n_w:
-            raise ValueError(
-                f"got {scales.shape[1]} scales for {n_w} weight tensors")
-
-    grid = (bsz // block_b,)
+    scales = check_scales(weights, scales)
 
     def wmap(ndim):
         def m(i):
             return (0,) * ndim
         return m
 
-    in_specs = [pl.BlockSpec((block_b, n_o, p), lambda i: (i, 0, 0))]
+    in_specs = [pl.BlockSpec((n_o, block_b, p), lambda i: (0, i, 0))]
     operands = [x]
-    if quantized:
+    if scales is not None:
         in_specs.append(pl.BlockSpec(scales.shape, wmap(scales.ndim)))
         operands.append(scales)
     for w in weights:
@@ -168,11 +130,11 @@ def jedi_linear_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
 
     kernel = functools.partial(
         _linear_forward_kernel, activation=activation, n_fr=n_fr, n_fo=n_fo,
-        n_phi=n_phi, n_o=n_o, quantized=quantized,
-        compute_dtype=compute_dtype)
+        n_o=n_o, quantized=scales is not None,
+        compute_dtype=jnp.dtype(compute_dtype))
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bsz // block_b,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_b, n_targets), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_targets), jnp.float32),

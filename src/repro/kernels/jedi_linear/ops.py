@@ -24,8 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.fused_jedinet import full_kernel as FK
-from repro.kernels.fused_jedinet import kernel as K
-from repro.kernels.fused_jedinet.ops import is_quantized_params
+from repro.kernels.fused_jedinet.ops import whole_network_operands
 from repro.kernels.jedi_linear import autotune
 from repro.kernels.jedi_linear import linear_kernel as LK
 
@@ -41,30 +40,13 @@ def jedi_linear_forward_full(params, cfg, x, *, interpret: bool = False,
     autotuner; pass it explicitly to pin the tile (tests).
     """
     cdt = jnp.dtype(cfg.compute_dtype)
-    quantized = is_quantized_params(params)
-    fr = K.split_first_layer(params["fr"], cfg.n_features, dtype=cdt)
-    fr_arrays = [fr[0], fr[1], fr[2], *fr[3]]
-    fo_arrays = FK.flatten_mlp(params["fo"], cdt)
-    phi_arrays = FK.flatten_mlp(params["phi"], cdt)
-    scales = None
-    if quantized:
-        s_fr = FK.mlp_scales(params["fr"])
-        # w1 splits into (w1r, w1s): both halves share w1's tensor scale
-        scales = [s_fr[0], s_fr[0], *s_fr[1:],
-                  *FK.mlp_scales(params["fo"]), *FK.mlp_scales(params["phi"])]
-
-    if block_b is None:
-        block_b = autotune.pick_block_b_linear(
-            x.shape[0], cfg.n_objects, cfg.n_features,
-            autotune.mlp_widths(params["fr"]),
-            autotune.mlp_widths(params["fo"]),
-            autotune.mlp_widths(params["phi"]),
-            reserved_bytes=autotune.weight_vmem_bytes(
-                params, cfg.compute_dtype))
-    bsz = x.shape[0]
-    xp = autotune.pad_batch(x.astype(cdt), block_b)
+    fr_arrays, fo_arrays, phi_arrays, scales = whole_network_operands(
+        params, cfg)
+    block_b = autotune.modeled_residency(cfg, params, x.shape[0],
+                                         block_b=block_b)["block_b"]
     out = LK.jedi_linear_kernel_call(
-        xp, fr_arrays, fo_arrays, phi_arrays,
+        FK.node_major(x, cdt, block_b), fr_arrays, fo_arrays, phi_arrays,
         activation=cfg.activation, n_targets=cfg.n_targets,
-        block_b=block_b, scales=scales, interpret=interpret)
-    return out[:bsz]
+        block_b=block_b, compute_dtype=cdt, scales=scales,
+        interpret=interpret)
+    return out[:x.shape[0]]

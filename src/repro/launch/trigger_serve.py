@@ -1,7 +1,7 @@
 """Streaming L1-trigger serving CLI over the serving engine.
 
     PYTHONPATH=src python -m repro.launch.trigger_serve \
-        --n-objects 30 --batch 256 --batches 40 --forward fused_full
+        --arch jedinet-30p --batch 256 --batches 40 --forward fused_full
 
 The LHC L1 trigger is a hard-real-time stream: events arrive at a fixed
 rate and every event must be classified within the trigger latency budget
@@ -25,7 +25,8 @@ SEAM[:TIMES[:DELAY_S]]`` arms the fault-injection harness
 (:mod:`repro.serving.faults`) and serves through the guarded
 per-request path (see EXPERIMENTS.md §Fault drills); ``--list-paths``
 prints the forward-path registry with each path's fallback chain and
-bucket policy.
+bucket policy.  The exit code is 1 when the stream was not served by
+the requested path (outside ``--drill``).
 """
 
 from __future__ import annotations
@@ -47,4 +48,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

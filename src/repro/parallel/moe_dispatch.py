@@ -118,7 +118,7 @@ def a2a_moe(x, params, moe_cfg, mesh, axis_name: str = "data"):
     """
     from jax.sharding import PartitionSpec as P
     from repro.models.moe import capacity
-    from repro.parallel.sharding import shard_map_compat
+    from repro.parallel.sharding import shard_map_unchecked
 
     n_shards = mesh.shape[axis_name]
     t = x.shape[0]
@@ -127,7 +127,7 @@ def a2a_moe(x, params, moe_cfg, mesh, axis_name: str = "data"):
     fn = partial(a2a_moe_shard, n_experts=moe_cfg.n_experts, cap=cap,
                  axis_name=axis_name, n_shards=n_shards,
                  top_k=moe_cfg.top_k)
-    return shard_map_compat(
+    return shard_map_unchecked(
         fn, mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(axis_name))(x, params)

@@ -21,7 +21,6 @@ SPMD partitioner pads. The roofline analysis charges that padding honestly.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import re
 import threading
 from typing import Optional
@@ -31,26 +30,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.common.tree import path_map
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma.
-_SHARD_MAP_CHECK_KW = (
-    "check_rep" if "check_rep" in inspect.signature(_shard_map).parameters
-    else "check_vma")
-
-
-def shard_map_compat(fn, mesh, *, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions: import location + check kwarg.
-
-    ``check=False`` (the default) disables the replication/VMA check —
-    required for bodies containing ``pallas_call`` (no replication rule)
-    or manual collectives the checker cannot type.
-    """
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SHARD_MAP_CHECK_KW: check})
+def shard_map_unchecked(fn, mesh, *, in_specs, out_specs):
+    """``jax.shard_map`` with the VMA (replication) check off — required
+    for bodies containing ``pallas_call`` (no replication rule) or
+    manual collectives the checker cannot type."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 _CTX = threading.local()

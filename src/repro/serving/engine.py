@@ -45,7 +45,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import paths as forward_paths
 from repro.launch.mesh import make_host_mesh
-from repro.parallel.sharding import shard_map_compat
+from repro.parallel.sharding import shard_map_unchecked
 from repro.serving.core import (  # noqa: F401  (re-exported: historical home)
     MAX_INFLIGHT_CHUNKS,
     ExecutionCore,
@@ -80,14 +80,19 @@ class TriggerWorkload(Workload):
         self.params = self.spec.prepare_params(params)
         self.cfg = cfg
         self.name = forward
-        # compiled Pallas needs a real TPU; fall back to interpret elsewhere
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = bool(interpret) and self.spec.pallas
         if mesh == "auto":
             mesh = make_host_mesh() if len(jax.devices()) > 1 else None
         self.mesh = mesh
         self.n_shards = int(np.prod(mesh.devices.shape)) if mesh else 1
+        # compiled Pallas needs a TPU: decided once, from the platform of
+        # the device this workload runs on.  An explicit value is obeyed:
+        # interpret=False off-TPU fails to compile instead of quietly
+        # running the interpreter
+        self.platform = (mesh.devices.flat[0] if mesh is not None
+                         else jax.devices()[0]).platform
+        if interpret is None:
+            interpret = self.platform != "tpu"
+        self.interpret = bool(interpret) and self.spec.pallas
 
     def bucket_ladder(self, max_batch: int) -> list[int]:
         # ceil so the top rung still covers max_batch after the
@@ -121,12 +126,16 @@ class TriggerWorkload(Workload):
         cfg = self.cfg
 
         def call(params, x):
-            return fn(params, cfg, x)
+            # fp32 matmuls at fp32 on every rung: a TPU's default
+            # precision would run them as one bf16 pass (Pallas kernels
+            # set their precision explicitly and are unaffected)
+            with jax.default_matmul_precision("highest"):
+                return fn(params, cfg, x)
 
         if self.mesh is not None:
-            call = shard_map_compat(call, self.mesh,
-                                    in_specs=(P(), P("data")),
-                                    out_specs=P("data"))
+            call = shard_map_unchecked(call, self.mesh,
+                                       in_specs=(P(), P("data")),
+                                       out_specs=P("data"))
         return jax.jit(functools.partial(call, self.params))
 
     def placeholder(self, bucket: int) -> np.ndarray:
@@ -186,6 +195,10 @@ class ServingEngine(ExecutionCore):
     @property
     def interpret(self) -> bool:
         return self.workload.interpret
+
+    @property
+    def platform(self) -> str:
+        return self.workload.platform
 
     @property
     def mesh(self):

@@ -73,11 +73,16 @@ class NonFiniteOutput(RuntimeError):
     """A rung returned NaN/Inf logits — numerics failure, demote."""
 
 
+def _describe(rung: str, exc: Exception) -> str:
+    """What ``health()`` keeps of a rung failure."""
+    return f"{rung}: {type(exc).__name__}: {exc}"
+
+
 class _BucketState:
     """Ladder position + probe schedule for one compile bucket."""
 
     __slots__ = ("level", "backoff_s", "next_probe", "demotions", "down",
-                 "quarantined", "q_level", "clean")
+                 "quarantined", "q_level", "clean", "last_error")
 
     def __init__(self, level: int, backoff_s: float):
         self.level = level           # active chain index (0 = primary)
@@ -88,6 +93,7 @@ class _BucketState:
         self.quarantined = False     # sentinel caught silent corruption
         self.q_level: int | None = None   # the quarantined rung
         self.clean = 0               # consecutive clean canaries at q_level
+        self.last_error: str | None = None   # "rung: Type: message"
 
 
 class ResilientPending:
@@ -181,7 +187,7 @@ class ResilientEngine:
         self._interpret = interpret  # its own transform at construction
         self._mesh = mesh
         self._max_batch = int(max_batch)
-        self._construct_failed: set[int] = set()
+        self._construct_failed: dict[int, str] = {}   # level -> error
         self._inflight: list[ResilientPending] = []
         self._last_shed: float | None = None
         self._last_down: float | None = None
@@ -201,7 +207,7 @@ class ResilientEngine:
                     bucket_sizes=bucket_sizes, max_batch=max_batch,
                     metrics=self.metrics, injector=injector)
             except Exception as e:    # noqa: BLE001 — rung skip, counted
-                self._construct_failed.add(lvl)
+                self._construct_failed[lvl] = _describe(self.chain[lvl], e)
                 self.metrics.incr("construct_failures")
                 err = e
                 continue
@@ -240,6 +246,10 @@ class ResilientEngine:
     @property
     def interpret(self) -> bool:
         return self._engines[self._base_level].interpret
+
+    @property
+    def platform(self) -> str:
+        return self._engines[self._base_level].platform
 
     def bucket_for(self, n_events: int) -> int:
         return self._engines[self._base_level].bucket_for(n_events)
@@ -282,6 +292,7 @@ class ResilientEngine:
                 "next_probe_in_s": (
                     None if st.next_probe is None
                     else max(0.0, st.next_probe - now)),
+                "last_error": st.last_error,
             }
         recent = (self._last_shed is not None
                   and now - self._last_shed < self.shed_window_s)
@@ -298,6 +309,7 @@ class ResilientEngine:
             state = "healthy"
         report = {"state": state, "chain": list(self.chain),
                   "base_path": self.chain[self._base_level],
+                  "construct_errors": dict(self._construct_failed),
                   "buckets": buckets, "inflight": len(self._inflight),
                   "counters": self.metrics.counters,
                   "gauges": self.metrics.gauges}
@@ -321,8 +333,8 @@ class ResilientEngine:
                     bucket_sizes=self.bucket_sizes,
                     max_batch=self._max_batch, metrics=self.metrics,
                     injector=self.injector)
-            except Exception:
-                self._construct_failed.add(level)
+            except Exception as e:
+                self._construct_failed[level] = _describe(self.chain[level], e)
                 self.metrics.incr("construct_failures")
                 raise
             self._engines[level] = eng
@@ -400,10 +412,11 @@ class ResilientEngine:
 
     def _rung_failed(self, st: _BucketState, level: int, now: float,
                      exc: Exception) -> None:
-        """Bookkeeping for one failed serve attempt at ``level``: demote
-        below it (if not already), schedule the next probe with
-        exponential backoff."""
+        """Bookkeeping for one failed serve attempt at ``level``: keep the
+        error text, demote below it (if not already), schedule the next
+        probe with exponential backoff."""
         self._count_failure(exc)
+        st.last_error = _describe(self.chain[level], exc)
         # clamp: a terminal-rung failure marks the bucket down (caller),
         # it must not index the level past the chain
         demote_to = min(level + 1, len(self.chain) - 1)

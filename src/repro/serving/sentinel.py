@@ -57,6 +57,7 @@ import dataclasses
 import queue
 import threading
 
+import jax
 import numpy as np
 
 from repro.core import paths as forward_paths
@@ -120,8 +121,11 @@ class Sentinel:
             spec = forward_paths.get(name)
             try:
                 prepared = spec.prepare_params(engine._params)
-                self._golden[lvl] = np.asarray(
-                    spec.ref(prepared, cfg, self._canary_x), np.float32)
+                # the oracle at full fp32 matmul precision, as the rungs
+                # serve it (a TPU's default is one bf16 pass)
+                with jax.default_matmul_precision("highest"):
+                    golden = spec.ref(prepared, cfg, self._canary_x)
+                self._golden[lvl] = np.asarray(golden, np.float32)
             except Exception:   # noqa: BLE001 — a rung without a golden
                 pass            # just cannot canary (counted per canary)
 

@@ -10,7 +10,11 @@ and the benchmarks can reuse it:
   generation stays off the timed path;
 * :func:`run_trigger_cli` — the whole serve flow: registry listing,
   fault drills through the guarded per-request path, the double-
-  buffered stream run with roofline context, and the health report;
+  buffered stream run with roofline context, and the health report.
+  Outside a drill it returns 1, naming the error, when the stream was
+  not served by the requested path: the ladder keeps serving, but an
+  operator who asked for a kernel must not be handed its fallback
+  silently;
 * :func:`print_health` — the health state machine's operator view.
 
 Output formats are part of the CLI contract (tests assert on them);
@@ -24,8 +28,10 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compile_cache import setup_compile_cache
+from repro.configs.registry import ARCH_MODULES, get_arch
 from repro.core import paths
-from repro.core.interaction_net import JediNetConfig, init
+from repro.core.interaction_net import init
 from repro.data.jets import make_jets
 from repro.serving.faults import SILENT_SEAMS, FaultInjector
 from repro.serving.resilient import ResilientEngine
@@ -46,6 +52,8 @@ def print_health(engine) -> None:
     h = engine.health()
     print(f"[health] state={h['state']} base={h['base_path']} "
           f"chain={'>'.join(h['chain'])} inflight={h['inflight']}")
+    for err in h["construct_errors"].values():
+        print(f"  construct error: {err}")
     for bucket, st in h["buckets"].items():
         probe = ("-" if st["next_probe_in_s"] is None
                  else f"{st['next_probe_in_s']:.2f}s")
@@ -56,6 +64,8 @@ def print_health(engine) -> None:
         print(f"  bucket {bucket:>5}: path={st['path']} level={st['level']} "
               f"demotions={st['demotions']} next_probe_in={probe}"
               f"{quarantine}{' DOWN' if st['down'] else ''}")
+        if st["last_error"]:
+            print(f"    last error: {st['last_error']}")
     if h.get("sentinel"):
         s = h["sentinel"]
         print(f"  sentinel: canary_every={s['canary_every']} "
@@ -91,8 +101,11 @@ def parse_drills(specs, injector, path) -> None:
 
 def build_trigger_cli(ap) -> None:
     """Install the trigger-serve arguments on an ``argparse`` parser."""
-    ap.add_argument("--n-objects", type=int, default=30)
-    ap.add_argument("--n-features", type=int, default=16)
+    ap.add_argument("--arch", default="jedinet-30p",
+                    choices=[a for a in ARCH_MODULES
+                             if a.startswith("jedinet")],
+                    help="JEDI-net model at its published widths "
+                         "(configs/registry.py)")
     ap.add_argument("--batch", type=int, default=256,
                     help="events per stream tick (the trigger's time slice)")
     ap.add_argument("--batches", type=int, default=40)
@@ -134,24 +147,21 @@ def build_trigger_cli(ap) -> None:
     ap.add_argument("--seed", type=int, default=0)
 
 
-def run_trigger_cli(args) -> None:
-    """Serve a synthetic stream per parsed ``args`` and print the report."""
+def run_trigger_cli(args) -> int:
+    """Serve a synthetic stream per parsed ``args`` and print the report.
+    Returns the process exit code."""
+    cfg = get_arch(args.arch).model.with_(compute_dtype=args.compute_dtype)
+    params = init(jax.random.PRNGKey(args.seed), cfg)
     if args.list_paths:
         # Registry table PLUS each path's resolved bucket policy (per-
         # sample VMEM model, weight residency, the ladder it earns) for
         # this CLI's config — the operator-facing answer to "why does
         # the quantized path get deeper buckets than fp32?".
-        cfg = JediNetConfig(n_objects=args.n_objects,
-                            n_features=args.n_features,
-                            compute_dtype=args.compute_dtype)
-        params = init(jax.random.PRNGKey(args.seed), cfg)
         print(paths.describe(cfg=cfg, params=params,
                              max_batch=max(args.batch, 1)))
-        return
+        return 0
 
-    cfg = JediNetConfig(n_objects=args.n_objects, n_features=args.n_features,
-                        compute_dtype=args.compute_dtype)
-    params = init(jax.random.PRNGKey(args.seed), cfg)
+    setup_compile_cache()
     injector = None
     if args.drill:
         injector = FaultInjector()
@@ -170,9 +180,11 @@ def run_trigger_cli(args) -> None:
                              watchdog_s=args.watchdog_s,
                              sentinel=sentinel)
 
+    print(f"[trigger_serve] arch={args.arch} platform={engine.platform} "
+          f"interpret={engine.interpret} devices={engine.n_shards}")
     rng = np.random.RandomState(args.seed)
-    stream = make_stream(rng, args.batches, args.batch, args.n_objects,
-                         args.n_features)
+    stream = make_stream(rng, args.batches, args.batch, cfg.n_objects,
+                         cfg.n_features)
 
     if args.drill:
         # guarded per-request path: every batch rides the full ladder —
@@ -196,23 +208,33 @@ def run_trigger_cli(args) -> None:
         print(f"  latency    p50 {snap['p50_us']:8.1f} us   "
               f"p99 {snap['p99_us']:8.1f} us  per batch")
         print_health(engine)
-        return
+        return 0
 
     res = engine.run_stream(stream, warmup=args.warmup)
+    bucket = engine.bucket_for(args.batch)
+    served_by = engine.active_path(bucket)
+    if served_by != args.forward:
+        h = engine.health()
+        errors = [*h["construct_errors"].values(),
+                  h["buckets"][bucket]["last_error"]]
+        print(f"[trigger_serve] FAILED: the stream was served by "
+              f"{served_by!r}, not the requested path {args.forward!r}; "
+              f"error: {next(e for e in errors if e)}")
+        print_health(engine)
+        return 1
 
     if not res["latencies"]:
         print("[trigger_serve] stream too short for stats "
               f"(need > warmup={args.warmup} batches, got {args.batches})")
         if args.health:
             print_health(engine)
-        return
+        return 0
 
     snap = engine.metrics.snapshot()
-    bucket = res["bucket"]
     model = engine.roofline([bucket])[bucket]
 
     print(f"[trigger_serve] forward={args.forward} "
-          f"n_objects={args.n_objects} batch={args.batch} bucket={bucket} "
+          f"n_objects={cfg.n_objects} batch={args.batch} bucket={bucket} "
           f"dtype={args.compute_dtype} shards={engine.n_shards}")
     print(f"  sustained  {snap['kgps']:8.1f} KGPS  "
           f"({res['events']} events / {res['wall_s']:.3f} s)")
@@ -222,7 +244,8 @@ def run_trigger_cli(args) -> None:
     print(f"  roofline   modeled {model['step_us']:.1f} us/step "
           f"({model['bound']}-bound, {model['hbm_bytes'] / 1e6:.2f} MB HBM, "
           f"level={model['fused_level']})")
-    print(f"  serving    path={engine.active_path(bucket)} "
+    print(f"  serving    path={served_by} "
           f"(chain {'>'.join(engine.chain)})")
     if args.health:
         print_health(engine)
+    return 0

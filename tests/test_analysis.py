@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.analysis.config import AnalysisConfig, _parse_toml_subset
+from repro.analysis.config import AnalysisConfig
 from repro.analysis.findings import Finding
 from repro.analysis.kernel_audit import audit_path, audit_registry
 from repro.analysis.lint import run_lint
@@ -298,28 +298,8 @@ def test_regression_gate_audit_hint_stays_quiet_on_clean_paths(capsys):
 
 
 # ---------------------------------------------------------------------------
-# Config loader (incl. the 3.10 no-tomllib fallback parser).
+# Config loader.
 # ---------------------------------------------------------------------------
-
-def test_toml_subset_parser_multiline_arrays_and_comments():
-    data = _parse_toml_subset(
-        "# header comment\n"
-        "[rules.some-rule]\n"
-        "allow = [\n"
-        '    "a.py",   # trailing comment\n'
-        '    "b/*.py",\n'
-        "]\n"
-        "limit = 5\n"
-        "strict = true\n")
-    table = data["rules"]["some-rule"]
-    assert table["allow"] == ["a.py", "b/*.py"]
-    assert table["limit"] == 5 and table["strict"] is True
-
-
-def test_toml_subset_parser_rejects_garbage():
-    with pytest.raises(ValueError):
-        _parse_toml_subset("[rules.x]\nallow = {oops}\n")
-
 
 def test_allowlist_glob_matching():
     cfg = AnalysisConfig(allow={"r": ["docs/*.md", "exact.py"]})

@@ -152,9 +152,30 @@ def test_compile_failure_demotes_and_fallback_serves(jedi8):
     assert h["state"] == "degraded"
     (detail,) = h["buckets"].values()
     assert detail["path"] == "sr_split" and detail["demotions"] == 1
+    # the failure is kept, not just counted: rung, type and message
+    assert detail["last_error"].startswith(
+        "fused_full: InjectedFault: injected compile fault")
+    assert h["construct_errors"] == {}
     assert eng.metrics.counter("compile_failures") == 1
     assert eng.metrics.counter("demotions") == 1
     assert eng.metrics.counter("fallback_batches") == 1
+
+
+def test_unconstructible_primary_is_skipped_and_named(jedi8):
+    """A primary rung that cannot even be built for the config (int8
+    paths compute in fp32 only) is skipped for good; health() keeps its
+    error so an operator sees why the base path moved."""
+    cfg, params, x, _ = jedi8
+    eng = ResilientEngine(params, cfg.with_(compute_dtype="bfloat16"),
+                          forward="int8_fused_full", interpret=True,
+                          max_batch=8)
+    h = eng.health()
+    assert h["base_path"] == "fused_full"
+    (err,) = h["construct_errors"].values()
+    assert err.startswith("int8_fused_full: ValueError:")
+    assert "compute dtypes" in err
+    assert eng.metrics.counter("construct_failures") == 1
+    assert np.isfinite(eng.infer(x)).all()
 
 
 def test_jedi_linear_full_demotes_to_xla_same_model(jedi8):
@@ -455,7 +476,7 @@ def test_resilient_engine_rejects_chain_without_terminal():
 def test_drill_cli_serves_and_reports_health(capsys):
     from repro.launch import trigger_serve
     trigger_serve.main([
-        "--forward", "fused_full", "--interpret", "--n-objects", "8",
+        "--forward", "fused_full", "--interpret",
         "--batch", "4", "--batches", "4", "--drill", "output_nan:99"])
     out = capsys.readouterr().out
     assert "DRILL" in out and "served=4" in out and "shed=0" in out

@@ -111,11 +111,12 @@ def test_pick_block_b_prime_batch_not_degraded():
 
 
 def test_pick_block_b_respects_budget_and_batch():
-    assert autotune.pick_block_b(4, 1024) == 4          # capped by batch
+    # capped by the batch, rounded up to one whole sublane tile
+    assert autotune.pick_block_b(4, 1024) == 8
     huge = autotune.VMEM_BUDGET_BYTES                   # 1 sample fills VMEM
-    assert autotune.pick_block_b(1024, huge) == 1
-    # whole batch fits -> one grid step, zero padding (no forced alignment)
-    assert autotune.pick_block_b(100, 1024) == 100
+    assert autotune.pick_block_b(1024, huge) == 8       # one-tile floor
+    # whole batch fits -> one grid step, padded to a sublane multiple
+    assert autotune.pick_block_b(100, 1024) == 104
     assert autotune.pick_block_b(1024, 1) == 1024
 
 

@@ -164,7 +164,7 @@ def test_kernel_rejects_non_divisible_batch():
     frs = K.split_first_layer(params["fr"], cfg.n_features, dtype=cdt)
     with pytest.raises(ValueError, match="pad_batch"):
         LK.jedi_linear_kernel_call(
-            x, [frs[0], frs[1], frs[2], *frs[3]],
+            jnp.transpose(x, (1, 0, 2)), [frs[0], frs[1], frs[2], *frs[3]],
             FK.flatten_mlp(params["fo"], cdt),
             FK.flatten_mlp(params["phi"], cdt),
             activation=cfg.activation, n_targets=cfg.n_targets,

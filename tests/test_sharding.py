@@ -9,6 +9,9 @@ test session was started with XLA_FLAGS device_count>1 (see
 tests/test_multidevice.py for the subprocess-based version).
 """
 
+import os
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,10 +90,13 @@ def _run_with_fake_devices(code: str) -> str:
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=256'\n"
             + textwrap.dedent(code))
+    # the child always runs on the CPU: on a machine with a TPU the
+    # pytest process may hold the chip
     out = subprocess.run(
         [sys.executable, "-c", prog], capture_output=True, text=True,
-        timeout=600, cwd="/root/repo",
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"})
+        timeout=600, cwd=pathlib.Path(__file__).resolve().parents[1],
+        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 

@@ -31,18 +31,18 @@ def _setup(n_o, fr_hidden, fo_hidden, batch, **cfg_kw):
 
 
 @pytest.mark.parametrize("block_s", [
-    5,       # block_s ∤ N_o: remainder sender tile, bounds mask live
-    8,       # sublane tile, 13 = 8 + 5 remainder
-    13,      # block_s == N_o: degenerate single sender step (old kernel)
+    5,       # block_s ∤ N_o: rounds down to the divisor 4 (3 sender steps)
+    6,       # a divisor of N_o = 12: 2 sender steps
+    12,      # block_s == N_o: degenerate single sender step (untiled)
     16,      # block_s > N_o: clamped to N_o
 ])
 @pytest.mark.parametrize("block_b", [1, 3, 4])
 def test_tiled_matches_reference_across_corner_tiles(block_s, block_b):
-    """Every (block_b, block_s) combination — remainder sender tiles,
+    """Every (block_b, block_s) combination — several sender steps,
     degenerate full-axis tiles, non-dividing batch tiles — matches the
     path's declared reference within its declared tolerance."""
     spec = paths.get("fused_full")
-    cfg, params, x = _setup(13, (16, 12), (10,), 7)
+    cfg, params, x = _setup(12, (16, 12), (10,), 7)
     ref = spec.ref(params, cfg, x)
     out = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
                                     block_b=block_b, block_s=block_s)
@@ -52,25 +52,26 @@ def test_tiled_matches_reference_across_corner_tiles(block_s, block_b):
 
 @pytest.mark.parametrize("batch", [1, 3, 7, 11])
 def test_tiled_prime_batches_with_sender_remainder(batch):
-    """Prime batches (padded batch tiles) x non-dividing sender tiles."""
+    """Prime batches (padded batch tiles) x a non-dividing pinned sender
+    tile (8 rounds down to 6, the largest divisor of 30 below it)."""
     spec = paths.get("fused_full")
     cfg, params, x = _setup(30, (20, 20, 20), (20, 20, 20), batch)
     ref = spec.ref(params, cfg, x)
     out = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
-                                    block_b=4, block_s=8)   # 30 = 3*8 + 6
+                                    block_b=4, block_s=8)   # 30 = 5*6
     assert out.shape == (batch, cfg.n_targets)
     err = float(jnp.max(jnp.abs(ref - out)))
     assert err < spec.tolerance, (batch, err)
 
 
 def test_block_s_degenerate_equals_untiled_summand_order():
-    """block_s = N_o is ONE sender step — bitwise the old untiled kernel
-    (same mask, same single-chunk accumulation); other tilings agree to
-    fp32 reassociation noise only."""
-    cfg, params, x = _setup(13, (16, 12), (10,), 4)
+    """block_s = N_o is ONE sender step, the untiled kernel; tilings
+    with several sender steps agree with it to fp32 reassociation noise
+    only."""
+    cfg, params, x = _setup(12, (16, 12), (10,), 4)
     full = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
-                                     block_b=4, block_s=13)
-    for bs in (5, 8):
+                                     block_b=4, block_s=12)
+    for bs in (4, 6):
         tiled = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
                                           block_b=4, block_s=bs)
         np.testing.assert_allclose(np.asarray(full), np.asarray(tiled),
@@ -78,12 +79,12 @@ def test_block_s_degenerate_equals_untiled_summand_order():
 
 
 def test_tiled_bf16_compute_dtype_threads_through():
-    cfg, params, x = _setup(13, (16, 12), (10,), 4)
+    cfg, params, x = _setup(12, (16, 12), (10,), 4)
     fp32 = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
-                                     block_s=5)
+                                     block_s=4)
     bcfg = cfg.with_(compute_dtype="bfloat16")
     bf16 = fj_ops.fused_forward_full(params, bcfg, x, interpret=True,
-                                     block_s=5)
+                                     block_s=4)
     assert bf16.dtype == jnp.float32
     err = float(jnp.max(jnp.abs(fp32 - bf16)))
     scale = float(jnp.max(jnp.abs(fp32)))
@@ -94,19 +95,19 @@ def test_unpadded_batch_raises_with_tile_and_vmem_context():
     """The kernel-call guard names the chosen (block_b, block_s) and the
     modeled VMEM bytes — not the bare (bsz, block_b) tuple — so a caller
     that skipped autotune.pad_batch sees what to pad to and why."""
-    cfg, params, x = _setup(13, (16, 12), (10,), 7)
+    cfg, params, x = _setup(12, (16, 12), (10,), 7)
     cdt = jnp.dtype(cfg.compute_dtype)
     from repro.kernels.fused_jedinet import kernel as K
     fr = K.split_first_layer(params["fr"], cfg.n_features, dtype=cdt)
     with pytest.raises(ValueError) as ei:
         FK.fused_forward_full_kernel_call(
-            x.astype(cdt), [fr[0], fr[1], fr[2], *fr[3]],
+            jnp.transpose(x, (1, 0, 2)), [fr[0], fr[1], fr[2], *fr[3]],
             FK.flatten_mlp(params["fo"], cdt),
             FK.flatten_mlp(params["phi"], cdt),
             activation=cfg.activation, n_targets=cfg.n_targets,
-            block_b=4, block_s=5, interpret=True)
+            block_b=4, block_s=6, interpret=True)
     msg = str(ei.value)
-    assert "block_b=4" in msg and "block_s=5" in msg
+    assert "block_b=4" in msg and "block_s=6" in msg
     assert "VMEM" in msg and "pad_batch" in msg
 
 
@@ -115,10 +116,10 @@ def test_unpadded_batch_raises_with_tile_and_vmem_context():
 
 @pytest.fixture(scope="module")
 def qsetup():
-    cfg = inet.JediNetConfig(n_objects=13, n_features=16,
+    cfg = inet.JediNetConfig(n_objects=12, n_features=16,
                              fr_hidden=(16, 12), fo_hidden=(10,))
     params = inet.init(jax.random.PRNGKey(0), cfg, scale="lecun")
-    x, _ = make_jets(np.random.RandomState(1), 5, 13)
+    x, _ = make_jets(np.random.RandomState(1), 5, 12)
     return cfg, quantize_params_int8(params), jnp.asarray(x)
 
 
@@ -135,7 +136,7 @@ def test_int8_weights_reach_the_kernel_as_int8(qsetup):
     assert fj_ops.is_quantized_params(qp)
 
 
-@pytest.mark.parametrize("block_s", [5, 13])
+@pytest.mark.parametrize("block_s", [4, 12])
 def test_int8_in_kernel_matches_hbm_boundary_dequant(qsetup, block_s):
     """In-kernel dequant ((h @ W_q) * scale on the fp32 accumulator) vs
     the PR-4 scheme (dequantize at the HBM boundary, kernel sees fp32
@@ -213,17 +214,19 @@ def test_pick_block_b_s_grows_block_b_at_50p():
 
 
 def test_pick_block_b_s_degenerates_to_untiled_for_small_batches():
-    """When the whole batch fits at every sender tile, ties break to
-    block_s = N_o — zero sender-loop overhead, the old kernel."""
+    """When the whole batch fits untiled, one grid step is the fewest
+    and ties break to block_s = N_o — zero sender-loop overhead."""
     fr, fo, phi = _w50()
-    bb, bs = autotune.pick_block_b_s(4, 50, 16, fr, fo, phi)
-    assert (bb, bs) == (4, 50)
+    bb, bs = autotune.pick_block_b_s(4, 16, 16, fr, fo, phi)
+    assert (bb, bs) == (8, 16)
 
 
 def test_sender_tile_candidates_cover_remainders():
-    assert autotune.sender_tile_candidates(50) == [8, 16, 32, 50]
-    assert autotune.sender_tile_candidates(128) == [8, 16, 32, 64, 128]
-    assert autotune.sender_tile_candidates(5) == [5]
+    """Candidates divide N_o, so no sender tile is a clamped remainder."""
+    assert autotune.sender_tile_candidates(50) == [1, 2, 5, 10, 25, 50]
+    assert autotune.sender_tile_candidates(128) == [1, 2, 4, 8, 16, 32,
+                                                    64, 128]
+    assert autotune.sender_tile_candidates(5) == [1, 5]
 
 
 @pytest.mark.parametrize("batch", [1, 2, 4])
@@ -312,16 +315,21 @@ def test_quantized_path_earns_deeper_ladder_when_weights_dominate():
     qparams = q_spec.prepare_params(params)
     fp_ladder = fp_spec.bucket_ladder(cfg, params, 4096)
     q_ladder = q_spec.bucket_ladder(cfg, qparams, 4096)
-    assert q_spec.reserved_vmem_bytes(cfg, qparams) < \
-        fp_spec.reserved_vmem_bytes(cfg, params)
-    # same per-sample model, smaller reservation -> larger VMEM tile:
-    # the first rung past the sublane doublings IS the tile
-    assert q_ladder != fp_ladder
-    assert q_ladder[1] > fp_ladder[1]
-    # rung-for-rung the quantized ladder is at least as deep (the final
-    # rung is max_batch padded to the tile, so it is excluded)
-    for q_b, fp_b in zip(q_ladder[:-1], fp_ladder[:-1]):
-        assert q_b >= fp_b
+    q_res = q_spec.reserved_vmem_bytes(cfg, qparams)
+    fp_res = fp_spec.reserved_vmem_bytes(cfg, params)
+    assert q_res < fp_res
+    # same per-sample model, smaller reservation -> larger VMEM tile,
+    # and the tile is a rung of the ladder the engine serves
+    per = fp_spec.bucket_bytes(cfg, params)
+    assert per == q_spec.bucket_bytes(cfg, qparams)
+
+    def tile(reserved):
+        return shared_autotune.pick_block_b(
+            4096, per, shared_autotune.effective_budget(
+                shared_autotune.VMEM_BUDGET_BYTES, reserved))
+
+    assert tile(q_res) > tile(fp_res)
+    assert tile(q_res) in q_ladder and tile(fp_res) in fp_ladder
 
 
 def test_path_bucket_policy_surface():
@@ -353,7 +361,7 @@ def test_describe_with_cfg_prints_resolved_policy():
 
 def test_trigger_serve_list_paths_prints_policy(capsys):
     from repro.launch import trigger_serve
-    trigger_serve.main(["--list-paths", "--n-objects", "16", "--batch", "32"])
+    trigger_serve.main(["--list-paths", "--batch", "32"])
     out = capsys.readouterr().out
     assert "wB" in out                     # weight-bytes column
     assert "float32" in out                # compute dtypes

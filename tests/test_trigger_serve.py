@@ -90,8 +90,7 @@ def test_make_stream_shapes():
 
 
 def test_cli_main_reports_stats_through_engine(capsys):
-    trigger_serve.main(["--forward", "sr", "--n-objects", "8",
-                        "--batch", "8", "--batches", "5", "--warmup", "1"])
+    trigger_serve.main(["--forward", "sr", "--batch", "8", "--batches", "5", "--warmup", "1"])
     out = capsys.readouterr().out
     assert "sustained" in out and "KGPS" in out
     assert "p50" in out and "p99" in out
@@ -99,8 +98,7 @@ def test_cli_main_reports_stats_through_engine(capsys):
 
 
 def test_cli_main_short_stream_prints_hint(capsys):
-    trigger_serve.main(["--forward", "sr", "--n-objects", "8",
-                        "--batch", "4", "--batches", "2"])
+    trigger_serve.main(["--forward", "sr", "--batch", "4", "--batches", "2"])
     out = capsys.readouterr().out
     assert "too short" in out
 
@@ -108,7 +106,7 @@ def test_cli_main_short_stream_prints_hint(capsys):
 def test_cli_main_fused_full_interpret(capsys):
     """The acceptance path, shrunk: fused_full through the engine on CPU."""
     trigger_serve.main(["--forward", "fused_full", "--interpret",
-                        "--n-objects", "8", "--batch", "4", "--batches", "4",
+                        "--batch", "4", "--batches", "4",
                         "--warmup", "1"])
     out = capsys.readouterr().out
     assert "KGPS" in out and "level=full" in out
@@ -118,7 +116,7 @@ def test_cli_list_paths_prints_fallback_chains_and_policy(capsys):
     """--list-paths is the operator's view of the degradation ladder:
     the registry table must carry each path's fallback chain next to
     its resolved bucket policy."""
-    trigger_serve.main(["--list-paths", "--n-objects", "8", "--batch", "16"])
+    trigger_serve.main(["--list-paths", "--batch", "16"])
     out = capsys.readouterr().out
     assert "fallback chain" in out
     assert "fused_full>sr_split" in out      # int8 path's two-rung chain
@@ -126,8 +124,7 @@ def test_cli_list_paths_prints_fallback_chains_and_policy(capsys):
 
 
 def test_cli_health_flag_reports_state(capsys):
-    trigger_serve.main(["--forward", "sr", "--n-objects", "8",
-                        "--batch", "8", "--batches", "5", "--warmup", "1",
+    trigger_serve.main(["--forward", "sr", "--batch", "8", "--batches", "5", "--warmup", "1",
                         "--health"])
     out = capsys.readouterr().out
     assert "[health] state=healthy" in out
@@ -136,8 +133,57 @@ def test_cli_health_flag_reports_state(capsys):
 
 
 def test_cli_reports_serving_path_and_chain(capsys):
-    trigger_serve.main(["--forward", "fused_full", "--interpret",
-                        "--n-objects", "8", "--batch", "4", "--batches", "4",
+    rc = trigger_serve.main(["--forward", "fused_full", "--interpret",
+                        "--batch", "4", "--batches", "4",
                         "--warmup", "1"])
     out = capsys.readouterr().out
+    assert rc == 0
+    assert "platform=cpu interpret=True" in out
     assert "path=fused_full" in out and "chain fused_full>sr_split" in out
+
+
+def test_cli_fails_when_the_requested_path_does_not_serve(capsys,
+                                                          monkeypatch):
+    """Outside --drill the ladder still serves the stream, but a stream
+    served by a fallback rung is a failed run: exit code 1, and the
+    rung's error is printed."""
+    import dataclasses
+
+    from repro.core import paths
+
+    def refused(params, cfg, x, *, interpret=False):
+        raise RuntimeError("kernel refused by the compiler")
+
+    monkeypatch.setitem(paths._REGISTRY, "fused_full", dataclasses.replace(
+        paths.get("fused_full"), forward=refused))
+    rc = trigger_serve.main(["--forward", "fused_full", "--interpret",
+                             "--batch", "4", "--batches", "3",
+                             "--warmup", "1"])
+    out = capsys.readouterr().out
+    assert rc == 1
+    assert "served by 'sr_split'" in out
+    assert "kernel refused by the compiler" in out
+
+
+def test_compile_cache_dir_is_fixed_per_checkout(monkeypatch, tmp_path):
+    """The cache lives at <checkout>/.jax_cache (gitignored) unless
+    JAX_COMPILATION_CACHE_DIR is set — then JAX's own setting applies
+    and the code sets nothing."""
+    import pathlib
+
+    from repro.common.compile_cache import setup_compile_cache
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert setup_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
