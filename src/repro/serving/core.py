@@ -23,7 +23,10 @@ bucket ladder is — is declared by a :class:`Workload`.
   dispatch;
 * **wall-union metrics** — KGPS wall time is the UNION of dispatch
   windows (overlap-safe in any realization order), recorded into a
-  shared :class:`~repro.serving.metrics.ServingMetrics`;
+  shared :class:`~repro.serving.metrics.ServingMetrics`, with counters
+  of rows served (``rows_valid`` / ``rows_bucket`` / ``rows_kernel``)
+  and cache misses (``compiles``), and — while spans are on — the
+  ``engine.launch``, ``engine.wait`` and ``engine.d2h`` spans;
 * **fault seams** — an optional
   :class:`~repro.serving.faults.FaultInjector` is consulted at the
   compile / dispatch / input / output boundaries.
@@ -118,6 +121,11 @@ class Workload:
         """A zero input of the bucket's shape (for :meth:`ExecutionCore.
         warm`)."""
         raise NotImplementedError
+
+    def kernel_rows(self, bucket: int) -> int:
+        """Rows the compiled call computes for a ``bucket``-row input
+        (a kernel may pad to its own tile).  Default: the bucket."""
+        return bucket
 
     # -- silent fault seams (optional) --------------------------------------
 
@@ -230,18 +238,24 @@ class PendingResult:
 
     def result(self, *, timeout_s: float | None = None) -> np.ndarray:
         if self._out is None:
+            metrics = self._engine.metrics
             deadline = (None if timeout_s is None
                         else time.perf_counter() + timeout_s)
-            outs = []
+            ready = []
+            with metrics.span("engine.wait"):
+                for out, *_ in self._chunks:
+                    self._wait_ready(out, deadline)
+                    ready.append(time.perf_counter())
+            with metrics.span("engine.d2h"):
+                self._out = np.concatenate(
+                    [np.asarray(out)[:n_valid]
+                     for out, n_valid, *_ in self._chunks], axis=0)
             t_first, t_last, events = None, None, 0
-            for out, n_valid, bucket, t0 in self._chunks:
-                self._wait_ready(out, deadline)
-                t1 = time.perf_counter()
+            for (_, n_valid, bucket, t0), t1 in zip(self._chunks, ready):
                 if self._record:
-                    self._engine.metrics.record_batch(t1 - t0, n_valid, bucket)
+                    metrics.record_batch(t1 - t0, n_valid, bucket)
                 t_first = t0 if t_first is None else t_first
                 t_last, events = t1, events + n_valid
-                outs.append(np.asarray(out)[:n_valid])
             if self._record and t_first is not None:
                 # ONE wall window for the whole dispatch, merged into the
                 # engine's busy-time union: overlapped chunks AND
@@ -249,7 +263,6 @@ class PendingResult:
                 # order — must not double-count elapsed time (KGPS is
                 # events/wall, not events/sum-of-latencies)
                 self._engine._record_wall_window(t_first, t_last, events)
-            self._out = np.concatenate(outs, axis=0)
             self._chunks = ()            # free device buffers
         return self._out
 
@@ -295,6 +308,7 @@ class ExecutionCore:
         # UNION of dispatch windows, never a double-counted sum
         self._wall_windows: list[tuple[float, float]] = []
         self._cache: dict[tuple, object] = {}
+        self._kernel_rows: dict[int, int] = {}    # bucket -> rows computed
 
     # -- compile-cache management ------------------------------------------
 
@@ -309,6 +323,7 @@ class ExecutionCore:
         key = self.workload.cache_key(bucket)
         fn = self._cache.get(key)
         if fn is None:
+            self.metrics.incr("compiles")
             if self.injector is not None:
                 # compile seam: fires only on a cache MISS — a warm
                 # callable never recompiles, so it cannot re-fail here
@@ -381,6 +396,17 @@ class ExecutionCore:
         from repro.kernels import autotune
         return autotune.bucket_for(self.bucket_sizes, n_events)
 
+    def _count_rows(self, n_valid: int, bucket: int, times: int = 1) -> None:
+        """Rows served: valid, padded to the bucket, and as the kernel
+        computes them (its tile decision for the bucket, asked once)."""
+        rows = self._kernel_rows.get(bucket)
+        if rows is None:
+            rows = self._kernel_rows[bucket] = int(
+                self.workload.kernel_rows(bucket))
+        self.metrics.incr("rows_valid", n_valid * times)
+        self.metrics.incr("rows_bucket", bucket * times)
+        self.metrics.incr("rows_kernel", rows * times)
+
     def warm(self, buckets=None) -> None:
         """Pre-compile (and pre-run once) the given buckets — compile cost
         paid before traffic arrives, not on the first unlucky request."""
@@ -443,7 +469,13 @@ class ExecutionCore:
                     chunk, path=self.workload.name, bucket=bucket)
             fn = self.compiled_for(bucket)
             t0 = time.perf_counter()
-            out = fn(jnp.asarray(self._pad(chunk, bucket)))   # async dispatch
+            xd = jnp.asarray(self._pad(chunk, bucket))
+            # the caller's engine.prep span (run_plan) ends at the call
+            self.metrics.close_span("engine.prep")
+            with self.metrics.span("engine.launch"):
+                out = fn(xd)                                  # async dispatch
+            if record:
+                self._count_rows(n_valid, bucket)
             if self.injector is not None:
                 out = self.injector.wrap_output(out, path=self.workload.name,
                                                 bucket=bucket)
@@ -457,8 +489,11 @@ class ExecutionCore:
 
         ``sync=False`` returns a :class:`PendingPlan` right after
         dispatch; realize it with ``.result()`` once the next plans are
-        in flight."""
-        pending = PendingPlan(self.infer(plan.x, sync=False), plan.requests)
+        in flight.  The ``engine.prep`` span runs from here to the
+        compiled call."""
+        with self.metrics.span("engine.prep"):
+            pending = PendingPlan(self.infer(plan.x, sync=False),
+                                  plan.requests)
         return pending.result() if sync else pending
 
     def run_stream(self, stream, *, warmup: int = 2) -> dict:
@@ -487,5 +522,6 @@ class ExecutionCore:
         for t in lat:
             self.metrics.record_batch(t, n_valid, bucket)
         self.metrics.record_wall(wall, events)
+        self._count_rows(n_valid, bucket, len(lat))
         return {"latencies": lat, "events": events, "wall_s": wall,
                 "bucket": bucket, "kgps": kgps(events, wall)}

@@ -27,7 +27,15 @@ with the three properties a live front-end owes its operators:
   in-flight occupancy land in :meth:`~repro.serving.metrics.
   ServingMetrics.gauge` (``queue_depth``, ``queue_requests``,
   ``inflight_plans``) next to the engine's monotonic shed/demotion
-  counters, all in the same ``snapshot()``.
+  counters, all in the same ``snapshot()``; each dispatched plan also
+  counts its flush reason (``flush_full`` / ``flush_deadline`` /
+  ``flush_forced``).
+* **spans** — while the metrics' span window is open
+  (:meth:`~repro.serving.metrics.ServingMetrics.start_spans`) each plan
+  gets one ``loop.dispatch`` and one ``loop.realize`` span sharing its
+  ``seq``, a full in-flight window a ``loop.backpressure`` span, and
+  each request a ``batch_wait_ns`` sample: submit to the cut of the plan
+  that carries its last event.
 
 The loop is engine-agnostic: anything with ``bucket_sizes``,
 ``metrics`` and ``run_plan(plan, sync=False) -> handle`` serves — the
@@ -127,6 +135,8 @@ class ServingLoop:
         self._futures: dict[int, RequestFuture] = {}
         self._next_rid = 0
         self._next_seq = 0
+        # span window only: rid -> [submit time (ns), events not yet cut]
+        self._uncut: dict[int, list] = {}
 
     # -- introspection ------------------------------------------------------
 
@@ -159,6 +169,8 @@ class ServingLoop:
         fut = RequestFuture(rid, x.shape[0])
         self._futures[rid] = fut
         self.metrics.incr("loop_requests")
+        if self.metrics.spans_on:
+            self._uncut[rid] = [self.metrics.clock(), x.shape[0]]
         plans = self.batcher.submit(rid, x, deadline_s=deadline_s)
         # instantaneous backlog INCLUDING what this submission just cut —
         # the high-water mark capacity planning reads (gauge_max)
@@ -187,14 +199,36 @@ class ServingLoop:
     # -- internals ----------------------------------------------------------
 
     def _dispatch(self, plans) -> None:
+        m = self.metrics
+        if plans and self._uncut:
+            self._sample_cuts(plans)
         for plan in plans:
             while len(self._inflight) >= self.max_inflight:
                 # backpressure: the oldest plan's completion is the brake
-                self._realize(self._inflight[0])
-            handle = self.engine.run_plan(plan, sync=False)
-            self._inflight.append((self._next_seq, handle, plan))
+                with m.span("loop.backpressure"):
+                    self._realize(self._inflight[0])
+            seq = self._next_seq
+            with m.span("loop.dispatch", seq=seq, bucket=plan.bucket,
+                        n_valid=plan.n_valid, reason=plan.reason):
+                handle = self.engine.run_plan(plan, sync=False)
+            self._inflight.append((seq, handle, plan))
             self._next_seq += 1
-            self.metrics.incr("loop_plans")
+            m.incr("loop_plans")
+            m.incr("flush_" + plan.reason)
+
+    def _sample_cuts(self, plans) -> None:
+        """One ``batch_wait_ns`` sample per request whose last event
+        these just-cut plans carry: its submit to now."""
+        now = self.metrics.clock()
+        for plan in plans:
+            for rid, start, stop in plan.requests:
+                entry = self._uncut.get(rid)
+                if entry is None:
+                    continue
+                entry[1] -= stop - start
+                if entry[1] <= 0:
+                    del self._uncut[rid]
+                    self.metrics.sample("batch_wait_ns", now - entry[0])
 
     def _reap(self) -> None:
         """Deliver every in-flight plan that is already realized-ready —
@@ -205,23 +239,24 @@ class ServingLoop:
 
     def _realize(self, entry) -> None:
         seq, handle, plan = entry
-        self._inflight.remove(entry)
-        results = handle.result()
-        rows = {}
-        for rid, start, stop in plan.requests:
-            rows[rid] = rows.get(rid, 0) + (stop - start)
-        for rid, out in results.items():
-            fut = self._futures.get(rid)
-            if fut is None:
-                continue
-            if out is None:                       # engine shed this segment
-                fut._deliver_shed(rows[rid])
-            else:
-                fut._deliver(seq, out)
-            if fut.done:
-                self.metrics.incr("loop_completed")
-                # the caller holds the future; the loop can forget it
-                del self._futures[rid]
+        with self.metrics.span("loop.realize", seq=seq):
+            self._inflight.remove(entry)
+            results = handle.result()
+            rows = {}
+            for rid, start, stop in plan.requests:
+                rows[rid] = rows.get(rid, 0) + (stop - start)
+            for rid, out in results.items():
+                fut = self._futures.get(rid)
+                if fut is None:
+                    continue
+                if out is None:                   # engine shed this segment
+                    fut._deliver_shed(rows[rid])
+                else:
+                    fut._deliver(seq, out)
+                if fut.done:
+                    self.metrics.incr("loop_completed")
+                    # the caller holds the future; the loop can forget it
+                    del self._futures[rid]
 
     def _update_gauges(self) -> None:
         self.metrics.gauge("queue_depth", self.batcher.pending_events)

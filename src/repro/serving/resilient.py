@@ -585,20 +585,23 @@ class ResilientEngine:
         ``sync=False`` returns a :class:`ResilientPlan` right after the
         async dispatch — the event loop's unit of in-flight work: the
         next plan's pad + dispatch overlaps this one's compute, and
-        realization-time faults still recover down the ladder."""
-        now = self._clock()
-        keep, results = [], {}
-        for i, (rid, start, stop) in enumerate(plan.requests):
-            t_deadline = plan.deadline_for(i)
-            if t_deadline is not None and now >= t_deadline:
-                self._shed(stop - start)
-                results[rid] = None
-            else:
-                keep.append((rid, start, stop))
-        if not keep:
-            return results if sync else ResilientPlan(results, (), None)
-        x = np.concatenate([plan.x[s:e] for _, s, e in keep], axis=0)
-        rp = ResilientPlan(results, tuple(keep), self.infer(x, sync=False))
+        realization-time faults still recover down the ladder.  The
+        ``engine.prep`` span runs from here to the compiled call."""
+        with self.metrics.span("engine.prep"):
+            now = self._clock()
+            keep, results = [], {}
+            for i, (rid, start, stop) in enumerate(plan.requests):
+                t_deadline = plan.deadline_for(i)
+                if t_deadline is not None and now >= t_deadline:
+                    self._shed(stop - start)
+                    results[rid] = None
+                else:
+                    keep.append((rid, start, stop))
+            if not keep:
+                return results if sync else ResilientPlan(results, (), None)
+            x = np.concatenate([plan.x[s:e] for _, s, e in keep], axis=0)
+            rp = ResilientPlan(results, tuple(keep),
+                               self.infer(x, sync=False))
         return rp.result() if sync else rp
 
     def run_stream(self, stream, *, warmup: int = 2) -> dict:

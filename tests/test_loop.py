@@ -201,6 +201,150 @@ def test_loop_gauges_track_queue_and_inflight():
     assert eng.metrics.gauge_value("inflight_plans") == 0
 
 
+# -- spans, samples and counters ---------------------------------------------
+
+
+def _tick_clock():
+    """A span clock that moves one ns per reading: every span has a
+    strictly ordered start and end."""
+    t = [0]
+
+    def clock():
+        t[0] += 1
+        return t[0]
+    return clock
+
+
+def _served_loop(jedi8, *, max_inflight=4, clock=None):
+    cfg, params = jedi8
+    metrics = ServingMetrics(clock=clock or _tick_clock())
+    eng = ResilientEngine(params, cfg, forward="sr_split",
+                          bucket_sizes=[4, 8], metrics=metrics)
+    eng.warm()
+    return eng, ServingLoop(eng, deadline_s=1e9, max_inflight=max_inflight)
+
+
+def _requests(n_list, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.normal(size=(n, 8, 4)).astype(np.float32) for n in n_list]
+
+
+def test_spans_off_record_nothing_and_never_annotate(jedi8, monkeypatch):
+    class Refuse:
+        def __init__(self, *a, **k):
+            raise AssertionError("TraceAnnotation entered with spans off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refuse)
+    eng, loop = _served_loop(jedi8, max_inflight=1)
+    futs = [loop.submit(x) for x in _requests((3, 5, 8, 2))]
+    loop.drain()
+    assert all(f.done and not f.shed for f in futs)
+    m = eng.metrics
+    assert m.span("loop.dispatch", seq=0) is m.span("engine.wait")
+    assert not loop._uncut
+    window = m.stop_spans()
+    assert window.spans == [] and window.samples == {}
+
+
+def test_each_plan_has_one_dispatch_and_one_realize_tree(jedi8):
+    eng, loop = _served_loop(jedi8, max_inflight=1)
+    m = eng.metrics
+    m.start_spans()
+    # the 20-event request cuts two full plans in one call: the second
+    # waits on the first (backpressure)
+    xs = _requests((3, 5, 20, 2, 7, 1, 4))
+    futs = [loop.submit(x) for x in xs]
+    loop.drain()
+    window = m.stop_spans()
+    assert all(f.done for f in futs)
+    n_plans = window.counters["loop_plans"]
+    by_id = {s[3]: s for s in window.spans}
+    names = [s[0] for s in window.spans]
+    for name in ("loop.dispatch", "engine.prep", "engine.launch",
+                 "loop.realize", "engine.wait", "engine.d2h"):
+        assert names.count(name) == n_plans, name
+    assert names.count("loop.backpressure") >= 1
+
+    def parent(s):
+        return by_id[s[4]] if s[4] is not None else None
+
+    for s in window.spans:
+        name, start, end, _, _, attrs = s
+        assert start < end
+        p = parent(s)
+        if name in ("engine.prep", "engine.launch"):
+            assert p[0] == "loop.dispatch"
+        elif name in ("engine.wait", "engine.d2h"):
+            assert p[0] == "loop.realize"
+        elif name == "loop.realize":
+            assert p is None or p[0] == "loop.backpressure"
+        else:
+            assert p is None, name
+        if p is not None:                         # children nest in time
+            assert p[1] < start and end < p[2]
+        if name != "loop.backpressure":
+            assert "seq" in attrs and (p is None or name == "loop.realize"
+                                       or p[5]["seq"] == attrs["seq"])
+    for seq in range(n_plans):
+        mine = sorted((s for s in window.spans if s[5].get("seq") == seq),
+                      key=lambda s: s[1])
+        assert [s[0] for s in mine] == [
+            "loop.dispatch", "engine.prep", "engine.launch", "loop.realize",
+            "engine.wait", "engine.d2h"]
+        disp = mine[0][5]
+        assert disp["reason"] in ("full", "forced")
+        assert disp["n_valid"] <= disp["bucket"]
+        prep, launch = mine[1], mine[2]
+        assert prep[2] < launch[1]                # prep ends, then launch
+
+
+def test_row_and_flush_counters_cover_the_window_only(jedi8):
+    eng, loop = _served_loop(jedi8)
+    m = eng.metrics
+    assert m.counter("compiles") >= 2             # warm: one per bucket
+    for x in _requests((6, 3)):                   # traffic before the window
+        loop.submit(x)
+    loop.drain()
+    before = m.counter("rows_valid")
+    assert before == 9
+    m.start_spans()
+    xs = _requests((3, 5, 8, 2, 7, 1), seed=3)
+    for x in xs:
+        loop.submit(x)
+    loop.poll()
+    loop.drain()
+    c = m.stop_spans().counters
+    assert c["rows_valid"] == sum(x.shape[0] for x in xs)
+    assert (c.get("flush_full", 0) + c.get("flush_deadline", 0)
+            + c.get("flush_forced", 0)) == c["loop_plans"]
+    assert c["rows_kernel"] >= c["rows_bucket"] >= c["rows_valid"]
+    assert "compiles" not in c                    # warm-up is outside
+    assert m.counter("rows_valid") == before + c["rows_valid"]
+
+
+def test_batch_wait_sample_is_submit_to_cut(jedi8):
+    cfg, params = jedi8
+    clk = FakeClock()
+    metrics = ServingMetrics(clock=lambda: int(round(clk.t * 1e9)))
+    eng = ResilientEngine(params, cfg, forward="sr_split",
+                          bucket_sizes=[4, 8], metrics=metrics, clock=clk)
+    loop = ServingLoop(eng, deadline_s=0.5, clock=clk)
+    metrics.start_spans()
+    a, b, c = _requests((3, 2, 8))
+    loop.submit(a)                                # t=0, waits for the fuse
+    clk.t = 0.3
+    loop.submit(b)
+    clk.t = 0.7
+    loop.poll()                                   # fuse: a and b cut at 0.7
+    loop.submit(c)                                # a full bucket: cut at once
+    loop.drain()
+    window = metrics.stop_spans()
+    assert sorted(window.samples["batch_wait_ns"]) == [0, 400_000_000,
+                                                       700_000_000]
+    assert window.counters["flush_deadline"] == 1
+    assert window.counters["flush_full"] == 1
+
+
 # -- LM fabric ---------------------------------------------------------------
 
 

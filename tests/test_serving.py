@@ -292,6 +292,92 @@ def test_metrics_gauges_replace_and_track_peak():
     assert snap["gauges"] == {"inflight": 2.0, "queue_depth": 1.0}
 
 
+def test_engine_counts_rows_as_the_kernel_computes_them(jedi30):
+    """A 12-row bucket is not a whole number of the kernel's 8-row
+    sublane tiles: the kernel computes 16 rows, and rows_kernel says so;
+    each bucket's cache miss is one compile."""
+    cfg, params = jedi30
+    eng = ServingEngine(params, cfg, forward="fused_full", interpret=True,
+                        bucket_sizes=[12])
+    assert eng.workload.kernel_rows(12) == 16
+    x = np.random.RandomState(2).normal(0, 1, (5, 30, 16)).astype(
+        np.float32)
+    eng.infer(x)
+    eng.infer(x)
+    m = eng.metrics
+    assert m.counter("compiles") == 1
+    assert (m.counter("rows_valid"), m.counter("rows_bucket"),
+            m.counter("rows_kernel")) == (10, 24, 32)
+    eng.infer(x, record=False)                    # canaries, warm-up
+    assert m.counter("rows_valid") == 10
+
+
+def test_engine_rows_kernel_is_the_bucket_without_a_kernel(jedi30):
+    cfg, params = jedi30
+    eng = ServingEngine(params, cfg, forward="sr_split", bucket_sizes=[12])
+    assert eng.workload.kernel_rows(12) == 12
+
+
+def test_run_plan_spans_prep_launch_wait_d2h(jedi30, engine30):
+    """ExecutionCore.run_plan: engine.prep runs up to the compiled call,
+    engine.launch is the call, and realization is engine.wait then
+    engine.d2h; with spans off the same plan records nothing."""
+    t = [0]
+
+    def tick():
+        t[0] += 1
+        return t[0]
+
+    m = ServingMetrics(clock=tick)
+    shared, engine30.metrics = engine30.metrics, m
+    b = DeadlineBatcher(engine30.bucket_sizes, deadline_s=0.0)
+    rng = np.random.RandomState(4)
+    b.submit(0, rng.normal(0, 1, (5, 30, 16)).astype(np.float32), now=0.0)
+    (plan,) = b.poll(now=1.0)
+    try:
+        engine30.run_plan(plan)
+        assert m.stop_spans().spans == []
+        m.start_spans()
+        out = engine30.run_plan(plan)
+        spans = m.stop_spans().spans
+    finally:
+        engine30.metrics = shared
+    assert out[0].shape == (5, 5)
+    order = sorted(spans, key=lambda s: s[1])
+    assert [s[0] for s in order] == ["engine.prep", "engine.launch",
+                                     "engine.wait", "engine.d2h"]
+    assert all(s[4] is None for s in spans)       # no loop above them
+    for a, b_ in zip(order, order[1:]):
+        assert a[2] < b_[1]                       # one after the other
+
+
+def test_spans_come_from_the_serve_thread_only():
+    """A worker thread (the sentinel's shadow) neither records spans nor
+    closes the serve thread's open one."""
+    import threading
+
+    m = ServingMetrics()
+    m.start_spans()
+    prep = m.span("engine.prep")
+    seen = []
+
+    def worker():
+        seen.append(m.span("engine.launch"))
+        m.close_span("engine.prep")
+
+    t = threading.Thread(target=worker)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    with m.span("engine.launch"):
+        pass
+    prep.close()
+    window = m.stop_spans()
+    assert seen[0] is m.span("anything")          # the shared no-op span
+    assert [s[0] for s in window.spans] == ["engine.launch", "engine.prep"]
+    assert window.spans[0][4] == window.spans[1][3]   # launch inside prep
+
+
 # -- sharded path (subprocess with 8 fake CPU devices) -------------------
 
 

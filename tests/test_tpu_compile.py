@@ -85,3 +85,24 @@ def test_kernel_compiles_for_v5e(one_chip, path, arch, rung):
     # compile time stays flat in the bucket: the kernel body holds one
     # batch tile, bigger buckets only add grid steps
     assert seconds < 30, (path, arch, bucket, seconds)
+
+
+def test_step_and_kernel_names_for_v5e(one_chip):
+    """The served step compiles as ``jit_trigger_step`` with the kernel's
+    custom call named ``fused_forward_full*``, whatever wraps the
+    ``pallas_call``: the names the benchmark's trace readers key on."""
+    from repro.serving.engine import TriggerWorkload
+
+    cfg = get_arch("jedinet-50p").model
+    work = TriggerWorkload(init(jax.random.PRNGKey(0), cfg), cfg,
+                           forward="fused_full", interpret=False, mesh=None)
+    bucket = work.bucket_ladder(1024)[-1]
+    x = jax.ShapeDtypeStruct((bucket, cfg.n_objects, cfg.n_features),
+                             jnp.float32, sharding=one_chip)
+    hlo = work.build(bucket).lower(x).compile().as_text()
+    assert hlo.startswith("HloModule jit_trigger_step"), hlo[:80]
+    calls = [line.split("=", 1)[0].strip() for line in hlo.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls and all(c.startswith("%fused_forward_full")
+                         or c.startswith("fused_forward_full")
+                         for c in calls), calls

@@ -130,6 +130,10 @@ def test_cli_health_flag_reports_state(capsys):
     assert "[health] state=healthy" in out
     assert "chain=sr" in out
     assert "path=sr" in out                  # serving line + bucket detail
+    # the per-plan row counters and the compile-cache misses
+    for counter in ("rows_valid=32", "rows_bucket=32", "rows_kernel=32",
+                    "compiles="):
+        assert counter in out, counter
 
 
 def test_cli_reports_serving_path_and_chain(capsys):
