@@ -98,19 +98,19 @@ def _tiling(cfg, params, batch: int) -> dict:
     sides run under the same weight-reserved budget, so block_b_gain
     isolates the tiling effect (not the reservation policy)."""
     fr_w, fo_w, phi_w = _widths(params)
-    reserved = fj_autotune.weight_vmem_bytes(params, cfg.compute_dtype)
+    tiles = fj_autotune.modeled_residency(cfg, params, batch)
+    reserved = tiles["reserved_bytes"]
     budget = fj_autotune.effective_budget(
         fj_autotune.VMEM_BUDGET_BYTES, reserved)
     untiled_per = fj_autotune.full_forward_bytes_per_sample(
         cfg.n_objects, cfg.n_features, fr_w, fo_w, phi_w)
     untiled_fits = fj_autotune.fits_vmem(untiled_per, budget)
     untiled_bb = fj_autotune.pick_block_b(batch, untiled_per, budget)
-    bb, bs = fj_autotune.pick_block_b_s(
-        batch, cfg.n_objects, cfg.n_features, fr_w, fo_w, phi_w,
-        reserved_bytes=reserved)
+    bb, bs = tiles["block_b"], tiles["block_s"]
     return {
         "autotuned_block_b": bb,
         "autotuned_block_s": bs,
+        "lane_pack": tiles["lane_pack"],
         "untiled_block_b": untiled_bb,
         "untiled_per_sample_bytes": untiled_per,
         "untiled_rejected": not untiled_fits,

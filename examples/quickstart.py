@@ -71,12 +71,10 @@ def main():
                    for k in ("fr", "fo", "phi"))
     untiled = fj_autotune.full_forward_bytes_per_sample(
         tcfg.n_objects, tcfg.n_features, *widths)
-    # same reservation the forward call's internal autotune applies, so
-    # the printed tile is the tile that actually runs
-    bb, bs = fj_autotune.pick_block_b_s(
-        4, tcfg.n_objects, tcfg.n_features, *widths,
-        reserved_bytes=fj_autotune.weight_vmem_bytes(tparams,
-                                                     tcfg.compute_dtype))
+    # the forward call's own tile decision, so the printed tile is the
+    # tile that actually runs
+    tiles = fj_autotune.modeled_residency(tcfg, tparams, 4)
+    bb, bs = tiles["block_b"], tiles["block_s"]
     xt = jnp.asarray(make_tracks(np.random.RandomState(0), 4)[0])
     spec = paths.get("fused_full")
     logits = spec.forward(tparams, tcfg, xt, interpret=True)

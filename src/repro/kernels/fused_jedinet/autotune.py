@@ -21,6 +21,17 @@ Three estimators:
   accumulator is live, so the per-sample set shrinks ~``N_o/block_s``
   and ``block_b`` grows by the ratio.
 
+Lane packing
+------------
+An f_R width of 50 fills 50 of a vreg's 128 lanes, and its weight tile
+15 % of the MXU.  The kernel therefore packs ``k`` sender edges of one
+receiver side by side in the lanes (:func:`lane_pack`): the slab is
+``(N_o, block_s / k, block_b, k*H)`` and each f_R layer runs on a
+block-diagonal weight.  The models bill the packed layout: ``block_s/k``
+slab rows per receiver, each ``lanes(k*H)`` wide.  ``lane_pack=1`` is
+the unpacked kernel, and what the serving ladder is derived from
+(``PathSpec.bucket_bytes``).
+
 The whole-network models bill every row at whole 128-lane tiles
 (:func:`~repro.kernels.autotune.lanes`): that is what the arrays occupy
 in VMEM, and it bounds the rows one grid step holds — which is also
@@ -41,6 +52,7 @@ from __future__ import annotations
 # Re-exported so kernel wrappers and tests have one import surface.
 from repro.kernels.autotune import (  # noqa: F401
     VMEM_BUDGET_BYTES,
+    _LANE,
     _SUBLANE,
     effective_budget,
     lanes,
@@ -97,28 +109,30 @@ def full_forward_tiled_bytes_per_sample(n_objects: int, n_features: int,
                                         fo_widths: list[int],
                                         phi_widths: list[int],
                                         block_s: int,
-                                        acc_bytes: int = 4) -> int:
+                                        acc_bytes: int = 4,
+                                        lane_pack: int = 1) -> int:
     """Per-jet VMEM working set of the sender-tiled whole-network kernel.
 
-    Live at any instant: one (N_o, block_s, H1) slab of the f_R grid,
-    the bilinear-split projections u_r (N_o, H1) / u_s (block_s, H1)
-    feeding it, the fp32 Ebar accumulator scratch, the receiver x tile
-    plus this step's sender-chunk slice, and — only after the last
-    sender tile — C and the f_O / phi_O activations.  The tail
-    intermediates share the budget because they coexist with the
-    accumulator and x.  Every row is billed at whole 128-lane tiles,
-    and the slab :data:`SLAB_LIVE_COPIES` times.
-    ``block_s = N_o`` reproduces the untiled estimate exactly.
+    Live at any instant: one (N_o, block_s/k, k*H1) slab of the f_R
+    grid (``k = lane_pack`` edges per row), the bilinear-split
+    projections u_r (N_o, k*H1) / u_s (block_s, k*H1) feeding it, the
+    fp32 Ebar accumulator scratch (N_o, k*D_e), the receiver x tile plus
+    this step's sender-chunk slice, and — only after the last sender
+    tile — C and the f_O / phi_O activations.  The tail intermediates
+    share the budget because they coexist with the accumulator and x.
+    Every row is billed at whole 128-lane tiles, and the slab
+    :data:`SLAB_LIVE_COPIES` times.  ``block_s = N_o`` reproduces the
+    untiled estimate exactly.
     """
-    n_o = n_objects
-    block_s = max(1, min(int(block_s), n_o))
-    h1 = lanes(fr_widths[0])
-    slab = SLAB_LIVE_COPIES * n_o * block_s * lanes(max(fr_widths))
+    n_o, k = n_objects, max(int(lane_pack), 1)
+    block_s = max(k, min(int(block_s), n_o))
+    h1 = lanes(k * fr_widths[0])
+    slab = SLAB_LIVE_COPIES * n_o * (block_s // k) * lanes(k * max(fr_widths))
     u_r = n_o * h1
     u_s = block_s * h1
     x_tile = n_o * lanes(n_features)
     xs_tile = block_s * lanes(n_features)
-    ebar_acc = n_o * lanes(fr_widths[-1])
+    ebar_acc = n_o * lanes(k * fr_widths[-1])
     c_tile = n_o * lanes(n_features + fr_widths[-1])
     fo_acts = n_o * lanes(max(fo_widths))
     phi_acts = lanes(max(phi_widths))
@@ -134,19 +148,48 @@ def fits_vmem(per_sample_bytes: int,
     return per_sample_bytes <= budget_bytes
 
 
-def sender_tile_candidates(n_objects: int) -> list[int]:
-    """Sender-axis tile sizes worth searching: the divisors of N_o, so
-    every sender step covers a whole tile (no clamped remainder, no
-    bounds mask).  Ascending; the last is N_o itself, the untiled
-    degenerate."""
-    return [d for d in range(1, n_objects + 1) if n_objects % d == 0]
+def lane_pack(fr_widths: list[int], n_objects: int) -> int:
+    """Sender edges the kernel packs side by side in one slab row:
+    ``128 // max(f_R widths)``, lowered until it divides N_o (every
+    sender tile is then a whole number of packed rows).  2 at width 50
+    and N_o 50, 6 at width 20 and N_o 30, 1 at width 64 and above."""
+    k = max(_LANE // max(int(w) for w in fr_widths), 1)
+    while n_objects % k:
+        k -= 1
+    return k
+
+
+def sender_tile_candidates(n_objects: int, lane_pack: int = 1) -> list[int]:
+    """Sender-axis tile sizes worth searching: the divisors of N_o that
+    are multiples of ``lane_pack``, so every sender step covers a whole
+    tile of whole packed rows (no clamped remainder, no bounds mask).
+    Ascending; the last is N_o itself, the untiled degenerate."""
+    return [d for d in range(lane_pack, n_objects + 1, lane_pack)
+            if n_objects % d == 0]
+
+
+def packed_weight_bytes(params, compute_dtype, lane_pack: int) -> int:
+    """VMEM the whole-network kernel's weight operands occupy with f_R
+    packed ``lane_pack`` edges per row: the first layer's two halves
+    tiled ``k`` times along the lanes, every later f_R weight
+    block-diagonal (``k**2`` its size), each f_R bias tiled ``k``
+    times; f_O, phi_O and the dequant scales as
+    :func:`~repro.kernels.autotune.weight_vmem_bytes` bills them."""
+    total = weight_vmem_bytes(params, compute_dtype)
+    k = int(lane_pack)
+    for i, lp in enumerate(params["fr"]["layers"]):
+        w = weight_vmem_bytes({"w": lp["w"]}, compute_dtype)
+        total += w * (k ** (1 if i == 0 else 2) - 1)
+        total += weight_vmem_bytes({"b": lp["b"]}) * (k - 1)
+    return total
 
 
 def pick_block_b_s(batch: int, n_objects: int, n_features: int,
                    fr_widths: list[int], fo_widths: list[int],
                    phi_widths: list[int],
                    budget_bytes: int = VMEM_BUDGET_BYTES,
-                   reserved_bytes: int = 0) -> tuple[int, int]:
+                   reserved_bytes: int = 0,
+                   lane_pack: int = 1) -> tuple[int, int]:
     """Jointly pick ``(block_b, block_s)`` for the tiled kernel.
 
     For each candidate sender tile the per-sample live set is modeled
@@ -160,13 +203,15 @@ def pick_block_b_s(batch: int, n_objects: int, n_features: int,
     ``reserved_bytes`` (e.g. the weight blocks' VMEM residency,
     :func:`~repro.kernels.autotune.weight_vmem_bytes`) is subtracted
     from the budget — the quantization-aware knob: int8 weights reserve
-    4x less, leaving more VMEM for batch rows.
+    4x less, leaving more VMEM for batch rows.  ``lane_pack`` is the
+    kernel's edges per slab row (:func:`lane_pack`).
     """
     budget = effective_budget(budget_bytes, reserved_bytes)
     best = fallback = None
-    for bs in sender_tile_candidates(n_objects):
+    for bs in sender_tile_candidates(n_objects, lane_pack):
         per = full_forward_tiled_bytes_per_sample(
-            n_objects, n_features, fr_widths, fo_widths, phi_widths, bs)
+            n_objects, n_features, fr_widths, fo_widths, phi_widths, bs,
+            lane_pack=lane_pack)
         bb = pick_block_b(batch, per, budget)
         # pick_block_b floors block_b at one sublane tile even when that
         # busts the budget, so a non-fitting candidate could still win on
@@ -190,43 +235,47 @@ def modeled_residency(cfg, params, batch: int, *,
     from this dict, and the kernel-contract auditor
     (``repro.analysis.kernel_audit``) cross-checks it against the
     *traced* ``pallas_call``.  Pinned knobs (tests) are honored; a
-    pinned ``block_s`` rounds down to a divisor of N_o.
+    pinned ``block_s`` rounds down to a sender-tile candidate.
 
-    Returns ``{kernel, block_b, block_s, batch_axis, grid,
+    Returns ``{kernel, block_b, block_s, lane_pack, batch_axis, grid,
     per_sample_bytes, reserved_bytes, effective_budget,
     weight_residency_bytes, fits}``;
+    ``lane_pack`` is read off the f_R widths (:func:`lane_pack`);
     ``weight_residency_bytes`` is the VMEM the weight blocks (and, for
-    quantized params, the dequant-scale vector) occupy at the dtypes the
-    kernel ships — what the traced input BlockSpecs must add up to.
+    quantized params, the dequant-scale vector) occupy at the dtypes
+    and packing the kernel ships — what the traced input BlockSpecs
+    must add up to.
     """
     fr_w = mlp_widths(params["fr"])
     fo_w = mlp_widths(params["fo"])
     phi_w = mlp_widths(params["phi"])
     n_o, n_f = cfg.n_objects, cfg.n_features
-    reserved = weight_vmem_bytes(params, cfg.compute_dtype)
+    k = lane_pack(fr_w, n_o)
+    reserved = packed_weight_bytes(params, cfg.compute_dtype, k)
+    budget = effective_budget(budget_bytes, reserved)
     if block_b is None and block_s is None:
         block_b, block_s = pick_block_b_s(
             batch, n_o, n_f, fr_w, fo_w, phi_w,
-            budget_bytes=budget_bytes, reserved_bytes=reserved)
+            budget_bytes=budget_bytes, reserved_bytes=reserved,
+            lane_pack=k)
     elif block_b is None:
-        block_s = sender_tile(block_s, n_o)
+        block_s = sender_tile(block_s, n_o, k)
         per = full_forward_tiled_bytes_per_sample(
-            n_o, n_f, fr_w, fo_w, phi_w, block_s)
-        block_b = pick_block_b(batch, per,
-                               effective_budget(budget_bytes, reserved))
+            n_o, n_f, fr_w, fo_w, phi_w, block_s, lane_pack=k)
+        block_b = pick_block_b(batch, per, budget)
     elif block_s is None:
         block_s = pick_block_s(block_b, n_o, n_f, fr_w, fo_w, phi_w,
                                budget_bytes=budget_bytes,
-                               reserved_bytes=reserved)
+                               reserved_bytes=reserved, lane_pack=k)
     else:
-        block_s = sender_tile(block_s, n_o)
+        block_s = sender_tile(block_s, n_o, k)
     per = full_forward_tiled_bytes_per_sample(
-        n_o, n_f, fr_w, fo_w, phi_w, block_s)
-    budget = effective_budget(budget_bytes, reserved)
+        n_o, n_f, fr_w, fo_w, phi_w, block_s, lane_pack=k)
     return {
         "kernel": "fused_jedinet.full",
         "block_b": int(block_b),
         "block_s": int(block_s),
+        "lane_pack": int(k),
         "batch_axis": 1,                  # x is node-major (N_o, B, P)
         "grid": (padded_batch(batch, block_b) // block_b,
                  n_o // block_s),
@@ -267,27 +316,30 @@ def pick_block_s(block_b: int, n_objects: int, n_features: int,
                  fr_widths: list[int], fo_widths: list[int],
                  phi_widths: list[int],
                  budget_bytes: int = VMEM_BUDGET_BYTES,
-                 reserved_bytes: int = 0) -> int:
+                 reserved_bytes: int = 0, lane_pack: int = 1) -> int:
     """Largest sender tile that fits the budget ALONGSIDE a pinned batch
     tile — the one-knob-pinned complement of :func:`pick_block_b_s`.
     Falls back to the smallest candidate when none fit (the caller's
     ``block_b`` is then oversubscribed either way; the smallest live set
     is the least-bad tile to run it with)."""
     budget = effective_budget(budget_bytes, reserved_bytes)
-    cands = sender_tile_candidates(n_objects)
+    cands = sender_tile_candidates(n_objects, lane_pack)
     best = cands[0]
     for bs in cands:                       # per-sample grows with bs, so
         per = full_forward_tiled_bytes_per_sample(   # the last fit wins
-            n_objects, n_features, fr_widths, fo_widths, phi_widths, bs)
+            n_objects, n_features, fr_widths, fo_widths, phi_widths, bs,
+            lane_pack=lane_pack)
         if max(int(block_b), 1) * per <= budget:
             best = bs
     return best
 
 
-def sender_tile(block_s: int | None, n_objects: int) -> int:
-    """A pinned sender tile as the kernel runs it: the largest divisor
-    of N_o not above ``block_s`` (``None`` means untiled)."""
+def sender_tile(block_s: int | None, n_objects: int,
+                lane_pack: int = 1) -> int:
+    """A pinned sender tile as the kernel runs it: the largest candidate
+    (:func:`sender_tile_candidates`) not above ``block_s``, or the
+    smallest when none is (``None`` means untiled)."""
     if block_s is None:
         return n_objects
-    return max(d for d in sender_tile_candidates(n_objects)
-               if d <= max(int(block_s), 1))
+    cands = sender_tile_candidates(n_objects, lane_pack)
+    return max([d for d in cands if d <= int(block_s)] or cands[:1])

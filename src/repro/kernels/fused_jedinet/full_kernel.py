@@ -31,6 +31,25 @@ tile: no clamped remainder, no bounds mask.  The grid includes each
 node's self-edge; the tail subtracts it once, from the same per-node
 projections.  ``block_s = N_o`` is the untiled kernel (one sender step).
 
+Lane-packed edges
+-----------------
+The f_R widths of the published models (20 at 30p, 50 at 50p) fill a
+fraction of the 128 lanes of a vreg and of the MXU's 128x128 weight
+tile.  The kernel therefore carries ``k = lane_pack`` sender edges of
+one receiver side by side in the lanes of each slab row
+(``autotune.lane_pack``: ``128 // max(f_R widths)``, lowered until it
+divides N_o): the slab is ``(N_o, block_s / k, block_b, k*H)``, lane
+block ``t`` of packed row ``r`` holding sender ``j*block_s + t*block_s/k
++ r``.  Its operands follow (:func:`pack_fr_operands`): both halves of
+the first layer tiled ``k`` times along the lanes, every later f_R
+weight block-diagonal ``diag(W, ..., W)``, each bias tiled.  The sender
+projection takes lane block ``t`` of its ``t``-th slice's product, so
+no lane concatenate is needed, and the receiver projection is one
+replicated product.  The accumulator is ``(N_o, block_b, k*D_e)``; the
+tail folds its ``k`` lane blocks once per batch tile.  The zeros of a
+block-diagonal weight add exact zeros, so only the order of the sender
+sum differs from the unpacked kernel; ``k = 1`` is that kernel.
+
 Node-major layout
 -----------------
 The wrapper hands the kernel x as ``(N_o, B, P)``: nodes and senders on
@@ -160,19 +179,43 @@ def _readout(x2, ebar, n_o: int, fo, phi, act, compute_dtype):
     return _mlp(o_sum, phi, act, compute_dtype)
 
 
+def _sender_projection(x_ref, j, w1s, s1s, compute_dtype, block_s: int,
+                       lane_pack: int):
+    """u_s of sender tile ``j`` in the packed layout: lane block ``t`` of
+    packed row ``r`` is sender ``j*block_s + t*block_s/k + r`` projected
+    by w1s.  ``w1s`` is tiled ``k`` times along the lanes, so each of the
+    ``k`` contiguous sender slices is one product whose lane block ``t``
+    is kept; ``k = 1`` is one product of the whole tile."""
+    rows = block_s // lane_pack
+    _, bb, p = x_ref.shape
+    u_s = lane = None
+    for t in range(lane_pack):
+        start = j * block_s if t == 0 else j * block_s + t * rows
+        u_t = _mmq(x_ref[pl.ds(start, rows)].reshape(rows * bb, p), w1s,
+                   s1s, compute_dtype)
+        if u_s is None:
+            u_s = u_t
+            continue
+        if lane is None:
+            lane = jax.lax.broadcasted_iota(jnp.int32, u_t.shape, 1)
+        u_s = jnp.where(lane >= t * (u_t.shape[-1] // lane_pack), u_t, u_s)
+    return u_s                                          # (rows*bb, k*H1)
+
+
 def _tiled_forward_kernel(x_ref, *rest_refs, activation: str,
                           n_fr: int, n_fo: int, n_o: int, block_s: int,
-                          quantized: bool, compute_dtype):
+                          lane_pack: int, quantized: bool, compute_dtype):
     """rest_refs = [scales?] + [w1r, w1s, b1, (fr w/b)*, (fo w/b)*,
-    (phi w/b)*] + [out_ref, acc_ref].
+    (phi w/b)*] + [out_ref, acc_ref], the f_R operands packed
+    ``lane_pack`` edges per row (:func:`pack_fr_operands`).
 
     ``x_ref``   — (N_o, block_b, P) fp32: every node of the batch tile,
                   resident across sender steps (its index map ignores
                   j), so x crosses HBM ONCE per batch tile.  Each sender
                   step slices its ``block_s`` nodes out of this block.
-    ``acc_ref`` — (N_o, block_b, D_e) fp32 VMEM scratch: the Ebar
-                  accumulator, carried across the sender steps of one
-                  batch tile.
+    ``acc_ref`` — (N_o, block_b, k*D_e) fp32 VMEM scratch: the Ebar
+                  accumulator, one lane block per packed edge, carried
+                  across the sender steps of one batch tile.
     Weight refs arrive pre-cast to the compute dtype (or int8 when
     ``quantized``); biases are fp32.
     """
@@ -182,23 +225,24 @@ def _tiled_forward_kernel(x_ref, *rest_refs, activation: str,
     act = ACTIVATIONS[activation]
     j = pl.program_id(1)
     _, bb, p = x_ref.shape
+    rows = block_s // lane_pack                         # packed slab rows
 
     x2 = x_ref[...].reshape(n_o * bb, p)
-    xs = x_ref[pl.ds(j * block_s, block_s)].reshape(block_s * bb, p)
 
     # --- f_R layer 1, bilinear split: receiver projection over ALL N_o
     # nodes (recomputed per sender step, so no second scratch), sender
-    # projection over THIS tile only.
-    u_r = _mmq(x2, w1r, s1r, compute_dtype)             # (N_o*bb, H1)
-    u_s = _mmq(xs, w1s, s1s, compute_dtype)             # (bs*bb, H1)
+    # projection over THIS tile only; both k*H1 lanes wide.
+    u_r = _mmq(x2, w1r, s1r, compute_dtype)             # (N_o*bb, k*H1)
+    u_s = _sender_projection(x_ref, j, w1s, s1s, compute_dtype, block_s,
+                             lane_pack)                 # (rows*bb, k*H1)
     h1 = u_r.shape[-1]
 
     # --- dense receiver x sender-tile slab (regular access, no gather)
-    h = u_r.reshape(n_o, 1, bb, h1) + u_s.reshape(1, block_s, bb, h1)
-    h = _edge_mlp(h.reshape(n_o * block_s * bb, h1) + b1[...],
-                  fr_rest, act, compute_dtype)           # (N_o*bs*bb, D_e)
-    d_e = h.shape[-1]
-    contrib = jnp.sum(h.reshape(n_o, block_s, bb, d_e), axis=1)
+    h = u_r.reshape(n_o, 1, bb, h1) + u_s.reshape(1, rows, bb, h1)
+    h = _edge_mlp(h.reshape(n_o * rows * bb, h1) + b1[...],
+                  fr_rest, act, compute_dtype)       # (N_o*rows*bb, k*D_e)
+    kd_e = h.shape[-1]
+    contrib = jnp.sum(h.reshape(n_o, rows, bb, kd_e), axis=1)
 
     @pl.when(j == 0)
     def _init():
@@ -206,15 +250,24 @@ def _tiled_forward_kernel(x_ref, *rest_refs, activation: str,
 
     acc_ref[...] += contrib
 
-    # --- after the LAST sender tile: drop the self-edges the dense grid
-    # summed, then C = [x ‖ Ebar], f_O, node-sum, phi_O — all still in
-    # VMEM, once per batch tile.
+    # --- after the LAST sender tile: fold the k lane blocks, drop the
+    # self-edges the dense grid summed, then C = [x ‖ Ebar], f_O,
+    # node-sum, phi_O — all still in VMEM, once per batch tile.
     @pl.when(j == pl.num_programs(1) - 1)
     def _tail():
+        # w1s is lane-tiled, so every lane block of the self-edge holds
+        # the same (unpacked) value
         u_self = _mmq(x2, w1s, s1s, compute_dtype)
         self_edge = _edge_mlp(u_r + u_self + b1[...], fr_rest, act,
                               compute_dtype)
-        ebar = acc_ref[...].reshape(n_o * bb, d_e) - self_edge
+        acc = acc_ref[...].reshape(n_o * bb, kd_e)
+        if lane_pack > 1:
+            d_e = kd_e // lane_pack
+            folded = acc[:, :d_e]
+            for t in range(1, lane_pack):
+                folded = folded + acc[:, t * d_e:(t + 1) * d_e]
+            acc, self_edge = folded, self_edge[:, :d_e]
+        ebar = acc - self_edge
         logits = _readout(x2, ebar, n_o, fo, phi, act, compute_dtype)
         out_ref[...] = logits.astype(out_ref.dtype)     # (bb, n_targets)
 
@@ -264,26 +317,60 @@ def check_scales(weights, scales):
     return scales
 
 
+def _lane_tile(a, k: int):
+    """``a`` repeated ``k`` times along its last (lane) axis."""
+    return a if k == 1 else jnp.concatenate([a] * k, axis=-1)
+
+
+def _block_diag(w, k: int):
+    """``diag(w, ..., w)``, ``k`` blocks, exact zeros elsewhere."""
+    if k == 1:
+        return w
+    zero = jnp.zeros_like(w)
+    return jnp.concatenate(
+        [jnp.concatenate([w if c == r else zero for c in range(k)], axis=1)
+         for r in range(k)], axis=0)
+
+
+def pack_fr_operands(fr_arrays, lane_pack: int) -> list:
+    """``[w1r, w1s, b1, w2, b2, ...]`` packed ``lane_pack`` edges per
+    slab row: both first-layer halves and every bias tiled along the
+    lanes, every later weight block-diagonal.  Integer weights stay
+    integer and keep their per-tensor scale; ``lane_pack = 1`` returns
+    the arrays as they are."""
+    k = int(lane_pack)
+    w1r, w1s, b1, rest = fr_arrays[0], fr_arrays[1], fr_arrays[2], \
+        fr_arrays[3:]
+    packed = [_lane_tile(w1r, k), _lane_tile(w1s, k), _lane_tile(b1, k)]
+    for w, b in zip(rest[0::2], rest[1::2]):
+        packed += [_block_diag(w, k), _lane_tile(b, k)]
+    return packed
+
+
 def fused_forward_full_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
                                    activation: str, n_targets: int,
                                    block_b: int, block_s: int | None = None,
+                                   lane_pack: int = 1,
                                    compute_dtype=jnp.float32,
                                    scales=None, interpret: bool = False):
     """x: (N_o, B, P) fp32 node-major (:func:`node_major`) -> logits
     (B, n_targets) fp32.
 
     ``B % block_b == 0`` (callers pad via :func:`node_major`).
-    ``fr_arrays = [w1r, w1s, b1, w2, b2, ...]`` from split_first_layer.
-    ``block_s`` tiles the sender axis and must divide N_o (default N_o =
-    untiled).  ``scales`` — fp32 vector of per-weight-tensor dequant
-    scales, in weight order [w1r, w1s, w2.., fo.., phi..], required iff
-    any weight array is an integer dtype (in-kernel int8 dequant).
+    ``fr_arrays = [w1r, w1s, b1, w2, b2, ...]`` from split_first_layer,
+    unpacked; the call packs them ``lane_pack`` edges per slab row
+    (:func:`pack_fr_operands`, ``autotune.lane_pack``).  ``block_s``
+    tiles the sender axis and must divide N_o and be a multiple of
+    ``lane_pack`` (default N_o = untiled).  ``scales`` — fp32 vector of
+    per-weight-tensor dequant scales, in weight order [w1r, w1s, w2..,
+    fo.., phi..], required iff any weight array is an integer dtype
+    (in-kernel int8 dequant).
     """
     n_o, bsz, p = x.shape
     block_s = n_o if block_s is None else int(block_s)
+    lane_pack = int(lane_pack)
     n_fr = 1 + (len(fr_arrays) - 3) // 2
     n_fo = len(fo_arrays) // 2
-    weights = [*fr_arrays, *fo_arrays, *phi_arrays]
     d_e = fr_arrays[-2].shape[-1] if n_fr > 1 else fr_arrays[0].shape[-1]
 
     if bsz % block_b != 0:
@@ -292,16 +379,19 @@ def fused_forward_full_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
         fo_w = [int(w.shape[-1]) for w in fo_arrays[0::2]]
         phi_w = [int(w.shape[-1]) for w in phi_arrays[0::2]]
         modeled = fj_autotune.full_forward_tiled_bytes_per_sample(
-            n_o, p, fr_w, fo_w, phi_w, block_s)
+            n_o, p, fr_w, fo_w, phi_w, block_s, lane_pack=lane_pack)
         raise ValueError(
             f"batch {bsz} is not a multiple of the batch tile: autotuned "
             f"(block_b={block_b}, block_s={block_s}) at modeled {modeled} "
             f"VMEM bytes/sample — pad the batch with autotune.pad_batch(x, "
             f"{block_b}) (kernel wrappers do this automatically)")
-    if n_o % block_s != 0:
+    if n_o % block_s != 0 or block_s % lane_pack != 0:
         raise ValueError(
-            f"sender tile block_s={block_s} does not divide N_o={n_o}; "
-            "pick one of autotune.sender_tile_candidates(N_o)")
+            f"sender tile block_s={block_s} does not divide N_o={n_o} in "
+            f"whole packed rows of lane_pack={lane_pack}; pick one of "
+            "autotune.sender_tile_candidates(N_o, lane_pack)")
+    weights = [*pack_fr_operands(fr_arrays, lane_pack), *fo_arrays,
+               *phi_arrays]
     scales = check_scales(weights, scales)
 
     def wmap(ndim):
@@ -320,15 +410,16 @@ def fused_forward_full_kernel_call(x, fr_arrays, fo_arrays, phi_arrays, *,
 
     kernel = functools.partial(
         _tiled_forward_kernel, activation=activation, n_fr=n_fr, n_fo=n_fo,
-        n_o=n_o, block_s=block_s, quantized=scales is not None,
-        compute_dtype=jnp.dtype(compute_dtype))
+        n_o=n_o, block_s=block_s, lane_pack=lane_pack,
+        quantized=scales is not None, compute_dtype=jnp.dtype(compute_dtype))
     return pl.pallas_call(
         kernel,
         grid=(bsz // block_b, n_o // block_s),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_b, n_targets), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_targets), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_o, block_b, d_e), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_o, block_b, lane_pack * d_e),
+                                   jnp.float32)],
         interpret=interpret,
         # the op's HLO name, whatever wraps this call: the benchmark's
         # fused_full_roofline reader finds the kernel by it

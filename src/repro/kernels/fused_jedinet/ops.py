@@ -111,7 +111,9 @@ def fused_forward_full(params, cfg, x, *, interpret: bool = False,
     all the way into VMEM.  ``(block_b, block_s)`` default to the 2D
     working-set autotuner, decided once in
     ``autotune.modeled_residency``; pass either explicitly to pin it
-    (tests).  A pinned ``block_s`` rounds down to a divisor of N_o.
+    (tests).  A pinned ``block_s`` rounds down to a sender-tile
+    candidate.  f_R runs packed ``tiles["lane_pack"]`` edges per slab
+    row, a factor the tile model reads off the f_R widths.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     fr_arrays, fo_arrays, phi_arrays, scales = whole_network_operands(
@@ -122,6 +124,6 @@ def fused_forward_full(params, cfg, x, *, interpret: bool = False,
     out = FK.fused_forward_full_kernel_call(
         FK.node_major(x, cdt, block_b), fr_arrays, fo_arrays, phi_arrays,
         activation=cfg.activation, n_targets=cfg.n_targets,
-        block_b=block_b, block_s=block_s, compute_dtype=cdt, scales=scales,
-        interpret=interpret)
+        block_b=block_b, block_s=block_s, lane_pack=tiles["lane_pack"],
+        compute_dtype=cdt, scales=scales, interpret=interpret)
     return out[:x.shape[0]]

@@ -24,8 +24,9 @@ bucket ladder is — is declared by a :class:`Workload`.
 * **wall-union metrics** — KGPS wall time is the UNION of dispatch
   windows (overlap-safe in any realization order), recorded into a
   shared :class:`~repro.serving.metrics.ServingMetrics`, with counters
-  of rows served (``rows_valid`` / ``rows_bucket`` / ``rows_kernel``)
-  and cache misses (``compiles``), and — while spans are on — the
+  of rows served (``rows_valid`` / ``rows_bucket`` / ``rows_kernel``,
+  and ``rows_lane_packed``, the kernel rows whose tile packs several
+  edges into the lanes) and cache misses (``compiles``), and — while spans are on — the
   ``engine.launch``, ``engine.wait`` and ``engine.d2h`` spans;
 * **fault seams** — an optional
   :class:`~repro.serving.faults.FaultInjector` is consulted at the
@@ -126,6 +127,11 @@ class Workload:
         """Rows the compiled call computes for a ``bucket``-row input
         (a kernel may pad to its own tile).  Default: the bucket."""
         return bucket
+
+    def kernel_lane_pack(self, bucket: int) -> int:
+        """Edges the compiled call's kernel packs into one row of lanes
+        for a ``bucket``-row input.  Default: 1, no packing."""
+        return 1
 
     # -- silent fault seams (optional) --------------------------------------
 
@@ -308,7 +314,8 @@ class ExecutionCore:
         # UNION of dispatch windows, never a double-counted sum
         self._wall_windows: list[tuple[float, float]] = []
         self._cache: dict[tuple, object] = {}
-        self._kernel_rows: dict[int, int] = {}    # bucket -> rows computed
+        # bucket -> (rows computed, of them lane-packed)
+        self._kernel_rows: dict[int, tuple[int, int]] = {}
 
     # -- compile-cache management ------------------------------------------
 
@@ -397,15 +404,18 @@ class ExecutionCore:
         return autotune.bucket_for(self.bucket_sizes, n_events)
 
     def _count_rows(self, n_valid: int, bucket: int, times: int = 1) -> None:
-        """Rows served: valid, padded to the bucket, and as the kernel
-        computes them (its tile decision for the bucket, asked once)."""
+        """Rows served: valid, padded to the bucket, as the kernel
+        computes them, and of those the rows of a lane-packed tile (its
+        tile decision for the bucket, asked once)."""
         rows = self._kernel_rows.get(bucket)
         if rows is None:
-            rows = self._kernel_rows[bucket] = int(
-                self.workload.kernel_rows(bucket))
+            n = int(self.workload.kernel_rows(bucket))
+            packed = self.workload.kernel_lane_pack(bucket) > 1
+            rows = self._kernel_rows[bucket] = (n, n if packed else 0)
         self.metrics.incr("rows_valid", n_valid * times)
         self.metrics.incr("rows_bucket", bucket * times)
-        self.metrics.incr("rows_kernel", rows * times)
+        self.metrics.incr("rows_kernel", rows[0] * times)
+        self.metrics.incr("rows_lane_packed", rows[1] * times)
 
     def warm(self, buckets=None) -> None:
         """Pre-compile (and pre-run once) the given buckets — compile cost

@@ -155,6 +155,15 @@ class TriggerWorkload(Workload):
         tiles = model(self.cfg, self.params, per_dev)
         return tiles["grid"][0] * tiles["block_b"] * self.n_shards
 
+    def kernel_lane_pack(self, bucket: int) -> int:
+        # read off the same tile decision; kernels without lane packing
+        # leave the key out
+        model = self.spec.residency_model
+        if model is None:
+            return 1
+        tiles = model(self.cfg, self.params, bucket // self.n_shards)
+        return int(tiles.get("lane_pack", 1))
+
     def placeholder(self, bucket: int) -> np.ndarray:
         c = self.cfg
         return np.zeros((bucket, c.n_objects, c.n_features), np.float32)

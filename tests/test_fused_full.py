@@ -43,6 +43,27 @@ def test_fused_full_equals_sr_fp32(n_o, fr, fo, batch):
     assert err < 1e-4, f"max abs err {err:.2e} >= 1e-4"
 
 
+@pytest.mark.parametrize("n_o,fr,block_s,lane_pack", [
+    (30, (20, 20, 20), 6, 6),      # 30p widths: 120 of 128 lanes, 5 steps
+    (30, (20, 20, 20), 30, 6),     # 30p widths, one sender step
+    (50, (50, 50, 50), 2, 2),      # 50p widths: one packed row per step
+    (50, (50, 50, 50), 10, 2),
+    (50, (50, 50, 50), 50, 2),
+])
+def test_lane_packed_fused_full_equals_sr_fp32(n_o, fr, block_s, lane_pack):
+    """The kernel packs k edges per 128-lane row at the published
+    widths; at every sender tile it may run, it meets the same bar
+    against forward_sr as the unpacked kernel did."""
+    cfg, params, x = _setup(n_o, fr, fr, 3)
+    tiles = autotune.modeled_residency(cfg, params, 3, block_s=block_s)
+    assert (tiles["lane_pack"], tiles["block_s"]) == (lane_pack, block_s)
+    sr = inet.forward_sr(params, cfg, x)
+    full = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
+                                     block_s=block_s)
+    err = float(jnp.max(jnp.abs(sr - full)))
+    assert err < 1e-4, f"max abs err {err:.2e} >= 1e-4"
+
+
 @pytest.mark.parametrize("batch", [1, 3, 7, 13, 17])
 def test_fused_full_odd_prime_batches(batch):
     """Non-divisible batches are padded to the tile, never degraded."""

@@ -318,6 +318,27 @@ def test_engine_rows_kernel_is_the_bucket_without_a_kernel(jedi30):
     assert eng.workload.kernel_rows(12) == 12
 
 
+def test_engine_counts_lane_packed_rows():
+    """Width-50 f_R (the 50p widths) packs two edges per row: every
+    kernel row is a lane-packed row.  A path without a packing kernel
+    counts none."""
+    cfg = JediNetConfig(n_objects=10, n_features=16, fr_hidden=(50, 50),
+                        fo_hidden=(20,), phi_hidden=(20,))
+    params = init(jax.random.PRNGKey(0), cfg, scale="lecun")
+    x = np.random.RandomState(3).normal(0, 1, (5, 10, 16)).astype(
+        np.float32)
+    eng = ServingEngine(params, cfg, forward="fused_full", interpret=True,
+                        bucket_sizes=[8])
+    assert eng.workload.kernel_lane_pack(8) == 2
+    eng.infer(x)
+    m = eng.metrics
+    assert m.counter("rows_lane_packed") == m.counter("rows_kernel") == 8
+    plain = ServingEngine(params, cfg, forward="sr_split", bucket_sizes=[8])
+    plain.infer(x)
+    assert plain.metrics.counter("rows_kernel") == 8
+    assert plain.metrics.counter("rows_lane_packed") == 0
+
+
 def test_run_plan_spans_prep_launch_wait_d2h(jedi30, engine30):
     """ExecutionCore.run_plan: engine.prep runs up to the compiled call,
     engine.launch is the call, and realization is engine.wait then

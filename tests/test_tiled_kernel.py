@@ -31,8 +31,8 @@ def _setup(n_o, fr_hidden, fo_hidden, batch, **cfg_kw):
 
 
 @pytest.mark.parametrize("block_s", [
-    5,       # block_s ∤ N_o: rounds down to the divisor 4 (3 sender steps)
-    6,       # a divisor of N_o = 12: 2 sender steps
+    5,       # below every candidate (f_R packs k = 6): rounds up to 6
+    6,       # a candidate, 6 | 12 and k | 6: 2 sender steps
     12,      # block_s == N_o: degenerate single sender step (untiled)
     16,      # block_s > N_o: clamped to N_o
 ])
@@ -53,7 +53,7 @@ def test_tiled_matches_reference_across_corner_tiles(block_s, block_b):
 @pytest.mark.parametrize("batch", [1, 3, 7, 11])
 def test_tiled_prime_batches_with_sender_remainder(batch):
     """Prime batches (padded batch tiles) x a non-dividing pinned sender
-    tile (8 rounds down to 6, the largest divisor of 30 below it)."""
+    tile (8 rounds down to 6, the largest candidate of 30 below it)."""
     spec = paths.get("fused_full")
     cfg, params, x = _setup(30, (20, 20, 20), (20, 20, 20), batch)
     ref = spec.ref(params, cfg, x)
@@ -71,7 +71,8 @@ def test_block_s_degenerate_equals_untiled_summand_order():
     cfg, params, x = _setup(12, (16, 12), (10,), 4)
     full = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
                                      block_b=4, block_s=12)
-    for bs in (4, 6):
+    k = autotune.lane_pack(autotune.mlp_widths(params["fr"]), 12)
+    for bs in autotune.sender_tile_candidates(12, k)[:-1]:
         tiled = fj_ops.fused_forward_full(params, cfg, x, interpret=True,
                                           block_b=4, block_s=bs)
         np.testing.assert_allclose(np.asarray(full), np.asarray(tiled),
@@ -152,6 +153,24 @@ def test_int8_in_kernel_matches_hbm_boundary_dequant(qsetup, block_s):
                                rtol=1e-4, atol=1e-5)
     ref = spec.ref(qp, cfg, x)
     assert float(jnp.max(jnp.abs(in_kernel - ref))) < spec.tolerance
+
+
+@pytest.mark.parametrize("arch", ["jedinet-30p", "jedinet-50p"])
+def test_int8_lane_packed_matches_its_reference(arch):
+    """At the published widths int8_fused_full runs packed (k = 6 at
+    30p, 2 at 50p): block-diagonal int8 tensors keep one scale each,
+    and the result stays within the path's tolerance of its reference."""
+    from repro.configs.registry import get_arch
+    cfg = get_arch(arch).model
+    spec = paths.get("int8_fused_full")
+    qp = spec.prepare_params(inet.init(jax.random.PRNGKey(0), cfg,
+                                       scale="lecun"))
+    x, _ = make_jets(np.random.RandomState(1), 3, cfg.n_objects)
+    x = jnp.asarray(x)
+    assert spec.residency_model(cfg, qp, 3)["lane_pack"] > 1
+    err = float(jnp.max(jnp.abs(spec.forward(qp, cfg, x, interpret=True)
+                                - spec.ref(qp, cfg, x))))
+    assert err < spec.tolerance, err
 
 
 def test_partially_quantized_params_rejected_at_boundary(qsetup):
@@ -242,6 +261,98 @@ def test_pick_block_b_s_never_returns_a_non_fitting_tile(batch):
                                                        bs)
     assert autotune.fits_vmem(per)
     assert bb * per <= autotune.VMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("widths,n_o,k", [
+    ([50, 50, 50, 8], 50, 2),      # jedinet-50p: 100 of 128 lanes
+    ([20, 20, 20, 8], 30, 6),      # jedinet-30p: 120 of 128 lanes
+    ([128, 128, 8], 128, 1),       # tracks-128: the lanes are full
+    ([32, 32, 8], 30, 3),          # 128 // 32 = 4 does not divide 30
+])
+def test_lane_pack_rule(widths, n_o, k):
+    """k = 128 // max(f_R widths), lowered until it divides N_o."""
+    assert autotune.lane_pack(widths, n_o) == k
+
+
+def test_sender_tile_candidates_are_multiples_of_the_lane_pack():
+    assert autotune.sender_tile_candidates(50, 2) == [2, 10, 50]
+    assert autotune.sender_tile_candidates(30, 6) == [6, 30]
+    assert autotune.sender_tile_candidates(30, 3) == [3, 6, 15, 30]
+    assert autotune.sender_tile_candidates(128, 1) == \
+        autotune.sender_tile_candidates(128)
+    # a pinned tile rounds to a candidate: down where one is below it,
+    # else up to the smallest
+    assert autotune.sender_tile(8, 30, 6) == 6
+    assert autotune.sender_tile(5, 30, 6) == 6
+    assert autotune.sender_tile(25, 50, 2) == 10
+
+
+@pytest.mark.parametrize("arch,k", [("jedinet-30p", 6), ("jedinet-50p", 2),
+                                    ("jedinet-tracks-128", 1)])
+def test_modeled_residency_returns_the_lane_pack(arch, k):
+    """The tile decision carries k, bills the packed weights, and the
+    traced kernel holds a (N_o, block_b, k*D_e) accumulator."""
+    from repro.analysis import kernel_audit as KA
+    from repro.configs.registry import get_arch
+    cfg = get_arch(arch).model
+    params = inet.init(jax.random.PRNGKey(0), cfg, scale="lecun")
+    spec = paths.get("fused_full")
+    model = spec.residency_model(cfg, params, 64)
+    assert model["lane_pack"] == k
+    assert model["block_s"] % k == 0
+    unpacked = shared_autotune.weight_vmem_bytes(params, cfg.compute_dtype)
+    assert model["weight_residency_bytes"] == \
+        autotune.packed_weight_bytes(params, cfg.compute_dtype, k)
+    assert (model["weight_residency_bytes"] > unpacked) == (k > 1)
+    (kern,) = [KA.TracedKernel(e) for e in KA.find_pallas_calls(
+        KA.trace_forward(spec, cfg, params, 64).jaxpr)]
+    assert kern.grid == tuple(model["grid"])
+    assert [a.shape for a in kern.scratch_avals] == \
+        [(cfg.n_objects, model["block_b"], k * cfg.d_e)]
+
+
+def test_width_128_f_r_traces_the_unpacked_kernel():
+    """k = 1 is the kernel as it was before lane packing: the f_R
+    operands go in as split_first_layer returns them, the accumulator is
+    (N_o, block_b, D_e), and the body has no lane select or fold."""
+    from repro.analysis import kernel_audit as KA
+    from repro.kernels.fused_jedinet import kernel as K
+    cfg, params, _ = _setup(16, (128,), (10,), 8)
+    spec = paths.get("fused_full")
+    model = spec.residency_model(cfg, params, 8)
+    assert model["lane_pack"] == 1
+    assert model["weight_residency_bytes"] == \
+        shared_autotune.weight_vmem_bytes(params, cfg.compute_dtype)
+    fr = K.split_first_layer(params["fr"], cfg.n_features,
+                             dtype=jnp.float32)
+    fr = [fr[0], fr[1], fr[2], *fr[3]]
+    assert all(a is b for a, b in zip(FK.pack_fr_operands(fr, 1), fr))
+    (kern,) = [KA.TracedKernel(e) for e in KA.find_pallas_calls(
+        KA.trace_forward(spec, cfg, params, 8).jaxpr)]
+    blocks = [tuple(KA._dim(d) for d in bm.block_shape)
+              for bm in kern.weight_blocks[:len(fr)]]
+    assert blocks == [a.shape for a in fr]
+    assert [a.shape for a in kern.scratch_avals] == \
+        [(16, model["block_b"], cfg.d_e)]
+    prims = {e.primitive.name for e in KA._iter_eqns(kern.kernel_jaxpr)}
+    assert not prims & {"iota", "select_n", "slice"}, prims
+
+
+@pytest.mark.parametrize("arch,ladder", [
+    ("jedinet-50p", [8, 16, 32, 64, 128, 256, 512, 1024]),
+    ("jedinet-30p", [8, 16, 32, 56, 112, 224, 448, 896, 1064]),
+])
+def test_serving_ladders_keep_their_rungs(arch, ladder):
+    """The serving ladder is the front end's and is derived from the
+    unpacked per-sample model: lane packing leaves every rung where it
+    was, so plan sizes and padding do not move with the kernel."""
+    from repro.configs.registry import get_arch
+    cfg = get_arch(arch).model
+    params = inet.init(jax.random.PRNGKey(0), cfg, scale="lecun")
+    for name in ("fused_full", "int8_fused_full"):
+        spec = paths.get(name)
+        assert spec.bucket_ladder(cfg, spec.prepare_params(params),
+                                  1024) == ladder, name
 
 
 def test_pick_block_s_fits_beside_pinned_block_b():
