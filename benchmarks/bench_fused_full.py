@@ -122,7 +122,7 @@ def run():
     on_tpu = jax.default_backend() == "tpu"
     rows = []
     payload = {"schema": 1, "backend": jax.default_backend(), "configs": {}}
-    names = select_paths()                 # default: the whole registry
+    names = select_paths()                 # default: every JEDI-net path
 
     for cname, n_o, batch, ibatch in (("30p", 30, 256, 16),
                                       ("50p", 50, 128, 8)):

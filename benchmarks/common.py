@@ -9,23 +9,25 @@ import numpy as np
 
 # Forward-path selection for path-parametrized benchmarks, set by
 # ``benchmarks.run --paths``: None = each module's default subset,
-# ["all"] = the whole registry, anything else = explicit names.
+# ["all"] = every JEDI-net path, anything else = explicit names.
 PATH_FILTER: list[str] | None = None
 
 
 def select_paths(default=None) -> list[str]:
     """Resolve the benchmark's path list against the registry.
 
-    ``default`` is the module's own subset (None = whole registry);
-    the ``--paths`` CLI filter overrides it.  Names are validated
+    ``default`` is the module's own subset (None = every JEDI-net path,
+    the model the benchmark modules measure); the ``--paths`` CLI
+    filter overrides it.  Names are validated
     through ``paths.get`` so a typo fails loudly, not by measuring
     nothing.
     """
     from repro.core import paths
     if PATH_FILTER is None:
-        names = list(default) if default is not None else paths.available()
+        names = (list(default) if default is not None
+                 else paths.available(model="jedinet"))
     elif PATH_FILTER == ["all"]:
-        names = paths.available()
+        names = paths.available(model="jedinet")
     else:
         names = list(PATH_FILTER)
     for n in names:

@@ -1,12 +1,14 @@
 """Bring-up check: the trigger serving path end to end on a TPU.
 
     python3 chip_smoke.py             # one chip
+    python3 chip_smoke.py --only part-jetclass:part   # one combination
     python3 chip_smoke.py --chips 4   # the data-parallel engine on four chips
 
-One chip: for each forward path and JEDI-net model below, at the model's
-published widths with seeded random weights, build a
+One chip: for each forward path and jet-tagging model below, at the
+model's published widths with seeded random weights, build a
 ``ResilientEngine`` (``interpret=False``, no mesh, sentinel on), warm
-every bucket of its ladder (printing the compile seconds of each), push
+every bucket of its ladder (printing the compile seconds of each and the
+device's peak memory after it), push
 a few hundred seeded requests through ``ServingLoop``, check every
 request's logits against the path's fp32 reference within the path's
 tolerance, and check the engine's health: every bucket still on the
@@ -42,6 +44,7 @@ ONE_CHIP = (
     ("jedinet-50p", "int8_fused_full"),
     ("jedinet-50p", "int8_jedi_linear_full"),
     ("jedinet-30p", "fused_full"),
+    ("part-jetclass", "part"),
 )
 
 #: Health counters that must stay 0 on a clean run.
@@ -88,6 +91,45 @@ def reference(spec, params, cfg, x):
     return np.concatenate(out)
 
 
+def planned_memory(cfg, params, path: str, buckets) -> list[str]:
+    """For a path whose ladder is sized against HBM: the chip compiler's
+    plan of each rung's step (``Compiled.memory_analysis``: arguments,
+    output, temporaries), beside the path's model of a jet's working set
+    and its HBM budget, which the whole plan must fit."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import paths
+    from repro.serving.engine import TriggerWorkload
+
+    spec = paths.get(path)
+    per_jet = spec.bucket_bytes(cfg, params)
+    work = TriggerWorkload(params, cfg, forward=path, interpret=False,
+                           mesh=None)
+    fails = []
+    for b in buckets:
+        step = work.build(b)
+        fn, args = ((step.func, step.args)
+                    if isinstance(step, functools.partial) else (step, ()))
+        x = jax.ShapeDtypeStruct((b, cfg.n_objects, cfg.n_features),
+                                 jnp.float32)
+        m = fn.lower(*args, x).compile().memory_analysis()
+        total = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes)
+        log(f"  bucket {b:>5} planned: arguments="
+            f"{m.argument_size_in_bytes} output={m.output_size_in_bytes} "
+            f"temp={m.temp_size_in_bytes} code="
+            f"{m.generated_code_size_in_bytes} temp/jet="
+            f"{m.temp_size_in_bytes / b:.0f} model/jet={per_jet}")
+        if total > spec.memory_budget_bytes:
+            fails.append(f"{path} bucket {b}: the compiled step plans "
+                         f"{total} bytes, over the {spec.memory_budget_bytes}"
+                         " the ladder was sized for")
+    return fails
+
+
 def serve_one_chip(arch: str, path: str) -> tuple[list[str], float]:
     """One (model, path) combination; returns (failures, setup seconds)."""
     import jax
@@ -95,25 +137,31 @@ def serve_one_chip(arch: str, path: str) -> tuple[list[str], float]:
 
     from repro.configs.registry import get_arch
     from repro.core import paths
-    from repro.core.interaction_net import init
     from repro.serving import ResilientEngine, ServingLoop
     from repro.serving.sentinel import SentinelConfig
+    from repro.serving.trigger import init_weights
 
     fails = []
     cfg = get_arch(arch).model
     spec = paths.get(path)
-    params = init(jax.random.PRNGKey(SEED), cfg, scale="lecun")
+    params = init_weights(cfg, jax.random.PRNGKey(SEED), scale="lecun")
     t0 = time.perf_counter()
     engine = ResilientEngine(params, cfg, forward=path, interpret=False,
                              mesh=None,
                              sentinel=SentinelConfig(shadow_sync=True))
     log(f"[chip_smoke] {arch} {path}: interpret={engine.interpret} "
         f"platform={engine.platform} ladder={engine.bucket_sizes}")
+    dev = jax.devices()[0]
     for b in engine.bucket_sizes:
         tb = time.perf_counter()
         engine.warm([b])
-        log(f"  bucket {b:>5} compile_s={time.perf_counter() - tb:.3f}")
+        stats = dev.memory_stats() or {}
+        log(f"  bucket {b:>5} compile_s={time.perf_counter() - tb:.3f} "
+            f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
+            f"largest_alloc_size={stats.get('largest_alloc_size')}")
     setup_s = time.perf_counter() - t0
+    if spec.memory_budget_bytes is not None:
+        fails += planned_memory(cfg, params, path, engine.bucket_sizes)
     log(f"  setup_s={setup_s:.3f}")
     if engine.interpret:
         fails.append(f"{arch} {path}: engine runs in interpret mode")
@@ -206,6 +254,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the four-chip data-parallel phase")
+    ap.add_argument("--only", action="append", metavar="MODEL:PATH",
+                    help="one chip: serve only these combinations "
+                         "(repeatable; default: all of them)")
     args = ap.parse_args(argv)
 
     src = pathlib.Path(__file__).resolve().parent / "src"
@@ -235,7 +286,9 @@ def main(argv=None) -> int:
         fails = serve_four_chips()
     else:
         fails, setup = [], 0.0
-        for arch, path in ONE_CHIP:
+        combos = ONE_CHIP if not args.only else [
+            tuple(c.split(":", 1)) for c in args.only]
+        for arch, path in combos:
             f, s = serve_one_chip(arch, path)
             fails += f
             setup += s

@@ -29,7 +29,7 @@ def main():
     # every path vs its own spec-declared reference (small batch: the
     # Pallas kernels run in interpret mode on CPU)
     xs = x[:8]
-    for name in paths.available():
+    for name in paths.available(model=cfg.family):
         spec = paths.get(name)
         p = spec.prepare_params(params)
         out = (spec.forward(p, cfg, xs, interpret=True) if spec.pallas
@@ -48,7 +48,7 @@ def main():
     # wall-clock for the XLA paths (kernel paths are TPU-targeted;
     # interpret-mode timing on CPU says nothing)
     print()
-    for name in paths.available(pallas=False):
+    for name in paths.available(model=cfg.family, pallas=False):
         spec = paths.get(name)
         pparams = spec.prepare_params(params)
         f = jax.jit(lambda p, a, s=spec: s.forward(p, cfg, a))

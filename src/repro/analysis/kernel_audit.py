@@ -414,7 +414,8 @@ def audit_path(spec, cfg, params, *, max_batch: int = 1024):
 
 def audit_registry(cfg, params, *, max_batch: int = 1024,
                    names=None):
-    """Audit every registered path (or the named subset) plus the
+    """Audit every registered path of ``cfg``'s model family (or the
+    named subset) plus the
     registry-level invariants: fallback chains resolve acyclically and
     every Pallas path carries a residency model."""
     from repro.core import paths as registry
@@ -425,7 +426,7 @@ def audit_registry(cfg, params, *, max_batch: int = 1024,
         findings.append(Finding(
             "audit-fallback-chain", "registry", 0,
             f"fallback-chain validation failed: {exc}"))
-    for name in (names or registry.available()):
+    for name in (names or registry.available(model=cfg.family)):
         spec = registry.get(name)
         findings.extend(audit_path(spec, cfg, params, max_batch=max_batch))
     return findings

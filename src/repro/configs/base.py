@@ -159,8 +159,8 @@ JEDI_SHAPES = {
 @dataclasses.dataclass(frozen=True)
 class ArchSpec:
     arch_id: str
-    family: str                  # lm | gnn | recsys | jedi
-    model: Any                   # TransformerConfig | GNNConfig | RecsysConfig | JediNetConfig
+    family: str                  # lm | gnn | recsys | jedinet | part
+    model: Any                   # TransformerConfig | GNNConfig | RecsysConfig | JediNetConfig | ParticleTransformerConfig
     shapes: dict                 # name -> ShapeSpec
     source: str = ""             # citation tag from the assignment
     notes: str = ""

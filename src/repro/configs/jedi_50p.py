@@ -20,7 +20,7 @@ MODEL = JediNetConfig(
 
 ARCH = ArchSpec(
     arch_id="jedinet-50p",
-    family="jedi",
+    family="jedinet",
     model=MODEL,
     shapes=dict(JEDI_SHAPES),
     source="arXiv:1908.05318 + this paper Table 2",

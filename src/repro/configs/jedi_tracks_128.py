@@ -27,7 +27,7 @@ MODEL = JediNetConfig(
 
 ARCH = ArchSpec(
     arch_id="jedinet-tracks-128",
-    family="jedi",
+    family="jedinet",
     model=MODEL,
     shapes=dict(JEDI_SHAPES),
     source="arXiv:2307.07289 (track-graph regime) + this repo",

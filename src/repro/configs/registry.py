@@ -1,8 +1,9 @@
 """Architecture registry: ``get_arch("<id>")`` -> ArchSpec.
 
-Every assigned architecture (plus the paper's own JEDI-net models) registers
-here; the launcher, dry-run sweep, smoke tests and benchmarks all resolve
-archs through this module (``--arch <id>``).
+Every assigned architecture (plus the paper's own JEDI-net models and the
+other jet taggers of the trigger path) registers here; the launcher, dry-run
+sweep, smoke tests and benchmarks all resolve archs through this module
+(``--arch <id>``).
 """
 
 from __future__ import annotations
@@ -29,10 +30,13 @@ ARCH_MODULES = {
     "jedinet-30p": "repro.configs.jedi_30p",
     "jedinet-50p": "repro.configs.jedi_50p",
     "jedinet-tracks-128": "repro.configs.jedi_tracks_128",
+    # jet taggers served beside them on the trigger path
+    "part-jetclass": "repro.configs.part_jetclass",
 }
 
-ASSIGNED_ARCHS = [a for a in ARCH_MODULES if not a.startswith("jedinet")]
-ALL_ARCHS = list(ARCH_MODULES)
+#: Families served on the trigger path (``trigger_serve --arch``); every
+#: other family is an LM / GNN / RecSys arch of the dry-run sweep.
+TRIGGER_FAMILIES = ("jedinet", "part")
 
 
 def get_arch(arch_id: str) -> ArchSpec:
@@ -40,6 +44,16 @@ def get_arch(arch_id: str) -> ArchSpec:
         raise KeyError(f"unknown arch {arch_id!r}; known: {sorted(ARCH_MODULES)}")
     mod = importlib.import_module(ARCH_MODULES[arch_id])
     return mod.ARCH
+
+
+def archs(*families: str) -> list[str]:
+    """Registered arch ids whose family is one of ``families``."""
+    return [a for a in ARCH_MODULES if get_arch(a).family in families]
+
+
+ASSIGNED_ARCHS = [a for a in ARCH_MODULES
+                  if get_arch(a).family not in TRIGGER_FAMILIES]
+ALL_ARCHS = list(ARCH_MODULES)
 
 
 def iter_cells(archs=None, include_skipped: bool = False):

@@ -197,15 +197,22 @@ class TPUModel:
     @classmethod
     def evaluate(cls, pt: TPUDesignPoint, level: str = "edge", *,
                  weight_bytes: int | None = None,
-                 flops_fn: Callable | None = None) -> dict:
+                 flops_fn: Callable | None = None,
+                 bytes_fn: Callable | None = None) -> dict:
         """``flops_fn`` — per-path FLOPs model ``(cfg, batch) -> float``
         (``PathSpec.flops_model``); ``None`` uses the dense edge-grid
         :meth:`flops`.  O(N) paths plug in :func:`jedi_linear_flops` so
         the compute term of the roofline matches their algorithmic
-        class — at N_o=128 the two differ by ~40x."""
+        class — at N_o=128 the two differ by ~40x.  ``bytes_fn`` —
+        per-path HBM model ``(cfg, batch, compute_bytes) -> float``
+        (``PathSpec.bytes_model``), for a model that is not JEDI-net;
+        ``None`` uses :meth:`hbm_bytes`."""
         fl = (flops_fn or cls.flops)(pt.cfg, pt.batch)
-        by = cls.hbm_bytes(pt.cfg, pt.batch, pt.compute_bytes, level,
-                           weight_bytes=weight_bytes)
+        if bytes_fn is not None:
+            by = bytes_fn(pt.cfg, pt.batch, pt.compute_bytes)
+        else:
+            by = cls.hbm_bytes(pt.cfg, pt.batch, pt.compute_bytes, level,
+                               weight_bytes=weight_bytes)
         t_c = fl / (pt.chips * TPU_V5E_BF16_FLOPS)
         t_m = by / (pt.chips * TPU_V5E_HBM_BPS)
         return {
@@ -254,7 +261,8 @@ def jedi_linear_flops(cfg: JediNetConfig, batch: int) -> float:
 def bucket_roofline(cfg: JediNetConfig, buckets, *, level: str = "full",
                     compute_bytes: int = 2, chips: int = 1,
                     weight_bytes: int | None = None,
-                    flops_fn: Callable | None = None) -> dict:
+                    flops_fn: Callable | None = None,
+                    bytes_fn: Callable | None = None) -> dict:
     """TPUModel roofline per serving bucket size.
 
     The batcher pads requests up to ladder buckets, so the question "what
@@ -274,7 +282,7 @@ def bucket_roofline(cfg: JediNetConfig, buckets, *, level: str = "full",
         m = TPUModel.evaluate(
             TPUDesignPoint(cfg=cfg, batch=int(b), chips=chips,
                            compute_bytes=compute_bytes), level,
-            weight_bytes=weight_bytes, flops_fn=flops_fn)
+            weight_bytes=weight_bytes, flops_fn=flops_fn, bytes_fn=bytes_fn)
         m["per_event_us"] = m["step_us"] / int(b)
         out[int(b)] = m
     return out

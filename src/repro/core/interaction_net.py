@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Sequence
+from typing import ClassVar, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,8 @@ class JediNetConfig:
     each of size S.  ``d_e = 8`` is backed out of Fig. 8 (6,960 = D_e * N_E
     remaining adds for the 30p model => D_e = 8).
     """
+
+    family: ClassVar[str] = "jedinet"   # the paths that serve it (PathSpec.model)
 
     n_objects: int = 30          # N_o: particles per jet (30p / 50p datasets)
     n_features: int = 16         # P: features per particle

@@ -74,7 +74,7 @@ class PathSpec:
     tolerance: float = 2e-4                 # max |forward - ref| in fp32
     quantized: bool = False                 # tag: weights are sub-fp32
     weight_bytes: int | None = None         # roofline weight precision override
-    per_sample_bytes: Callable | None = None   # (cfg, params) -> VMEM bytes/jet
+    per_sample_bytes: Callable | None = None   # (cfg, params) -> bytes/jet
     fallback: str | None = None             # degrade-to path (see fallback_chain)
     complexity: str = "O(N^2)"              # aggregation class (COMPLEXITY_CLASSES)
     flops_model: Callable | None = None     # (cfg, batch) -> FLOPs of one step
@@ -84,6 +84,16 @@ class PathSpec:
     #   kernel-contract auditor cross-checks the traced pallas_call
     #   against.  Required for pallas=True paths (the auditor flags its
     #   absence); meaningless for XLA paths.
+    model: str = "jedinet"                  # family of the configs it serves
+    memory_budget_bytes: int | None = None  # HBM a bucket may fill; None:
+    #   the ladder is sized against VMEM (see bucket_ladder)
+    pairs_per_sample: Callable | None = None   # (cfg) -> pairs per jet
+    bytes_model: Callable | None = None     # (cfg, batch, compute_bytes)
+    #   -> HBM bytes of one step, for the roofline (None: TPUModel's
+    #   JEDI-net traffic at ``fused_level``)
+    params_as_args: bool = False            # the served step takes the
+    #   weights as a jit argument; False: they are HLO constants of every
+    #   rung (serving.engine.TriggerWorkload.build)
     description: str = ""
 
     def __post_init__(self):
@@ -108,7 +118,8 @@ class PathSpec:
         return compute_dtype in self.compute_dtypes
 
     def bucket_bytes(self, cfg, params) -> int:
-        """Per-sample VMEM working set driving the serving bucket ladder.
+        """Per-sample working set driving the serving bucket ladder: in
+        VMEM, or in HBM for a path that sets ``memory_budget_bytes``.
 
         Defaults to the sender-TILED whole-network kernel's estimate at
         the smallest sender tile — the deepest honest ladder, since the
@@ -142,12 +153,25 @@ class PathSpec:
         :func:`repro.kernels.autotune.bucket_ladder` under the path's
         OWN per-sample working set and weight-residency reservation —
         the per-path policy every consumer (engine, CLI ``--list-paths``,
-        benchmarks) resolves through one call."""
+        benchmarks) resolves through one call.
+
+        A path that sets ``memory_budget_bytes`` (an XLA path whose
+        activations live in HBM) sizes its rungs against that budget
+        instead of VMEM, and its ladder stops at the largest rung whose
+        whole working set fits."""
         from repro.kernels import autotune
-        kw = {} if budget_bytes is None else {"budget_bytes": budget_bytes}
-        return autotune.bucket_ladder(
-            max_batch, self.bucket_bytes(cfg, params),
-            reserved_bytes=self.reserved_vmem_bytes(cfg, params), **kw)
+        per = self.bucket_bytes(cfg, params)
+        reserved = self.reserved_vmem_bytes(cfg, params)
+        if budget_bytes is None:
+            budget_bytes = (self.memory_budget_bytes
+                            or autotune.VMEM_BUDGET_BYTES)
+        if self.memory_budget_bytes is not None:
+            # one call holds its whole bucket in that memory, with no
+            # batch grid to tile it: no rung above the largest that fits
+            max_batch = min(max_batch, autotune.fitting_batch(
+                per, budget_bytes, reserved))
+        return autotune.bucket_ladder(max_batch, per, budget_bytes,
+                                      reserved_bytes=reserved)
 
     def flops_for(self, cfg, batch: int) -> float:
         """Modeled FLOPs of one batched forward step through this path.
@@ -170,7 +194,8 @@ class PathSpec:
         return codesign.bucket_roofline(
             cfg, buckets, level=self.fused_level,
             compute_bytes=compute_bytes, chips=chips,
-            weight_bytes=self.weight_bytes, flops_fn=self.flops_model)
+            weight_bytes=self.weight_bytes, flops_fn=self.flops_model,
+            bytes_fn=self.bytes_model)
 
     def audit(self, cfg, params, *, max_batch: int = 1024) -> list:
         """Statically audit this path's kernel contract: trace the
@@ -198,6 +223,7 @@ _BUILTIN_MODULES = (
     "repro.core.interaction_net",
     "repro.core.int8_path",
     "repro.core.jedi_linear_path",
+    "repro.core.particle_transformer",
 )
 _builtins_state = "pending"           # "pending" -> "loading" -> "done"
 
@@ -356,9 +382,12 @@ def describe(names: Sequence[str] | None = None, *, cfg=None, params=None,
         from repro.core.codesign import path_bucket_policy
         lines.append("")
         lines.append(f"bucket policy @ n_objects={cfg.n_objects} "
-                     f"max_batch={max_batch} (per-path VMEM model):")
+                     f"max_batch={max_batch} (per-path memory model; "
+                     f"{cfg.family} paths):")
         lines.append(f"{'path':<22} {'B/sample':>9} {'reservedB':>10} ladder")
         for s in rows:
+            if s.model != cfg.family:
+                continue
             pol = path_bucket_policy(s, cfg, params, max_batch=max_batch,
                                      roofline=False)
             lines.append(

@@ -98,6 +98,15 @@ def pick_block_b(batch: int, per_sample_bytes: int,
     return padded_batch(-(-whole // steps), _SUBLANE)
 
 
+def fitting_batch(per_sample_bytes: int, budget_bytes: int,
+                  reserved_bytes: int = 0) -> int:
+    """Most rows, in whole sublane tiles, whose working set fits what
+    the budget leaves after ``reserved_bytes`` (one tile at least)."""
+    room = effective_budget(budget_bytes, reserved_bytes)
+    return max(_SUBLANE,
+               room // max(per_sample_bytes, 1) // _SUBLANE * _SUBLANE)
+
+
 def bucket_ladder(max_batch: int, per_sample_bytes: int,
                   budget_bytes: int = VMEM_BUDGET_BYTES, *,
                   reserved_bytes: int = 0) -> list[int]:

@@ -148,10 +148,12 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
 
-    from repro.configs.registry import ALL_ARCHS, ASSIGNED_ARCHS, get_arch
+    from repro.configs.registry import ASSIGNED_ARCHS, get_arch
+    from repro.configs.registry import archs as family_archs
 
     archs = ([args.arch] if args.arch
-             else (ALL_ARCHS if args.include_jedi else ASSIGNED_ARCHS))
+             else ASSIGNED_ARCHS + (family_archs("jedinet")
+                                    if args.include_jedi else []))
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]
 

@@ -487,7 +487,7 @@ def build_cell(arch: ArchSpec, shape: ShapeSpec, mesh) -> CellProgram:
             return fm_serve_cell(arch, shape, mesh)
         if kind == "retrieval":
             return fm_retrieval_cell(arch, shape, mesh)
-    if fam == "jedi":
+    if fam == "jedinet":
         if kind == "jedi_infer":
             return jedi_infer_cell(arch, shape, mesh)
         if kind == "jedi_train":

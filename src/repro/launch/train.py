@@ -59,7 +59,7 @@ def make_program(arch_id: str, batch: int, lr: float):
 
     arch = get_arch(arch_id)
 
-    if arch.family == "jedi":
+    if arch.family == "jedinet":
         from repro.core import interaction_net as inet
         from repro.data.jets import jet_batches
         cfg = arch.model

@@ -26,7 +26,8 @@ bucket ladder is — is declared by a :class:`Workload`.
   shared :class:`~repro.serving.metrics.ServingMetrics`, with counters
   of rows served (``rows_valid`` / ``rows_bucket`` / ``rows_kernel``,
   and ``rows_lane_packed``, the kernel rows whose tile packs several
-  edges into the lanes) and cache misses (``compiles``), and — while spans are on — the
+  edges into the lanes), of particle pairs evaluated (``pairs_kernel``)
+  and cache misses (``compiles``), and — while spans are on — the
   ``engine.launch``, ``engine.wait`` and ``engine.d2h`` spans;
 * **fault seams** — an optional
   :class:`~repro.serving.faults.FaultInjector` is consulted at the
@@ -132,6 +133,11 @@ class Workload:
         """Edges the compiled call's kernel packs into one row of lanes
         for a ``bucket``-row input.  Default: 1, no packing."""
         return 1
+
+    def kernel_pairs(self, bucket: int) -> int:
+        """Particle pairs the compiled call evaluates for a
+        ``bucket``-row input.  Default: 0, none counted."""
+        return 0
 
     # -- silent fault seams (optional) --------------------------------------
 
@@ -314,8 +320,8 @@ class ExecutionCore:
         # UNION of dispatch windows, never a double-counted sum
         self._wall_windows: list[tuple[float, float]] = []
         self._cache: dict[tuple, object] = {}
-        # bucket -> (rows computed, of them lane-packed)
-        self._kernel_rows: dict[int, tuple[int, int]] = {}
+        # bucket -> (rows computed, of them lane-packed, pairs evaluated)
+        self._kernel_rows: dict[int, tuple[int, int, int]] = {}
 
     # -- compile-cache management ------------------------------------------
 
@@ -406,16 +412,19 @@ class ExecutionCore:
     def _count_rows(self, n_valid: int, bucket: int, times: int = 1) -> None:
         """Rows served: valid, padded to the bucket, as the kernel
         computes them, and of those the rows of a lane-packed tile (its
-        tile decision for the bucket, asked once)."""
+        tile decision for the bucket, asked once); and the particle
+        pairs the step evaluates."""
         rows = self._kernel_rows.get(bucket)
         if rows is None:
             n = int(self.workload.kernel_rows(bucket))
             packed = self.workload.kernel_lane_pack(bucket) > 1
-            rows = self._kernel_rows[bucket] = (n, n if packed else 0)
+            rows = self._kernel_rows[bucket] = (
+                n, n if packed else 0, int(self.workload.kernel_pairs(bucket)))
         self.metrics.incr("rows_valid", n_valid * times)
         self.metrics.incr("rows_bucket", bucket * times)
         self.metrics.incr("rows_kernel", rows[0] * times)
         self.metrics.incr("rows_lane_packed", rows[1] * times)
+        self.metrics.incr("pairs_kernel", rows[2] * times)
 
     def warm(self, buckets=None) -> None:
         """Pre-compile (and pre-run once) the given buckets — compile cost

@@ -71,6 +71,10 @@ class TriggerWorkload(Workload):
     def __init__(self, params, cfg, *, forward: str = "fused_full",
                  interpret: bool | None = None, mesh="auto"):
         self.spec = forward_paths.get(forward)   # raises listing choices
+        if self.spec.model != cfg.family:
+            raise ValueError(
+                f"path {forward!r} serves {self.spec.model} models, not a "
+                f"{type(cfg).__name__}")
         if not self.spec.supports_dtype(cfg.compute_dtype):
             raise ValueError(
                 f"path {forward!r} supports compute dtypes "
@@ -136,11 +140,20 @@ class TriggerWorkload(Workload):
             call = shard_map_unchecked(call, self.mesh,
                                        in_specs=(P(), P("data")),
                                        out_specs=P("data"))
+        # the step is a function named ``trigger_step``: it is
+        # ``jit_trigger_step`` in HLO and on the trace's XLA Modules line
+        if self.spec.params_as_args:
+            # no rung's program holds a copy of the weights, so its
+            # compile-cache entry stays small
+            def trigger_step(params, x):
+                return call(params, x)
+
+            return functools.partial(jax.jit(trigger_step), self.params)
+
         params = self.params
 
         def trigger_step(x):
-            # a named function: the step is ``jit_trigger_step`` in HLO
-            # and on the trace's XLA Modules line
+            # the weights closed over: HLO constants of every rung
             return call(params, x)
 
         return jax.jit(trigger_step)
@@ -163,6 +176,11 @@ class TriggerWorkload(Workload):
             return 1
         tiles = model(self.cfg, self.params, bucket // self.n_shards)
         return int(tiles.get("lane_pack", 1))
+
+    def kernel_pairs(self, bucket: int) -> int:
+        # particle pairs the path evaluates per jet, as it declares
+        per_jet = self.spec.pairs_per_sample
+        return 0 if per_jet is None else bucket * int(per_jet(self.cfg))
 
     def placeholder(self, bucket: int) -> np.ndarray:
         c = self.cfg

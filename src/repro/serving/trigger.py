@@ -29,8 +29,8 @@ import jax
 import numpy as np
 
 from repro.common.compile_cache import setup_compile_cache
-from repro.configs.registry import ARCH_MODULES, get_arch
-from repro.core import paths
+from repro.configs.registry import TRIGGER_FAMILIES, archs, get_arch
+from repro.core import particle_transformer, paths
 from repro.core.interaction_net import init
 from repro.data.jets import make_jets
 from repro.serving.faults import SILENT_SEAMS, FaultInjector
@@ -45,6 +45,14 @@ def make_stream(rng, n_batches: int, batch: int, n_objects: int,
     latencies below must measure transfer+compute, not the generator."""
     return [make_jets(rng, batch, n_objects, n_features)[0]
             for _ in range(n_batches)]
+
+
+def init_weights(cfg, key, *, scale: str = "fan_in"):
+    """Seeded weights of a trigger model at ``cfg``'s widths, by its
+    family; ``scale`` is JEDI-net's variance-scaling rule."""
+    if cfg.family == "part":
+        return particle_transformer.init_params(key, cfg)
+    return init(key, cfg, scale=scale)
 
 
 def print_health(engine) -> None:
@@ -102,10 +110,10 @@ def parse_drills(specs, injector, path) -> None:
 def build_trigger_cli(ap) -> None:
     """Install the trigger-serve arguments on an ``argparse`` parser."""
     ap.add_argument("--arch", default="jedinet-30p",
-                    choices=[a for a in ARCH_MODULES
-                             if a.startswith("jedinet")],
-                    help="JEDI-net model at its published widths "
-                         "(configs/registry.py)")
+                    choices=archs(*TRIGGER_FAMILIES),
+                    help="jet tagger at its published widths "
+                         "(configs/registry.py); pair it with a --forward "
+                         "path of its family (--list-paths)")
     ap.add_argument("--batch", type=int, default=256,
                     help="events per stream tick (the trigger's time slice)")
     ap.add_argument("--batches", type=int, default=40)
@@ -151,7 +159,7 @@ def run_trigger_cli(args) -> int:
     """Serve a synthetic stream per parsed ``args`` and print the report.
     Returns the process exit code."""
     cfg = get_arch(args.arch).model.with_(compute_dtype=args.compute_dtype)
-    params = init(jax.random.PRNGKey(args.seed), cfg)
+    params = init_weights(cfg, jax.random.PRNGKey(args.seed))
     if args.list_paths:
         # Registry table PLUS each path's resolved bucket policy (per-
         # sample VMEM model, weight residency, the ladder it earns) for

@@ -130,3 +130,21 @@ def test_registry_covers_assignment():
     assert len(total) == 40
     runnable = list(iter_cells())
     assert len(runnable) == 36       # 4 documented long_500k skips
+
+
+def test_part_jetclass_is_a_trigger_arch_of_its_own_family():
+    """ParT registers with family ``part``: served on the trigger path,
+    never picked up by the LM/GNN dry-run sweep."""
+    from repro.configs.registry import (ASSIGNED_ARCHS, TRIGGER_FAMILIES,
+                                        archs, iter_cells)
+    spec = get_arch("part-jetclass")
+    assert spec.family == "part" == spec.model.family
+    assert "part-jetclass" in archs(*TRIGGER_FAMILIES)
+    assert "part-jetclass" not in ASSIGNED_ARCHS
+    assert all(a.family not in TRIGGER_FAMILIES for a, _ in iter_cells())
+    assert archs("jedinet") == ["jedinet-30p", "jedinet-50p",
+                                "jedinet-tracks-128"]
+    # one vocabulary: an arch's family is its model's, which the paths
+    # that serve it name (PathSpec.model)
+    for a in archs(*TRIGGER_FAMILIES):
+        assert get_arch(a).family == get_arch(a).model.family, a

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import interaction_net as inet
+from repro.core import particle_transformer as pt
 from repro.core import paths
 from repro.core.int8_path import dequantize_params, quantize_params_int8
 from repro.data.jets import make_jets
@@ -27,6 +28,23 @@ def jedi():
     params = inet.init(jax.random.PRNGKey(0), cfg, scale="lecun")
     x, _ = make_jets(np.random.RandomState(1), 4, 16)
     return cfg, params, jnp.asarray(x)
+
+
+@pytest.fixture(scope="module")
+def part():
+    cfg = pt.ParticleTransformerConfig(
+        n_objects=16, embed_dims=(32, 64, 32), pair_embed_dims=(8, 8, 8, 4),
+        n_heads=4, n_blocks=1, n_cls_blocks=1, ffn_dim=64)
+    params = pt.init_params(jax.random.PRNGKey(0), cfg)
+    x, _ = make_jets(np.random.RandomState(1), 4, 16, cfg.n_features)
+    return cfg, params, jnp.asarray(x)
+
+
+@pytest.fixture
+def model_inputs(request, jedi, part):
+    """(cfg, params, x) of the model family a path serves."""
+    return {"jedinet": jedi, "part": part}[
+        paths.get(request.node.callspec.params["name"]).model]
 
 
 def _call(spec, params, cfg, x):
@@ -219,10 +237,11 @@ def test_describe_prints_fallback_chains():
 
 
 @pytest.mark.parametrize("name", paths.available())
-def test_path_matches_its_reference_within_tolerance(name, jedi):
+def test_path_matches_its_reference_within_tolerance(name, model_inputs):
     """The registry IS the test matrix: any newly registered path gets
-    checked against its own ref fn at its own declared tolerance."""
-    cfg, params, x = jedi
+    checked against its own ref fn at its own declared tolerance, on a
+    small model of the family it serves."""
+    cfg, params, x = model_inputs
     spec = paths.get(name)
     pparams = spec.prepare_params(params)
     got = _call(spec, pparams, cfg, x)
@@ -234,10 +253,10 @@ def test_path_matches_its_reference_within_tolerance(name, jedi):
 
 
 @pytest.mark.parametrize("name", paths.available(transform_params=None))
-def test_untransformed_paths_accept_raw_params(name, jedi):
+def test_untransformed_paths_accept_raw_params(name, model_inputs):
     """Paths with no transform hook must run on raw init() params
     (prepare_params is the identity)."""
-    cfg, params, x = jedi
+    cfg, params, x = model_inputs
     spec = paths.get(name)
     assert spec.prepare_params(params) is params
     _call(spec, params, cfg, x)

@@ -466,8 +466,11 @@ def test_describe_with_cfg_prints_resolved_policy():
     table = paths.describe(cfg=cfg, params=params, max_batch=32)
     assert "bucket policy" in table and "ladder" in table
     assert "reservedB" in table
-    for n in paths.available():
+    for n in paths.available(model=cfg.family):
         assert table.count(n) >= 2        # static row + policy row
+    # a path of another model family has its static row only
+    policy = table.split("bucket policy", 1)[1]
+    assert "\npart " not in policy and "\npart " in table
 
 
 def test_trigger_serve_list_paths_prints_policy(capsys):

@@ -106,3 +106,34 @@ def test_step_and_kernel_names_for_v5e(one_chip):
     assert calls and all(c.startswith("%fused_forward_full")
                          or c.startswith("fused_forward_full")
                          for c in calls), calls
+
+
+def test_part_step_names_scopes_and_memory_for_v5e(one_chip):
+    """ParT's served step compiles for the chip as ``jit_trigger_step``
+    at the published widths, its pair embedding and two attention
+    stacks carry their named scopes in the compiled program's op
+    metadata, and the compiler's temporaries stay within the path's
+    HBM model (``particle_transformer.hbm_bytes_per_sample``)."""
+    from repro.core import particle_transformer as pt
+    from repro.serving.engine import TriggerWorkload
+
+    cfg = get_arch("part-jetclass").model
+    work = TriggerWorkload(pt.init_params(jax.random.PRNGKey(0), cfg), cfg,
+                           forward="part", interpret=False, mesh=None)
+    bucket = 64
+    assert bucket in work.bucket_ladder(1024)
+    x = jax.ShapeDtypeStruct((bucket, cfg.n_objects, cfg.n_features),
+                             jnp.float32, sharding=one_chip)
+    step = work.build(bucket)
+    compiled = step.func.lower(*step.args, x).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_trigger_step"), hlo[:80]
+    for scope in ("part.pair_embed", "part.particle_blocks",
+                  "part.class_blocks"):
+        assert f"/{scope}/" in hlo, scope
+    # the weights are an argument of the step, not constants of it
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * pt.weight_count(cfg)
+    temp = mem.temp_size_in_bytes
+    assert 0.5 * bucket * pt.hbm_bytes_per_sample(cfg) < temp \
+        <= bucket * pt.hbm_bytes_per_sample(cfg), temp
