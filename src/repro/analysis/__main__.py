@@ -42,9 +42,9 @@ def _audit(arch: str, max_batch: int, path_names):
     import jax
 
     from repro.analysis.kernel_audit import audit_registry
-    from repro.core import interaction_net
+    from repro.serving.trigger import init_weights
     cfg = importlib.import_module(f"repro.configs.{arch}").MODEL
-    params = interaction_net.init(jax.random.PRNGKey(0), cfg)
+    params = init_weights(cfg, jax.random.PRNGKey(0))
     return audit_registry(cfg, params, max_batch=max_batch,
                           names=path_names or None)
 

@@ -21,14 +21,19 @@ N x N ordered
 pairs (twice the 8,128 unordered pairs the model needs: U is symmetric,
 so the second half is redundant work that ``step_mfu`` shows), and the
 three stages carry the named scopes ``part.pair_embed``,
-``part.particle_blocks`` and ``part.class_blocks``.  The path ``part``
-(registered below) serves it; its activations live in HBM, so its
-bucket ladder is sized against an HBM budget (:func:`hbm_bytes_per_sample`).
+``part.particle_blocks`` and ``part.class_blocks``.  Two paths serve it
+(registered below): ``part`` runs the pair embedding as one Pallas
+kernel (``kernels/part_pair``, :func:`pair_bias_kernel`) whose 64-wide
+activations never leave VMEM; ``part_xla``, its fallback, is the plain
+XLA program.  The other activations live in HBM, so both ladders are
+sized against an HBM budget (:func:`hbm_bytes_per_sample`,
+:func:`hbm_bytes_per_sample_xla`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import ClassVar
 
@@ -37,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.paths import register_path
+from repro.kernels.part_pair import ops as part_pair
 
 #: LayerNorm and BatchNorm epsilon (PyTorch's default, as weaver-core uses).
 NORM_EPS = 1e-5
@@ -243,20 +249,36 @@ def particle_mask(x):
     return jnp.any(x != 0, axis=-1).astype(jnp.float32)
 
 
+def pair_keep(mask):
+    """1.0 for a pair of two distinct particles, 0.0 on the diagonal and
+    for a pair with a padding particle, (B, N, N)."""
+    n = mask.shape[1]
+    return (mask[:, :, None] * mask[:, None, :]
+            * (1.0 - jnp.eye(n, dtype=jnp.float32)))
+
+
 def pair_bias(params, cfg: ParticleTransformerConfig, x, mask):
     """U, one attention bias per head for every pair, (B, H, N, N):
     symmetric, 0 on the diagonal and on every pair with a padding
-    particle."""
-    n = x.shape[1]
+    particle.  Plain XLA: each pair layer's activations go through HBM."""
     u = pair_features(x)
     folded = _fold_pair_layers(params["pair"])
     for i, (w, b) in enumerate(folded):
         u = u @ w + b
         if i < len(folded) - 1:
             u = _gelu(u)
-    keep = (mask[:, :, None] * mask[:, None, :]
-            * (1.0 - jnp.eye(n, dtype=jnp.float32)))
-    return jnp.transpose(u, (0, 3, 1, 2)) * keep[:, None]
+    return jnp.transpose(u, (0, 3, 1, 2)) * pair_keep(mask)[:, None]
+
+
+def pair_bias_kernel(params, cfg: ParticleTransformerConfig, x, mask, *,
+                     interpret: bool = False):
+    """:func:`pair_bias` through the ``part_pair_embed`` kernel: the pair
+    features channel-major (B, 4, N, N) in, U written once, masked."""
+    tiles = part_pair.modeled_residency(cfg, params, x.shape[0])
+    return part_pair.part_pair_embed(
+        jnp.moveaxis(pair_features(x), -1, 1), pair_keep(mask),
+        _fold_pair_layers(params["pair"]), block_b=tiles["block_b"],
+        interpret=interpret)
 
 
 def pair_bias_unordered(params, cfg: ParticleTransformerConfig, x, mask):
@@ -390,8 +412,18 @@ def flops_per_sample(cfg: ParticleTransformerConfig) -> int:
 
 
 def hbm_bytes_per_sample(cfg: ParticleTransformerConfig) -> int:
-    """HBM working set of one jet in the compiled step, at its peak in
-    the pair embedding: the input and the output of the widest pair
+    """HBM working set of one jet in the ``part`` step, whose pair MLP
+    runs in VMEM: the kernel's (5, N*N) input slab, U and the attention
+    logits, float32.  (Compiled for a v5e at the published widths, the
+    step's temporaries come to 1.2 MiB a jet at rung 1024; this gives
+    1.3.)"""
+    return 4 * part_pair.padded_pairs(cfg.n_objects) * (
+        part_pair.IN_ROWS + 2 * cfg.n_heads)
+
+
+def hbm_bytes_per_sample_xla(cfg: ParticleTransformerConfig) -> int:
+    """HBM working set of one jet in the ``part_xla`` step, at its peak
+    in the pair embedding: the input and the output of the widest pair
     layer, (N, N, max pair width) float32 each, and U's (H, N, N)
     float32 as margin.  (Compiled for a v5e at the published widths,
     the step's temporaries come to 8.1-8.4 MiB a jet; this gives 8.5.)"""
@@ -399,18 +431,24 @@ def hbm_bytes_per_sample(cfg: ParticleTransformerConfig) -> int:
     return 4 * n * n * (2 * max(cfg.pair_embed_dims) + cfg.n_heads)
 
 
-def _per_sample_bytes(cfg, params):
-    return hbm_bytes_per_sample(cfg)
-
-
 def _flops(cfg, batch):
     return float(flops_per_sample(cfg) * batch)
 
 
 def _hbm_traffic(cfg, batch, compute_bytes):
-    """Least HBM traffic of one step: the weights once, the input in,
-    the logits out, and each pair layer's activations written and read
-    once (the program's pair embedding cannot keep them on chip)."""
+    """Least HBM traffic of one ``part`` step: the weights once, the
+    input in, the logits out, and the kernel's input slab and U each
+    written and read once."""
+    acts = (cfg.n_objects * cfg.n_features + cfg.n_targets
+            + 2 * part_pair.padded_pairs(cfg.n_objects)
+            * (part_pair.IN_ROWS + cfg.n_heads))
+    return float(weight_count(cfg) * compute_bytes + acts * batch * 4)
+
+
+def _hbm_traffic_xla(cfg, batch, compute_bytes):
+    """Least HBM traffic of one ``part_xla`` step: the weights once, the
+    input in, the logits out, and each pair layer's activations written
+    and read once (the XLA pair embedding cannot keep them on chip)."""
     n = cfg.n_objects
     pair_w = (N_PAIR_FEATURES, *cfg.pair_embed_dims)
     acts = 2 * n * n * sum(pair_w) + n * cfg.n_features + cfg.n_targets
@@ -433,27 +471,52 @@ def weight_count(cfg: ParticleTransformerConfig) -> int:
             + d + 2 * d + d * cfg.n_targets + cfg.n_targets)
 
 
-@register_path(
-    name="part",
+#: Fields the two ParT paths share: the model, its reference, work and
+#: HBM-sized ladder, and weights passed as a jit argument (8.6 MB of
+#: them: as constants they would fill every rung's program and its
+#: compile-cache entry).
+_PART_PATH = dict(
     ref=reference,
     model="part",
     fused_level="none",
     complexity="O(N^2)",              # the pair embedding over N x N pairs
-    fallback=None,                    # terminal: no other model stands in
     compute_dtypes=("float32",),
     # the reference-class bar of the XLA paths; measured 1.7e-6 on one
     # TPU v5 lite at the published widths, ~1e-6 on the CPU
     tolerance=2e-4,
-    per_sample_bytes=_per_sample_bytes,
     memory_budget_bytes=HBM_BUDGET_BYTES,
     pairs_per_sample=pairs_per_sample,
     flops_model=_flops,
-    bytes_model=_hbm_traffic,
-    # 8.6 MB of weights: as constants they would fill every rung's
-    # program and its compile-cache entry
     params_as_args=True,
-    description="Particle Transformer (XLA), activations in HBM",
 )
-def forward_part(params, cfg, x):
-    """The ``part`` path: :func:`forward`."""
+
+
+@register_path(
+    name="part",
+    pallas=True,
+    fallback="part_xla",
+    per_sample_bytes=lambda cfg, params: hbm_bytes_per_sample(cfg),
+    bytes_model=_hbm_traffic,
+    residency_model=part_pair.modeled_residency,
+    description="Particle Transformer, pair embedding in one Pallas "
+                "kernel (part_pair_embed)",
+    **_PART_PATH,
+)
+def forward_part(params, cfg, x, *, interpret: bool = False):
+    """The ``part`` path: :func:`forward` with U from
+    :func:`pair_bias_kernel`."""
+    return forward(params, cfg, x, bias_fn=functools.partial(
+        pair_bias_kernel, interpret=interpret))
+
+
+@register_path(
+    name="part_xla",
+    fallback=None,                    # terminal: plain XLA
+    per_sample_bytes=lambda cfg, params: hbm_bytes_per_sample_xla(cfg),
+    bytes_model=_hbm_traffic_xla,
+    description="Particle Transformer (XLA), activations in HBM",
+    **_PART_PATH,
+)
+def forward_part_xla(params, cfg, x):
+    """The ``part_xla`` path: :func:`forward`."""
     return forward(params, cfg, x)

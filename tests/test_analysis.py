@@ -40,6 +40,12 @@ def params():
     return interaction_net.init(jax.random.PRNGKey(0), CFG)
 
 
+def _part_model():
+    from repro.configs.part_jetclass import MODEL
+    from repro.core import particle_transformer as pt
+    return MODEL, pt.init_params(jax.random.PRNGKey(0), MODEL)
+
+
 # ---------------------------------------------------------------------------
 # Repo-clean gates (the tier-1 wiring of `python -m repro.analysis`).
 # ---------------------------------------------------------------------------
@@ -54,16 +60,21 @@ def test_kernel_audit_reports_zero_findings(params):
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_every_pallas_path_is_audited_at_every_rung(params):
+@pytest.mark.parametrize("family", ["jedinet", "part"])
+def test_every_pallas_path_is_audited_at_every_rung(params, family):
     """The drift check actually covers each Pallas path's whole ladder:
-    the residency model must answer (consistently) at every rung."""
-    for spec in paths.specs(pallas=True):
+    the residency model must answer (consistently) at every rung, for
+    each model family at its published widths."""
+    cfg, params = {"jedinet": (CFG, params), "part": _part_model()}[family]
+    specs = paths.specs(pallas=True, model=family)
+    assert specs
+    for spec in specs:
         assert spec.residency_model is not None, spec.name
         tparams = spec.prepare_params(params)
-        ladder = spec.bucket_ladder(CFG, tparams, 1024)
+        ladder = spec.bucket_ladder(cfg, tparams, 1024)
         assert ladder, spec.name
         for rung in ladder:
-            model = spec.residency_model(CFG, tparams, rung)
+            model = spec.residency_model(cfg, tparams, rung)
             assert model["fits"], (spec.name, rung)
             assert model["block_b"] * model["per_sample_bytes"] <= \
                 model["effective_budget"], (spec.name, rung)

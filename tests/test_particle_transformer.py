@@ -1,12 +1,13 @@
 """Particle Transformer on the trigger path, at a small size on the CPU.
 
-The ``part`` path is held to the plain per-jet reference of the test
+The ``part`` path (its pair embedding the ``part_pair_embed`` kernel, in
+interpret mode here) is held to the plain per-jet reference of the test
 tree (``part_reference.py``, nothing of the program) on seeded random
 weights, through the normal serving path (``ResilientEngine`` and
 ``ServingLoop``); the model's symmetries (particle permutation, zero
-padding, a symmetric pair bias) and the pair features' exact massless
-forms are checked on their own; the path's ladder keeps within its HBM
-model.
+padding, a symmetric pair bias, on both paths) and the pair features'
+exact massless forms are checked on their own; each path's ladder keeps
+within its HBM model.
 
 Tolerances: the program and the reference compute the same float32
 arithmetic in different orders (BatchNorms folded into the pair
@@ -66,7 +67,7 @@ def test_part_path_through_the_loop_matches_the_reference(model, sizes):
     cfg, params = model
     engine = ResilientEngine(params, cfg, forward="part", mesh=None,
                              max_batch=16)
-    assert engine.chain == ["part"]
+    assert engine.chain == ["part", "part_xla"]
     loop = ServingLoop(engine, deadline_s=1e9)
     xs = np.split(_jets(sum(sizes)), np.cumsum(sizes)[:-1])
     futures = [loop.submit(x) for x in xs]
@@ -104,14 +105,20 @@ def test_zero_padding_rows_leave_the_logits_unchanged(model):
                                atol=TOL, rtol=0)
 
 
-def test_pair_bias_is_symmetric_with_a_zero_diagonal(model):
+#: U of each path: the kernel (interpret mode) and plain XLA.
+BIAS_FNS = {"part": lambda *a: pt.pair_bias_kernel(*a, interpret=True),
+            "part_xla": pt.pair_bias}
+
+
+@pytest.mark.parametrize("path", sorted(BIAS_FNS))
+def test_pair_bias_is_symmetric_with_a_zero_diagonal(model, path):
     cfg, params = model
     x = _jets(3).copy()
     x[1, 12:] = 0.0                               # 4 padding particles
     x = jnp.asarray(x)
     mask = pt.particle_mask(x)
     with jax.default_matmul_precision("highest"):
-        u = np.asarray(pt.pair_bias(params, cfg, x, mask))
+        u = np.asarray(BIAS_FNS[path](params, cfg, x, mask))
         ref = np.asarray(pt.pair_bias_unordered(params, cfg, x, mask))
     assert u.shape == (3, cfg.n_heads, cfg.n_objects, cfg.n_objects)
     # the pair features are exactly symmetric; the matmul may sum a
@@ -157,24 +164,28 @@ def test_stable_m2_is_the_massless_invariant_mass():
     np.testing.assert_allclose(stable[off], m2[off], rtol=1e-9)
 
 
-def test_ladder_respects_its_hbm_model():
+@pytest.mark.parametrize("path,model_bytes,per_jet", [
+    ("part", pt.hbm_bytes_per_sample, 1_376_256),        # 1.3 MiB
+    ("part_xla", pt.hbm_bytes_per_sample_xla, 8_912_896),  # 8.5 MiB
+])
+def test_ladder_respects_its_hbm_model(path, model_bytes, per_jet):
     """At the published widths the ladder is sized against the HBM
     budget: every rung's working set fits, and the top rung is the
     largest that does, or max_batch."""
     cfg = pt.ParticleTransformerConfig()
     params = pt.init_params(jax.random.PRNGKey(0), cfg)
-    spec = paths.get("part")
+    spec = paths.get(path)
     per = spec.bucket_bytes(cfg, params)
-    assert per == pt.hbm_bytes_per_sample(cfg) == 8_912_896
+    assert per == model_bytes(cfg) == per_jet
     reserved = spec.reserved_vmem_bytes(cfg, params)
     room = pt.HBM_BUDGET_BYTES - reserved
     ladder = spec.bucket_ladder(cfg, params, 1024)
     assert ladder == [8, 16, 32, 64, 128, 256, 512, 1024]
     assert all(b * per <= room for b in ladder)
-    # a budget a third the size: the top rung is the largest that fits,
-    # and a request above it is chunked through it
+    # a budget of 500 jets: the top rung is the largest that fits, and a
+    # request above it is chunked through it
     small = paths.PathSpec(**{**spec.__dict__, "name": "_t",
-                              "memory_budget_bytes": pt.HBM_BUDGET_BYTES // 3})
+                              "memory_budget_bytes": 500 * per})
     top = small.bucket_ladder(cfg, params, 1024)[-1]
     assert top * per <= small.memory_budget_bytes - reserved
     assert (top + 8) * per > small.memory_budget_bytes - reserved

@@ -76,7 +76,7 @@ def test_tag_filters():
         "int8_fused_full", "int8_jedi_linear_full"]
     assert set(paths.available(pallas=True)) == {
         "fused", "fused_full", "int8_fused_full",
-        "jedi_linear_full", "int8_jedi_linear_full"}
+        "jedi_linear_full", "int8_jedi_linear_full", "part"}
     assert set(paths.available(fused_level="full")) == {
         "fused_full", "int8_fused_full",
         "jedi_linear_full", "int8_jedi_linear_full"}

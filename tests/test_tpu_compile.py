@@ -108,19 +108,24 @@ def test_step_and_kernel_names_for_v5e(one_chip):
                          for c in calls), calls
 
 
-def test_part_step_names_scopes_and_memory_for_v5e(one_chip):
+@pytest.mark.parametrize("path,bucket,kernels,model_bytes", [
+    ("part", 1024, ["part_pair_embed"], "hbm_bytes_per_sample"),
+    ("part_xla", 64, [], "hbm_bytes_per_sample_xla"),
+])
+def test_part_step_names_scopes_and_memory_for_v5e(one_chip, path, bucket,
+                                                   kernels, model_bytes):
     """ParT's served step compiles for the chip as ``jit_trigger_step``
-    at the published widths, its pair embedding and two attention
-    stacks carry their named scopes in the compiled program's op
-    metadata, and the compiler's temporaries stay within the path's
-    HBM model (``particle_transformer.hbm_bytes_per_sample``)."""
+    at the published widths; on ``part`` its one Mosaic call is the pair
+    embedding's ``part_pair_embed`` kernel, on ``part_xla`` it has none;
+    the pair embedding and two attention stacks carry their named scopes
+    in the compiled program's op metadata, and the compiler's
+    temporaries stay within the path's HBM model."""
     from repro.core import particle_transformer as pt
     from repro.serving.engine import TriggerWorkload
 
     cfg = get_arch("part-jetclass").model
     work = TriggerWorkload(pt.init_params(jax.random.PRNGKey(0), cfg), cfg,
-                           forward="part", interpret=False, mesh=None)
-    bucket = 64
+                           forward=path, interpret=False, mesh=None)
     assert bucket in work.bucket_ladder(1024)
     x = jax.ShapeDtypeStruct((bucket, cfg.n_objects, cfg.n_features),
                              jnp.float32, sharding=one_chip)
@@ -128,6 +133,10 @@ def test_part_step_names_scopes_and_memory_for_v5e(one_chip):
     compiled = step.func.lower(*step.args, x).compile()
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_trigger_step"), hlo[:80]
+    calls = [line.split("=", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in hlo.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls == kernels, calls
     for scope in ("part.pair_embed", "part.particle_blocks",
                   "part.class_blocks"):
         assert f"/{scope}/" in hlo, scope
@@ -135,5 +144,5 @@ def test_part_step_names_scopes_and_memory_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 4 * pt.weight_count(cfg)
     temp = mem.temp_size_in_bytes
-    assert 0.5 * bucket * pt.hbm_bytes_per_sample(cfg) < temp \
-        <= bucket * pt.hbm_bytes_per_sample(cfg), temp
+    per_jet = getattr(pt, model_bytes)(cfg)
+    assert 0.5 * bucket * per_jet < temp <= bucket * per_jet, temp

@@ -195,8 +195,9 @@ def test_compile_cache_dir_is_fixed_per_checkout(monkeypatch, tmp_path):
 
 def test_cli_serves_part_jetclass(capsys):
     """ParT at its published widths through the same CLI and engine: a
-    short stream of 8-jet ticks on the ``part`` path, with the pairs
-    the step evaluates counted (128 x 128 per jet)."""
+    short stream of 8-jet ticks on the ``part`` path (its pair embedding
+    the ``part_pair_embed`` kernel, interpreted here), with the pairs the
+    step evaluates counted (128 x 128 per jet)."""
     rc = trigger_serve.main(["--arch", "part-jetclass", "--forward", "part",
                              "--batch", "8", "--batches", "3",
                              "--warmup", "1", "--health"])
@@ -204,7 +205,7 @@ def test_cli_serves_part_jetclass(capsys):
     assert rc == 0, out
     assert "[trigger_serve] arch=part-jetclass" in out
     assert "forward=part n_objects=128" in out
-    assert "path=part (chain part)" in out
+    assert "path=part (chain part>part_xla)" in out
     assert f"pairs_kernel={2 * 8 * 128 * 128}" in out
     assert "level=none" in out
 
@@ -215,7 +216,9 @@ def test_cli_list_paths_resolves_the_part_policy(capsys):
     out = capsys.readouterr().out
     policy = out.split("bucket policy", 1)[1]
     assert "part paths" in policy
-    row = next(ln for ln in policy.splitlines() if ln.startswith("part "))
-    assert row.split()[1] == "8912896"          # HBM bytes per jet
-    assert row.split()[-1] == "8,16,32,64,128,256,512,1024"
+    for path, per_jet in (("part", "1376256"), ("part_xla", "8912896")):
+        row = next(ln for ln in policy.splitlines()
+                   if ln.startswith(path + " "))
+        assert row.split()[1] == per_jet        # HBM bytes per jet
+        assert row.split()[-1] == "8,16,32,64,128,256,512,1024"
     assert "fused_full" not in policy
