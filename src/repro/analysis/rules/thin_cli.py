@@ -6,8 +6,8 @@ docstring, imports, simple constant assignments, a ``main`` function,
 and the ``if __name__ == "__main__"`` block; ``main`` itself may only
 build an argparse parser and delegate into ``repro.serving`` — no
 loops, branches, nested defs, or numerics imports.  Logic that needs
-any of those belongs behind the serving package where the event loop,
-the benchmarks and the tests can reuse it.
+any of those belongs behind the serving package where the event loop
+and the tests can reuse it.
 """
 
 from __future__ import annotations

@@ -84,8 +84,7 @@ def dense_relation_matrices(n_obj: int) -> tuple[np.ndarray, np.ndarray]:
 def mmm_op_counts(n_obj: int, n_feat: int, d_e: int) -> dict:
     """Multiply/add/iteration counts for MMM1/2/3, baseline vs strength-reduced.
 
-    Reproduces Fig. 8 of the paper analytically (benchmarked in
-    ``benchmarks/bench_ops_reduction.py``):
+    Reproduces Fig. 8 of the paper analytically:
 
     * baseline MMM1 (I @ Rr): P x N_o x N_E mults, P x (N_o-1) x N_E adds
     * baseline MMM3 (E @ Rr^T): D_e x N_E x N_o mults, D_e x (N_E-1) x N_o adds

@@ -23,8 +23,7 @@ deeper serving ladder than the fp32 twin earns.
 This module is also the registry's proof of extension: the path is
 registered ONLY here via :func:`~repro.core.paths.register_path`, yet
 the serving engine, deadline batcher, ``trigger_serve --forward``
-choices, ``benchmarks/run.py --paths all`` and the CI regression gate
-all pick it up with zero edits — everything they need (params
+choices and ``chip_smoke.py`` all pick it up with zero edits — everything they need (params
 quantizer, reference fn, tolerance, roofline weight bytes) rides on the
 :class:`~repro.core.paths.PathSpec`.
 
@@ -122,7 +121,7 @@ def _int8_residency(cfg, qparams, batch, **kw):
     # The kernel loads int8 into VMEM and dequantizes on-chip (scale
     # folded after the fp32 accumulate), so the roofline honestly bills
     # 1 byte/weight of HBM traffic — this one field flips the model for
-    # every consumer (engine roofline, codesign, benchmarks, CI gate).
+    # every consumer (engine roofline, codesign, --list-paths).
     weight_bytes=1,
     # Degradation ladder (serving/resilient.py): a failing int8 kernel
     # demotes to the fp32 fused kernel, which itself bottoms out in the

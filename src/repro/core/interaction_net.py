@@ -284,7 +284,7 @@ def forward_sr_split(params, cfg: JediNetConfig, x, *, grid: bool = True):
 
 # ---------------------------------------------------------------------------
 # Path registration: one PathSpec per forward path (see core/paths.py).
-# Every consumer (serving engine, batcher, CLI, benchmarks, CI gate,
+# Every consumer (serving engine, batcher, CLI, chip benchmark,
 # numerics tests) discovers these through the registry.
 # ---------------------------------------------------------------------------
 

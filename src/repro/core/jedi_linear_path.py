@@ -28,9 +28,8 @@ All three declare ``complexity="O(N)"`` and plug
 :func:`~repro.core.codesign.jedi_linear_flops` into the per-path FLOPs
 hook, so rooflines and codesign bill O(N_o) compute — at N_o=128 the
 edge-grid model would overcharge them ~40x.  Like ``int8_path``, this
-module is pure registration: the engine, ResilientEngine ladder, CLIs,
-benchmarks and CI gate pick the paths up by introspection with zero
-consumer edits.
+module is pure registration: the engine, ResilientEngine ladder and
+CLIs pick the paths up by introspection with zero consumer edits.
 """
 
 from __future__ import annotations

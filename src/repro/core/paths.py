@@ -8,7 +8,7 @@ a :class:`PathSpec` declaratively bundles everything a forward path
 roofline should model it at, supported compute dtypes, an optional
 params-transform hook (e.g. quantization), the VMEM working-set model
 the bucket ladder derives from, and a roofline hook — so the serving
-engine, batcher, CLI, benchmarks and CI gate all introspect ONE object
+engine, batcher, CLI and the chip benchmark all introspect ONE object
 instead of agreeing by convention across five files.
 
 Registering a path makes it appear everywhere with zero consumer
@@ -153,7 +153,7 @@ class PathSpec:
         :func:`repro.kernels.autotune.bucket_ladder` under the path's
         OWN per-sample working set and weight-residency reservation —
         the per-path policy every consumer (engine, CLI ``--list-paths``,
-        benchmarks) resolves through one call.
+        the chip benchmark) resolves through one call.
 
         A path that sets ``memory_budget_bytes`` (an XLA path whose
         activations live in HBM) sizes its rungs against that budget
@@ -218,7 +218,7 @@ _REGISTRY: dict[str, PathSpec] = {}
 
 # Modules that register built-in paths at import.  Imported lazily on
 # first registry access, so a path lives entirely in its own module and
-# still shows up in every consumer (engine, CLI, benchmarks, CI gate).
+# still shows up in every consumer (engine, CLI, the chip benchmark).
 _BUILTIN_MODULES = (
     "repro.core.interaction_net",
     "repro.core.int8_path",

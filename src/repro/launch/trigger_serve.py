@@ -13,32 +13,25 @@ throughput at bounded tail latency* rather than single-jet latency.
 ALL the behavior lives in :mod:`repro.serving.trigger` — this module is
 the thin shell (argparse + one call), and ``tests/test_thin_cli.py``
 keeps it that way with an AST guard: no batching, engine or scheduling
-logic may creep back in here.  ``make_stream`` and ``serve_stream`` are
-re-exported for drivers and tests that historically imported them from
-this module.
+logic may creep back in here.
 
-Serving goes through the fault-tolerant
-:class:`~repro.serving.resilient.ResilientEngine` — the degradation
-ladder, deadline shedding and watchdog are always armed.  ``--health``
-prints the health state machine's report after the run; ``--drill
-SEAM[:TIMES[:DELAY_S]]`` arms the fault-injection harness
-(:mod:`repro.serving.faults`) and serves through the guarded
-per-request path (see EXPERIMENTS.md §Fault drills); ``--list-paths``
-prints the forward-path registry with each path's fallback chain and
-bucket policy.  The exit code is 1 when the stream was not served by
-the requested path (outside ``--drill``).
+Every tick is submitted to a :class:`~repro.serving.loop.ServingLoop`
+over the fault-tolerant :class:`~repro.serving.resilient.ResilientEngine`
+— the path the benchmark measures; the degradation ladder, deadline
+shedding and watchdog are always armed.  ``--health`` prints the health
+state machine's report after the run; ``--drill SEAM[:TIMES[:DELAY_S]]``
+arms the fault-injection harness (:mod:`repro.serving.faults`) on the
+same path (see EXPERIMENTS.md §Fault drills); ``--list-paths`` prints
+the forward-path registry with each path's fallback chain and bucket
+policy.  The exit code is 1 when any bucket ended off the requested
+path (outside ``--drill``).
 """
 
 from __future__ import annotations
 
 import argparse
 
-from repro.serving import serve_stream  # noqa: F401  (re-export: tests/drivers)
-from repro.serving.trigger import (  # noqa: F401  (make_stream re-exported)
-    build_trigger_cli,
-    make_stream,
-    run_trigger_cli,
-)
+from repro.serving.trigger import build_trigger_cli, run_trigger_cli
 
 
 def main(argv=None):

@@ -26,7 +26,6 @@ from repro.serving.core import (
     PendingResult,
     WatchdogTimeout,
     Workload,
-    serve_stream,
 )
 from repro.serving.engine import ServingEngine, TriggerWorkload
 from repro.serving.faults import (
@@ -79,5 +78,4 @@ __all__ = [
     "Workload",
     "kgps",
     "percentile",
-    "serve_stream",
 ]

@@ -12,7 +12,8 @@ bucket ladder is — is declared by a :class:`Workload`.
 ``ExecutionCore`` owns, for any workload:
 
 * **warm compile cache** — callables cached per workload cache key
-  (built on miss, fault-injectable at the ``compile`` seam);
+  (built on miss, a ladder rung then run once on its placeholder;
+  fault-injectable at the ``compile`` seam);
 * **pad-to-bucket dispatch** — requests padded up the workload's
   ladder so arbitrary request counts reuse a handful of compilations;
 * **async in-flight window** — :meth:`infer` with ``sync=False``
@@ -50,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.metrics import ServingMetrics, kgps
+from repro.serving.metrics import ServingMetrics
 
 # In-flight dispatch depth for chunked infer(): enough to hide pad/H2D
 # behind compute, small enough that a huge request can't pin unbounded
@@ -149,56 +150,6 @@ class Workload:
         fault then does not fire (see ``FaultInjector.corrupt_build``).
         """
         return None
-
-
-def serve_stream(fwd, stream, *, warmup: int = 2, metrics=None, bucket=None):
-    """Double-buffered device-feed loop; returns per-batch latencies.
-
-    ``fwd`` must be an async-dispatch callable (jitted) taking a host or
-    device array; latencies are seconds from host handoff to
-    logits-ready.  Batch k+1's ``device_put`` is issued while batch k is
-    still computing, so H2D transfer hides behind compute.  The first
-    ``warmup`` batches (compile + cache warm) are excluded from stats;
-    a stream no longer than ``warmup`` yields empty stats, not a crash.
-
-    When ``metrics`` is given every post-warmup batch is recorded there
-    (``bucket`` labels the records; defaults to the batch row count).
-    """
-    latencies = []
-    events = 0
-    it = iter(stream)
-
-    # prime the pipeline: first transfer issued before the loop body
-    try:
-        nxt = jax.device_put(next(it))
-    except StopIteration:
-        return latencies, events, 0.0
-
-    # wall time starts at the last warmup batch; with no warmup it starts
-    # here, so KGPS is well-defined for any stream length
-    t_start = time.perf_counter() if warmup == 0 else None
-    k = 0
-    while nxt is not None:
-        cur = nxt
-        t0 = time.perf_counter()
-        out = fwd(cur)                      # async dispatch
-        try:
-            nxt = jax.device_put(next(it))  # overlap next H2D with compute
-        except StopIteration:
-            nxt = None
-        jax.block_until_ready(out)
-        t1 = time.perf_counter()
-        k += 1
-        if k <= warmup:                     # exclude compile from stats
-            t_start = time.perf_counter()
-            continue
-        latencies.append(t1 - t0)
-        events += cur.shape[0]
-        if metrics is not None:
-            metrics.record_batch(t1 - t0, cur.shape[0],
-                                 bucket or cur.shape[0])
-    wall = (time.perf_counter() - t_start) if t_start else 0.0
-    return latencies, events, wall
 
 
 class PendingResult:
@@ -349,6 +300,13 @@ class ExecutionCore:
                 fn = self.injector.corrupt_build(self.workload, bucket)
             if fn is None:
                 fn = self.workload.build(bucket)
+            if bucket in self.bucket_sizes:
+                # a new executable's first run carries one-time runtime
+                # set-up (20-30 ms on a CPU host): pay it here, on the
+                # rung's placeholder, so that a realization watchdog
+                # times the request and never reads set-up as a wedge
+                jax.block_until_ready(
+                    fn(jnp.asarray(self.workload.placeholder(bucket))))
             if self.injector is not None:
                 # stale_cache seam: the entry replays the previous
                 # dispatch's output — real logits, wrong events
@@ -427,11 +385,11 @@ class ExecutionCore:
         self.metrics.incr("pairs_kernel", rows[2] * times)
 
     def warm(self, buckets=None) -> None:
-        """Pre-compile (and pre-run once) the given buckets — compile cost
-        paid before traffic arrives, not on the first unlucky request."""
+        """Pre-compile (and so pre-run once) the given buckets — compile
+        cost paid before traffic arrives, not on the first unlucky
+        request."""
         for b in buckets if buckets is not None else self.bucket_sizes:
-            jax.block_until_ready(
-                self.compiled_for(b)(jnp.asarray(self.workload.placeholder(b))))
+            self.compiled_for(b)
 
     # -- inference ----------------------------------------------------------
 
@@ -514,33 +472,3 @@ class ExecutionCore:
             pending = PendingPlan(self.infer(plan.x, sync=False),
                                   plan.requests)
         return pending.result() if sync else pending
-
-    def run_stream(self, stream, *, warmup: int = 2) -> dict:
-        """Pump a fixed-size batch stream through the double-buffered feed
-        loop (the trigger CLI's hot path).  All batches must share one
-        size; each is padded to its ladder bucket before dispatch."""
-        stream = list(stream)
-        if not stream:
-            return {"latencies": [], "events": 0, "wall_s": 0.0,
-                    "bucket": None, "kgps": float("nan")}
-        sizes = {b.shape[0] for b in stream}
-        if len(sizes) != 1:
-            raise ValueError(f"stream batches differ in size: {sorted(sizes)}")
-        n_valid = sizes.pop()
-        if n_valid > self.bucket_sizes[-1]:
-            raise ValueError(
-                f"stream batch size {n_valid} exceeds the top bucket "
-                f"{self.bucket_sizes[-1]}; build the engine with "
-                f"max_batch >= {n_valid} or chunk through infer()")
-        bucket = self.bucket_for(n_valid)
-        fwd = self.compiled_for(bucket)
-        padded = [self._pad(np.asarray(b), bucket) for b in stream]
-        lat, _, wall = serve_stream(fwd, padded, warmup=warmup)
-        # KGPS counts VALID events only — padding rows are not throughput.
-        events = n_valid * len(lat)
-        for t in lat:
-            self.metrics.record_batch(t, n_valid, bucket)
-        self.metrics.record_wall(wall, events)
-        self._count_rows(n_valid, bucket, len(lat))
-        return {"latencies": lat, "events": events, "wall_s": wall,
-                "bucket": bucket, "kgps": kgps(events, wall)}

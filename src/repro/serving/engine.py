@@ -28,9 +28,8 @@ only what is trigger-specific:
   knowledge of why.
 
 :class:`TriggerWorkload` is the :class:`~repro.serving.core.Workload`
-declaration; :class:`ServingEngine` composes it with the core and keeps
-the historical engine API (``infer`` / ``run_plan`` / ``run_stream`` /
-``warm`` / ``roofline``).
+declaration; :class:`ServingEngine` composes it with the core and adds
+the trigger workload's surface (``spec``, ``cfg``, ``roofline``, ...).
 """
 
 from __future__ import annotations
@@ -46,15 +45,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import paths as forward_paths
 from repro.launch.mesh import make_host_mesh
 from repro.parallel.sharding import shard_map_unchecked
-from repro.serving.core import (  # noqa: F401  (re-exported: historical home)
-    MAX_INFLIGHT_CHUNKS,
-    ExecutionCore,
-    PendingPlan,
-    PendingResult,
-    WatchdogTimeout,
-    Workload,
-    serve_stream,
-)
+from repro.serving.core import ExecutionCore, Workload
 from repro.serving import faults
 from repro.serving.metrics import ServingMetrics
 

@@ -2,11 +2,9 @@
 
 LL-GNN's whole point is sustained *online* event selection — the L1
 trigger drains a continuous stream of events under a hard latency
-budget, it does not score pre-cut offline batches.  Until this module
-the engine only ever saw offline streams (``run_stream``) or whole
-batches (``infer``); this is the missing front-end: a single-threaded
-event loop that takes individual requests as they arrive and pushes
-them through
+budget, it does not score pre-cut offline batches.  This is the
+front-end: a single-threaded event loop that takes individual requests
+as they arrive and pushes them through
 
     :class:`~repro.serving.batcher.DeadlineBatcher`
         -> ``engine.run_plan(plan, sync=False)``

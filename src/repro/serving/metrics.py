@@ -1,16 +1,16 @@
 """Rolling serving metrics: p50/p99 batch latency + sustained KGPS.
 
-One accounting surface shared by the engine, the trigger CLI and
-``benchmarks/bench_serving.py`` so every consumer reports the same
-numbers the same way:
+One accounting surface shared by the engine, the serving loop and the
+trigger CLI, so every consumer reports the same numbers the same way:
 
 * latencies are *per dispatched batch*, measured host-handoff ->
-  logits-ready (what the double-buffered feed loop observes);
+  logits realized on the host;
 * events are the VALID (un-padded) events in the batch — padding rows
   added to reach a compile bucket never inflate throughput;
 * KGPS (thousand graphs = events per second) is events / wall over the
-  post-warmup stream, not the sum of latencies — with double buffering
-  the pipeline sustains more than 1/latency batches per second.
+  union of dispatch windows, not the sum of latencies — with several
+  batches in flight the pipeline sustains more than 1/latency batches
+  per second.
 * fault-tolerance events (shed requests, path demotions/re-promotions,
   watchdog timeouts, non-finite batches, ...) land in monotonic named
   COUNTERS (:meth:`ServingMetrics.incr`) — the health state machine
@@ -129,7 +129,7 @@ class ServingMetrics:
     def __init__(self, window: int = 4096, *, clock=time.perf_counter_ns):
         self._records: collections.deque[BatchRecord] = collections.deque(
             maxlen=window)
-        self._wall_s = 0.0       # accumulated post-warmup stream wall time
+        self._wall_s = 0.0       # accumulated busy wall time (union of windows)
         self._wall_events = 0    # valid events covered by _wall_s
         self._counters: collections.Counter[str] = collections.Counter()
         self._gauges: dict[str, float] = {}

@@ -57,7 +57,8 @@ import time
 import numpy as np
 
 from repro.core import paths as forward_paths
-from repro.serving.engine import ServingEngine, WatchdogTimeout
+from repro.serving.core import WatchdogTimeout
+from repro.serving.engine import ServingEngine
 from repro.serving.faults import InjectedFault
 from repro.serving.metrics import ServingMetrics
 from repro.serving.sentinel import Sentinel, SentinelConfig
@@ -234,7 +235,7 @@ class ResilientEngine:
     @metrics.setter
     def metrics(self, m: ServingMetrics) -> None:
         # every rung records into ONE shared metrics object; swapping it
-        # (benchmarks reset the window per bucket) must re-point them all
+        # (the CLI resets the window after warm-up) must re-point them all
         self._metrics = m
         for eng in self._engines.values():
             eng.metrics = m
@@ -429,21 +430,15 @@ class ResilientEngine:
 
     def _rung_served(self, st: _BucketState, level: int) -> None:
         st.down = False
-        if level < st.level:         # successful probe: re-promote
+        # a successful probe re-promotes; a quarantined bucket waits for
+        # clean canaries, whatever a plan dispatched before the trip did
+        if level < st.level and not st.quarantined:
             st.level = level
             st.backoff_s = self.probe_initial_s
             st.next_probe = None
             self.metrics.incr("promotions")
         if level > self._base_level:
             self.metrics.incr("fallback_batches")
-
-    def _serve_once(self, level: int, x, *, record: bool) -> np.ndarray:
-        out = self._engine_for(level).infer(
-            x, record=record, timeout_s=self.watchdog_s)
-        if not np.isfinite(out).all():
-            raise NonFiniteOutput(
-                f"rung {self.chain[level]!r} returned non-finite logits")
-        return out
 
     def _last_resort(self, n: int) -> np.ndarray:
         """Every rung failed: the loop still must not raise.  Return
@@ -454,33 +449,32 @@ class ResilientEngine:
         n_targets = getattr(self.cfg, "n_targets", 1)
         return np.full((n, n_targets), np.nan, np.float32)
 
-    def _serve_ladder(self, x, *, record: bool = True,
-                      start: int | None = None) -> np.ndarray:
-        """Serve ``x`` trying rungs from ``start`` (default: the probe/
-        active decision) downward.  Never raises."""
-        x = np.asarray(x)
+    def _dispatch(self, x, start: int | None, record: bool) -> ResilientPending:
+        """The ladder walk: dispatch ``x`` on the first rung from
+        ``start`` (``None``: the probe/active decision) down that takes
+        it.  A dispatch-time fault (compile, dispatch exception) demotes
+        and tries the next rung; realization-time faults (stuck, NaN)
+        surface in :meth:`_realize`.  When every rung fails, the handle
+        already holds the NaN last resort.  Never raises."""
         bucket = self.bucket_for(min(x.shape[0], self.bucket_sizes[-1]))
         st = self._bucket_state(bucket)
-        now = self._clock()
-        lvl = self._start_level(st, now)[0] if start is None else start
+        lvl = self._start_level(st, self._clock())[0] if start is None else start
         while lvl < len(self.chain):
             if lvl in self._construct_failed:
                 lvl += 1
                 continue
             try:
-                out = self._serve_once(lvl, x, record=record)
+                pending = self._engine_for(lvl).infer(
+                    x, record=record, sync=False)
             except Exception as e:   # noqa: BLE001 — ladder catches all
                 self._rung_failed(st, lvl, self._clock(), e)
                 lvl += 1
                 continue
-            self._rung_served(st, lvl)
-            if record and self.sentinel is not None:
-                # canaries ride the RUNG engines directly, so the
-                # sentinel never re-enters this ladder
-                self.sentinel.observe(x, out, bucket, lvl)
-            return out
+            return ResilientPending(self, x, bucket, lvl, pending, record)
         st.down = True
-        return self._last_resort(x.shape[0])
+        rp = ResilientPending(self, x, bucket, lvl, None, record)
+        rp._out, rp._done = self._last_resort(x.shape[0]), True
+        return rp
 
     # -- serving API ---------------------------------------------------------
 
@@ -497,9 +491,8 @@ class ResilientEngine:
         compile-time demotion) paid before traffic arrives."""
         c = self.cfg
         for b in buckets if buckets is not None else self.bucket_sizes:
-            self._serve_ladder(
-                np.zeros((b, c.n_objects, c.n_features), np.float32),
-                record=False)
+            self._dispatch(np.zeros((b, c.n_objects, c.n_features),
+                                    np.float32), None, False).result()
 
     def infer(self, x, *, deadline: float | None = None, record: bool = True,
               sync: bool = True):
@@ -517,7 +510,7 @@ class ResilientEngine:
             self._shed(x.shape[0])
             return None
         if sync:
-            return self._serve_ladder(x, record=record)
+            return self._dispatch(x, None, record).result()
 
         # async: drain the queue head until a slot frees up
         while len(self._inflight) >= self.max_inflight:
@@ -525,33 +518,10 @@ class ResilientEngine:
             if deadline is not None and self._clock() >= deadline:
                 self._shed(x.shape[0])   # expired while backpressured
                 return None
-        bucket = self.bucket_for(min(x.shape[0], self.bucket_sizes[-1]))
-        st = self._bucket_state(bucket)
-        lvl = self._start_level(st, self._clock())[0]
-        pending = None
-        while lvl < len(self.chain):
-            if lvl in self._construct_failed:
-                lvl += 1
-                continue
-            try:
-                # dispatch-time faults (compile, dispatch exception)
-                # surface here synchronously; realization-time faults
-                # (stuck, NaN) surface in ResilientPending.result()
-                pending = self._engine_for(lvl).infer(
-                    x, record=record, sync=False)
-                break
-            except Exception as e:   # noqa: BLE001 — ladder catches all
-                self._rung_failed(st, lvl, self._clock(), e)
-                lvl += 1
-        if pending is None:
-            st.down = True
-            rp = ResilientPending(self, x, bucket, len(self.chain), None,
-                                  record)
-            rp._out, rp._done = self._last_resort(x.shape[0]), True
-            return rp
-        rp = ResilientPending(self, x, bucket, lvl, pending, record)
-        self._inflight.append(rp)
-        self._gauge_inflight()
+        rp = self._dispatch(x, None, record)
+        if not rp._done:             # the last resort holds no dispatch
+            self._inflight.append(rp)
+            self._gauge_inflight()
         return rp
 
     def _realize(self, rp: ResilientPending, pending, x, bucket: int,
@@ -566,7 +536,7 @@ class ResilientEngine:
                     "logits")
         except Exception as e:       # noqa: BLE001 — ladder catches all
             self._rung_failed(st, level, self._clock(), e)
-            out = self._serve_ladder(x, record=record, start=level + 1)
+            out = self._dispatch(x, level + 1, record).result()
         else:
             self._rung_served(st, level)
             if record and self.sentinel is not None:
@@ -603,39 +573,3 @@ class ResilientEngine:
             rp = ResilientPlan(results, tuple(keep),
                                self.infer(x, sync=False))
         return rp.result() if sync else rp
-
-    def run_stream(self, stream, *, warmup: int = 2) -> dict:
-        """The double-buffered fixed-size stream loop, ladder-protected:
-        a rung that fails to compile (or raises mid-stream) demotes and
-        the WHOLE stream re-runs on the fallback — the hot path itself
-        stays the sub-engine's zero-overhead feed loop."""
-        stream = list(stream)
-        if not stream:
-            return self._engines[self._base_level].run_stream(stream,
-                                                              warmup=warmup)
-        bucket = self.bucket_for(stream[0].shape[0])
-        st = self._bucket_state(bucket)
-        lvl = self._start_level(st, self._clock())[0]
-        last_err: Exception | None = None
-        while lvl < len(self.chain):
-            if lvl in self._construct_failed:
-                lvl += 1
-                continue
-            try:
-                res = self._engine_for(lvl).run_stream(stream, warmup=warmup)
-            except Exception as e:   # noqa: BLE001 — ladder catches all
-                self._rung_failed(st, lvl, self._clock(), e)
-                last_err = e
-                lvl += 1
-                continue
-            self._rung_served(st, lvl)
-            if self.sentinel is not None:
-                # post-hoc: the hot stream loop itself stays untouched
-                self.sentinel.verify_stream(stream, bucket, lvl)
-            return res
-        st.down = True
-        self.metrics.incr("failed_requests")
-        self._last_down = self._clock()
-        raise RuntimeError(
-            f"every rung of {self.chain} failed for the stream "
-            f"(bucket {bucket}); last error: {last_err!r}") from last_err

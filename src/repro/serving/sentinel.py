@@ -72,9 +72,9 @@ class SentinelConfig:
     optionally adds a time cadence on the engine's clock.
     ``shadow_rate`` is the duty cycle of terminal-rung shadow
     re-execution (0 disables it); ``shadow_sync`` runs shadow jobs
-    inline on the serve thread — deterministic for tests, and what the
-    post-stream verification uses.  ``promote_after`` is K, the clean
-    canary streak a quarantined rung needs to re-promote.
+    inline on the serve thread — deterministic for tests and the CLI.
+    ``promote_after`` is K, the clean canary streak a quarantined rung
+    needs to re-promote.
     ``tolerance_slack`` scales ``PathSpec.tolerance`` into the canary
     trip threshold (live-vs-ref tolerances are tight; corruption is
     orders of magnitude away).
@@ -218,48 +218,6 @@ class Sentinel:
         m.incr("canary_mismatches")
         eng._quarantine(bucket, lvl)
         return False
-
-    def verify_stream(self, stream, bucket: int, level: int) -> None:
-        """Post-hoc sentinel pass over a served fixed-size stream.
-
-        The double-buffered stream loop is the latency-critical path —
-        it is left untouched.  After the stream returns, a duty-cycled
-        sample of its ticks re-runs through the live rung's compiled
-        callable and shadows against the terminal oracle (synchronously
-        — the stream is already over, there is nothing to overlap), and
-        the bucket canaries on its normal ``canary_every`` cadence with
-        every tick counted as one observed request (a bucket's FIRST
-        stream still always canaries, preserving the one-batch
-        detection guarantee for build-time corruption; later short
-        streams amortize the canary instead of each paying one).  This
-        is the overhead the ≤5% stream budget in EXPERIMENTS.md
-        §Sentinel measures: the elapsed verification wall lands in the
-        ``sentinel_verify_s`` gauge so the benchmark can report it
-        against the stream's wall."""
-        t0 = self._clock()
-        if self.config.shadow_rate > 0 and level < self.terminal_level:
-            stride = max(1, int(round(1.0 / self.config.shadow_rate)))
-            try:
-                eng = self._engine._engine_for(level)
-            except Exception:   # noqa: BLE001 — rung gone: canary only
-                eng = None
-            if eng is not None:
-                for i in range(stride - 1, len(stream), stride):
-                    x = np.asarray(stream[i])
-                    try:
-                        out = eng.infer(x, record=False)
-                    except Exception:   # noqa: BLE001 — loud: ladder's job
-                        continue
-                    self._shadow_job(x, np.asarray(out), bucket, level)
-        cnt = self._since.get(bucket, self.config.canary_every)
-        for _ in range(len(stream)):
-            cnt += 1
-            if cnt >= self.config.canary_every:
-                self.canary(bucket)
-                cnt = 0
-        self._since[bucket] = cnt
-        self._apply_pending()
-        self._engine.metrics.gauge("sentinel_verify_s", self._clock() - t0)
 
     def detail(self) -> dict:
         """Sentinel block for ``health()``."""

@@ -3,18 +3,21 @@
 The launch module is deliberately a THIN shell — argparse plus one call
 in here (``tests/test_thin_cli.py`` enforces that with an AST guard) —
 so every behavior an operator reaches from the command line lives
-inside the serving package where the event loop, the resilience ladder
-and the benchmarks can reuse it:
+inside the serving package:
 
 * :func:`make_stream` — synthetic event stream, fully materialized so
   generation stays off the timed path;
-* :func:`run_trigger_cli` — the whole serve flow: registry listing,
-  fault drills through the guarded per-request path, the double-
-  buffered stream run with roofline context, and the health report.
-  Outside a drill it returns 1, naming the error, when the stream was
-  not served by the requested path: the ladder keeps serving, but an
-  operator who asked for a kernel must not be handed its fallback
-  silently;
+* :func:`serve_ticks` — the driver: each tick submitted to a
+  :class:`~repro.serving.loop.ServingLoop`, the loop pumped between
+  ticks and drained at the end;
+* :func:`run_trigger_cli` — the whole serve flow: registry listing, then
+  :func:`serve_ticks` over the fault-tolerant engine (the path the
+  benchmark measures), with or without armed fault drills, and the
+  report with roofline context and the health view.  Outside a drill
+  it returns 1, naming the error, when any bucket ended off the
+  requested path: the ladder
+  keeps serving, but an operator who asked for a kernel must not be
+  handed its fallback silently;
 * :func:`print_health` — the health state machine's operator view.
 
 Output formats are part of the CLI contract (tests assert on them);
@@ -34,6 +37,8 @@ from repro.core import particle_transformer, paths
 from repro.core.interaction_net import init
 from repro.data.jets import make_jets
 from repro.serving.faults import SILENT_SEAMS, FaultInjector
+from repro.serving.loop import ServingLoop
+from repro.serving.metrics import ServingMetrics, kgps
 from repro.serving.resilient import ResilientEngine
 from repro.serving.sentinel import SentinelConfig
 
@@ -117,7 +122,9 @@ def build_trigger_cli(ap) -> None:
     ap.add_argument("--batch", type=int, default=256,
                     help="events per stream tick (the trigger's time slice)")
     ap.add_argument("--batches", type=int, default=40)
-    ap.add_argument("--warmup", type=int, default=2)
+    ap.add_argument("--warmup", type=int, default=2,
+                    help="ticks served first and left out of the report "
+                         "(a --drill reports every tick)")
     ap.add_argument("--forward", default="fused_full",
                     choices=paths.available())
     ap.add_argument("--compute-dtype", default="float32",
@@ -130,8 +137,8 @@ def build_trigger_cli(ap) -> None:
                     help="print the engine health report after the run")
     ap.add_argument("--drill", action="append", default=None,
                     metavar="SEAM[:TIMES[:MAGNITUDE]]",
-                    help="arm a fault against the primary path (repeatable) "
-                         "and serve through the guarded per-request path. "
+                    help="arm a fault against the primary path (repeatable); "
+                         "the report covers every tick. "
                          "Loud seams: compile, dispatch, input_nan, "
                          "output_nan, latency, stuck (MAGNITUDE = delay "
                          "seconds).  Silent seams: scale_drift, "
@@ -150,14 +157,40 @@ def build_trigger_cli(ap) -> None:
     ap.add_argument("--watchdog-s", type=float, default=30.0,
                     help="stuck-dispatch watchdog budget")
     ap.add_argument("--deadline-ms", type=float, default=None,
-                    help="per-tick serve deadline (drill path); expired "
-                         "ticks are shed, not dispatched")
+                    help="per-tick serve-by budget; the engine sheds an "
+                         "expired tick instead of dispatching it")
     ap.add_argument("--seed", type=int, default=0)
 
 
+def serve_ticks(loop, ticks, deadline_s=None) -> list:
+    """Submit each tick, pump the loop between ticks, drain at the end;
+    returns the ticks' futures, every one of them done."""
+    futures = []
+    for tick in ticks:
+        futures.append(loop.submit(tick, deadline_s=deadline_s))
+        loop.poll()
+    loop.drain()
+    return futures
+
+
+def _off_path(engine, forward: str) -> list[str]:
+    """One error line per bucket that ended off ``forward`` (the rule of
+    the benchmark's health checks: ``level != 0`` or another path)."""
+    h = engine.health()
+    off = []
+    for bucket, st in h["buckets"].items():
+        if st["level"] != 0 or st["path"] != forward:
+            error = next((e for e in (*h["construct_errors"].values(),
+                                      st["last_error"]) if e), "none kept")
+            off.append(f"bucket {bucket} was served by {st['path']!r}, not "
+                       f"the requested path {forward!r}; error: {error}")
+    return off
+
+
 def run_trigger_cli(args) -> int:
-    """Serve a synthetic stream per parsed ``args`` and print the report.
-    Returns the process exit code."""
+    """Serve a synthetic stream per parsed ``args`` through a
+    :class:`~repro.serving.loop.ServingLoop` (the path the benchmark
+    measures) and print the report.  Returns the process exit code."""
     cfg = get_arch(args.arch).model.with_(compute_dtype=args.compute_dtype)
     params = init_weights(cfg, jax.random.PRNGKey(args.seed))
     if args.list_paths:
@@ -193,67 +226,58 @@ def run_trigger_cli(args) -> int:
     rng = np.random.RandomState(args.seed)
     stream = make_stream(rng, args.batches, args.batch, cfg.n_objects,
                          cfg.n_features)
+    deadline_s = None if args.deadline_ms is None else args.deadline_ms * 1e-3
 
-    if args.drill:
-        # guarded per-request path: every batch rides the full ladder —
-        # NaN detection, watchdog, shedding — so injected faults are
-        # absorbed, counted, and visible in --health, never raised.
-        served = shed = 0
-        t0 = time.perf_counter()
-        for tick in stream:
-            deadline = (None if args.deadline_ms is None
-                        else engine._clock() + args.deadline_ms * 1e-3)
-            out = engine.infer(tick, deadline=deadline)
-            if out is None:
-                shed += 1
-            else:
-                served += 1
-        wall = time.perf_counter() - t0
-        snap = engine.metrics.snapshot()
-        print(f"[trigger_serve] DRILL forward={args.forward} "
-              f"faults={','.join(args.drill)} ticks={args.batches} "
-              f"served={served} shed={shed} wall={wall:.3f}s")
-        print(f"  latency    p50 {snap['p50_us']:8.1f} us   "
-              f"p99 {snap['p99_us']:8.1f} us  per batch")
-        print_health(engine)
-        return 0
+    # warm-up ticks compile and are left out of the report; a drill
+    # reports every tick, since a fault may strike a warm-up tick
+    warmup = 0 if args.drill else min(args.warmup, len(stream))
+    serve_ticks(ServingLoop(engine), stream[:warmup])
+    engine.metrics = ServingMetrics()
+    t0 = time.perf_counter()
+    futures = serve_ticks(ServingLoop(engine), stream[warmup:], deadline_s)
+    wall = time.perf_counter() - t0
+    shed = sum(f.shed for f in futures)
+    events = sum(f.n_events for f in futures if not f.shed)
+    snap = engine.metrics.snapshot()
 
-    res = engine.run_stream(stream, warmup=args.warmup)
-    bucket = engine.bucket_for(args.batch)
-    served_by = engine.active_path(bucket)
-    if served_by != args.forward:
-        h = engine.health()
-        errors = [*h["construct_errors"].values(),
-                  h["buckets"][bucket]["last_error"]]
-        print(f"[trigger_serve] FAILED: the stream was served by "
-              f"{served_by!r}, not the requested path {args.forward!r}; "
-              f"error: {next(e for e in errors if e)}")
+    off = [] if args.drill else _off_path(engine, args.forward)
+    for line in off:
+        print(f"[trigger_serve] FAILED: {line}")
+    if off:
         print_health(engine)
         return 1
-
-    if not res["latencies"]:
+    if args.drill:
+        print(f"[trigger_serve] DRILL faults={','.join(args.drill)} "
+              "(every tick reported)")
+    if not futures:
         print("[trigger_serve] stream too short for stats "
-              f"(need > warmup={args.warmup} batches, got {args.batches})")
+              f"(need > warmup={warmup} batches, got {args.batches})")
         if args.health:
             print_health(engine)
         return 0
 
-    snap = engine.metrics.snapshot()
-    model = engine.roofline([bucket])[bucket]
-
+    buckets = snap["buckets"]
     print(f"[trigger_serve] forward={args.forward} "
-          f"n_objects={cfg.n_objects} batch={args.batch} bucket={bucket} "
+          f"n_objects={cfg.n_objects} batch={args.batch} "
+          f"buckets={','.join(map(str, buckets)) or '-'} "
           f"dtype={args.compute_dtype} shards={engine.n_shards}")
-    print(f"  sustained  {snap['kgps']:8.1f} KGPS  "
-          f"({res['events']} events / {res['wall_s']:.3f} s)")
+    print(f"  ticks      {len(futures)} after {warmup} warm-up: "
+          f"served={len(futures) - shed} shed={shed}")
+    print(f"  sustained  {kgps(events, wall):8.1f} KGPS  "
+          f"({events} events / {wall:.3f} s)")
     print(f"  latency    p50 {snap['p50_us']:8.1f} us   "
           f"p99 {snap['p99_us']:8.1f} us  per batch")
     print(f"  per-event  p50 {snap['per_event_p50_us']:8.3f} us")
-    print(f"  roofline   modeled {model['step_us']:.1f} us/step "
-          f"({model['bound']}-bound, {model['hbm_bytes'] / 1e6:.2f} MB HBM, "
-          f"level={model['fused_level']})")
-    print(f"  serving    path={served_by} "
+    if buckets:
+        model = engine.roofline([buckets[-1]])[buckets[-1]]
+        print(f"  roofline   modeled {model['step_us']:.1f} us/step at "
+              f"bucket {buckets[-1]} ({model['bound']}-bound, "
+              f"{model['hbm_bytes'] / 1e6:.2f} MB HBM, "
+              f"level={model['fused_level']})")
+    served_by = sorted({st["path"]
+                        for st in engine.health()["buckets"].values()})
+    print(f"  serving    path={','.join(served_by) or '-'} "
           f"(chain {'>'.join(engine.chain)})")
-    if args.health:
+    if args.health or args.drill:
         print_health(engine)
     return 0

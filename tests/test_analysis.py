@@ -284,31 +284,6 @@ def test_retired_names_rule_honors_analysis_toml_allowlist(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Perf-gate cross-reference: failing baselines name registered paths.
-# ---------------------------------------------------------------------------
-
-def test_regression_gate_extracts_path_names_for_audit_hint():
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    lines = [
-        "BENCH_fused.json: jedi_30p/fused_full: wall_us 10 -> 20 us",
-        "BENCH_serving.json: jedi_30p/int8_fused_full/b64: per_event 1 -> 9",
-        "BENCH_fused.json: missing fresh file",
-    ]
-    assert check_regression._failing_path_names(lines) == {
-        "fused_full", "int8_fused_full"}
-
-
-def test_regression_gate_audit_hint_stays_quiet_on_clean_paths(capsys):
-    """The hint machinery runs the real auditor on the named paths and
-    must not fire (or crash the gate) when their contracts hold."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    check_regression._audit_hint(
-        ["BENCH_fused.json: jedi_30p/fused_full: wall_us 10 -> 20 us"])
-    out = capsys.readouterr().out
-    assert "NOTE: the kernel-contract auditor" not in out
-
-
-# ---------------------------------------------------------------------------
 # Config loader.
 # ---------------------------------------------------------------------------
 

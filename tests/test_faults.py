@@ -9,9 +9,7 @@ guarantee — with faults firing, every non-shed request is served via a
 fallback path with ZERO exceptions escaping the serve loop.
 
 All tests carry the ``chaos`` marker: they run in tier-1 and standalone
-in CI's dedicated chaos job (``pytest -m chaos``), which is kept out of
-the serialized perf-gate job so injected sleeps never pollute the
-benchmark calibration window.
+in CI's dedicated chaos job (``pytest -m chaos``).
 """
 
 import math
@@ -28,9 +26,11 @@ from repro.serving import (
     InjectedFault,
     ResilientEngine,
     ServingEngine,
+    ServingLoop,
     WatchdogTimeout,
 )
 from repro.serving.faults import StuckBuffer
+from repro.serving.trigger import serve_ticks
 
 pytestmark = pytest.mark.chaos
 
@@ -431,20 +431,135 @@ def test_zero_exceptions_under_rotating_faults(jedi8):
     assert inj.fired() >= 4                          # the drills really ran
 
 
-def test_run_stream_demotes_on_compile_failure(jedi8):
+def test_compile_failure_demotes_under_serving_loop(jedi8):
+    """A stream of ticks through the serving loop: the primary's compile
+    failure demotes once and the fallback serves every tick.  Each tick
+    fills the top bucket, so every plan is cut at submit into that one
+    bucket, whatever the host's timing."""
     cfg, params, _, _ = jedi8
     inj = FaultInjector()
     inj.arm("compile", path="fused_full", times=math.inf)
-    eng = _engine(jedi8, inj)
+    eng = _engine(jedi8, inj, probe_initial_s=60.0, max_batch=8)
     stream = [np.random.RandomState(i).normal(0, 1, (8, 8, 16))
               .astype(np.float32) for i in range(4)]
-    res = eng.run_stream(stream, warmup=1)
-    assert len(res["latencies"]) == 3                # stream still served
-    assert eng.active_path(eng.bucket_for(8)) == "sr_split"
+    futures = serve_ticks(ServingLoop(eng), stream)
+    assert all(f.done and not f.shed for f in futures)  # stream still served
+    assert {st["path"] for st in eng.health()["buckets"].values()} == {
+        "sr_split"}
     assert eng.metrics.counter("compile_failures") == 1
 
 
+# -- one ladder walk: sync and async, bare and under the serving loop ------
+
+#: Each loud seam and the counter its failure lands in.
+LOUD_SEAM_COUNTERS = {"compile": "compile_failures",
+                      "dispatch": "dispatch_failures",
+                      "output_nan": "nonfinite_batches",
+                      "stuck": "watchdog_timeouts"}
+
+
+def _arm_loud(seam):
+    inj = FaultInjector()
+    inj.arm(seam, path="fused_full", times=1, delay_s=60.0)
+    return inj
+
+
+@pytest.mark.parametrize("seam", sorted(LOUD_SEAM_COUNTERS))
+def test_sync_and_async_infer_walk_the_same_ladder(jedi8, seam):
+    """A sync ``infer`` is the async walk realized: the same seam leaves
+    the same logits, the same counters and the same active rung."""
+    cfg, params, x, ref = jedi8
+    seen = []
+    for sync in (True, False):
+        eng = _engine(jedi8, _arm_loud(seam), watchdog_s=0.5,
+                      probe_initial_s=60.0)
+        out = eng.infer(x) if sync else eng.infer(x, sync=False).result()
+        counters = {k: v for k, v in eng.metrics.counters.items()
+                    if k == "demotions" or k.endswith("_failures")
+                    or k in LOUD_SEAM_COUNTERS.values()}
+        seen.append((out, counters, eng.active_path(eng.bucket_for(5))))
+    (sync_out, sync_c, sync_path), (async_out, async_c, async_path) = seen
+    np.testing.assert_array_equal(sync_out, async_out)
+    assert np.abs(sync_out - ref).max() < 1e-4
+    assert sync_c == async_c
+    assert sync_c[LOUD_SEAM_COUNTERS[seam]] == 1 and sync_c["demotions"] == 1
+    assert sync_path == async_path == "sr_split"
+
+
+@pytest.mark.parametrize("seam", sorted(LOUD_SEAM_COUNTERS))
+def test_loop_over_resilient_engine_absorbs_loud_seam(jedi8, seam):
+    """The serving loop over the engine, a loud seam striking the
+    primary: every request is answered, finite and right, and the
+    fault is counted."""
+    cfg, params, _, _ = jedi8
+    eng = _engine(jedi8, _arm_loud(seam), watchdog_s=0.5)
+    rng = np.random.RandomState(4)
+    xs = [rng.normal(0, 1, (n, 8, 16)).astype(np.float32)
+          for n in (3, 7, 5, 11, 2, 9)]
+    futures = serve_ticks(ServingLoop(eng), xs)
+    for fut, x in zip(futures, xs):
+        assert fut.done and not fut.shed
+        out = fut.result()
+        assert out.shape == (x.shape[0], cfg.n_targets)
+        assert np.isfinite(out).all()
+        ref = np.asarray(forward_sr(params, cfg, x))
+        assert np.abs(out - ref).max() < 1e-3
+    c = eng.metrics.counters
+    assert c[LOUD_SEAM_COUNTERS[seam]] == 1 and c["demotions"] >= 1
+    assert c["loop_completed"] == len(xs)
+
+
+@pytest.mark.parametrize("sync", [True, False], ids=["sync", "async"])
+def test_fresh_rung_first_run_is_not_read_as_a_wedge(jedi8, monkeypatch,
+                                                     sync):
+    """A new executable's first run carries one-time set-up that can
+    outlast a tight watchdog (on a loaded host it did): the compile
+    cache pays it on the rung's placeholder, so the fallback that
+    serves after a demotion is never taken for a wedged dispatch."""
+    import time
+
+    from repro.serving.engine import TriggerWorkload
+
+    build = TriggerWorkload.build
+
+    def slow_first_run(self, bucket=None):
+        fn, runs = build(self, bucket), []
+
+        def call(x):
+            out = fn(x)
+            runs.append(1)
+            if len(runs) == 1:       # set-up: ready 0.3 s from now
+                return StuckBuffer(out, time.monotonic() + 0.3)
+            return out
+        return call
+
+    monkeypatch.setattr(TriggerWorkload, "build", slow_first_run)
+    cfg, params, x, ref = jedi8
+    inj = FaultInjector()
+    inj.arm("compile", path="fused_full", times=math.inf)
+    eng = _engine(jedi8, inj, watchdog_s=0.05)
+    out = eng.infer(x) if sync else eng.infer(x, sync=False).result()
+    assert np.abs(out - ref).max() < 1e-4
+    assert eng.metrics.counter("watchdog_timeouts") == 0
+    assert eng.health()["state"] == "degraded"       # demoted, not down
+
+
 # -- health + registry contract ------------------------------------------
+
+
+def test_warm_walks_every_bucket_without_recording(jedi8):
+    """``warm`` is the ladder walk with ``record=False``: every bucket
+    compiled and served once on its rung, nothing counted as traffic,
+    nothing left in flight."""
+    eng = _engine(jedi8)
+    eng.warm()
+    h = eng.health()
+    assert sorted(h["buckets"]) == eng.bucket_sizes
+    assert all(st["path"] == "fused_full" and st["level"] == 0
+               for st in h["buckets"].values())
+    assert eng.metrics.batches == 0 and h["inflight"] == 0
+    assert eng.metrics.counter("rows_valid") == 0
+    assert eng.metrics.counter("compiles") == len(eng.bucket_sizes)
 
 
 def test_health_snapshot_shape(jedi8):

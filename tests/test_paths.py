@@ -2,9 +2,8 @@
 (every registered path vs its own declared reference — no hand-listed
 path names), complexity-class metadata + per-path FLOPs hooks, the int8
 quantized path end-to-end, per-bucket engine coverage of every
-fully-fused path, and the CI gate's baseline bootstrap."""
+fully-fused path."""
 
-import json
 import time
 
 import jax
@@ -472,24 +471,16 @@ def test_wall_union_handles_out_of_order_realization(jedi):
 def test_infer_bounds_inflight_chunks(jedi):
     """A request many times the top bucket still completes correctly with
     the throttled dispatch pipeline."""
-    from repro.serving import engine as engine_mod
+    from repro.serving.core import MAX_INFLIGHT_CHUNKS
     cfg, params, _ = jedi
     eng = ServingEngine(params, cfg, forward="sr", max_batch=4)
     top = eng.bucket_sizes[-1]
-    n = top * (engine_mod.MAX_INFLIGHT_CHUNKS + 3) + 1
+    n = top * (MAX_INFLIGHT_CHUNKS + 3) + 1
     x = np.random.RandomState(5).normal(0, 1, (n, 16, 16)).astype(np.float32)
     got = eng.infer(x)
     ref = np.asarray(inet.forward_sr(params, cfg, jnp.asarray(x)))
     assert got.shape == (n, cfg.n_targets)
     assert np.abs(got - ref).max() < 1e-5
-
-
-def test_run_stream_rejects_oversized_batches(jedi):
-    cfg, params, _ = jedi
-    eng = ServingEngine(params, cfg, forward="sr", max_batch=8)
-    big = np.zeros((eng.bucket_sizes[-1] + 1, 16, 16), np.float32)
-    with pytest.raises(ValueError, match="top bucket"):
-        eng.run_stream([big, big, big])
 
 
 def test_run_plan_async_overlaps_flushes(jedi):
@@ -514,177 +505,3 @@ def test_run_plan_async_overlaps_flushes(jedi):
     for rid, x in xs.items():
         ref = np.asarray(inet.forward_sr(params, cfg, jnp.asarray(x)))
         assert np.abs(results[rid] - ref).max() < 1e-5
-
-
-# -- CI gate: baseline bootstrap for newly registered paths --------------
-
-
-def _fused_doc(path_entries, calibration=100.0):
-    return {"schema": 1, "backend": "cpu", "calibration_us": calibration,
-            "configs": {"30p": {"n_objects": 30, "paths": path_entries}}}
-
-
-def test_check_regression_bootstraps_new_path(tmp_path):
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    base = _fused_doc({"sr": {"wall_us": 100.0}}, calibration=100.0)
-    # fresh machine is 2x slower (calibration 200): times halve on merge
-    fresh = _fused_doc({"sr": {"wall_us": 210.0},
-                        "int8_fused_full": {"wall_us": 300.0,
-                                            "modeled_hbm_bytes": 7085.0}},
-                       calibration=200.0)
-    (base_dir / "BENCH_fused.json").write_text(json.dumps(base))
-    (base_dir / "BENCH_serving.json").write_text(json.dumps(
-        {"schema": 1, "backend": "cpu", "configs": {}}))
-    for name, doc in (("BENCH_fused.json", fresh),
-                      ("BENCH_serving.json",
-                       {"schema": 1, "backend": "cpu", "configs": {}})):
-        (fresh_dir / name).write_text(json.dumps(doc))
-
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir),
-                                "--bootstrap"])
-    assert rc == 0
-    merged = json.loads((base_dir / "BENCH_fused.json").read_text())
-    entry = merged["configs"]["30p"]["paths"]["int8_fused_full"]
-    # speed-normalized into baseline-machine units; modeled bytes untouched
-    assert entry["wall_us"] == pytest.approx(150.0)
-    assert entry["modeled_hbm_bytes"] == pytest.approx(7085.0)
-    # the pre-existing entry is NOT rewritten by bootstrap
-    assert merged["configs"]["30p"]["paths"]["sr"]["wall_us"] == 100.0
-
-
-def test_check_regression_bootstrap_seeds_missing_baseline_file(tmp_path):
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    for name in ("BENCH_fused.json", "BENCH_serving.json"):
-        (fresh_dir / name).write_text(json.dumps(_fused_doc({})))
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir),
-                                "--bootstrap"])
-    assert rc == 0
-    for name in ("BENCH_fused.json", "BENCH_serving.json"):
-        assert (base_dir / name).exists()
-
-
-def test_check_regression_still_gates_existing_entries(tmp_path):
-    """Bootstrap only seeds NEW entries — a regression on a gated path
-    still fails even with --bootstrap."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    (base_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"sr": {"wall_us": 100.0}})))
-    (fresh_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"sr": {"wall_us": 500.0}})))
-    for d in (base_dir, fresh_dir):
-        (d / "BENCH_serving.json").write_text(json.dumps(
-            {"schema": 1, "backend": "cpu", "configs": {}}))
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir),
-                                "--bootstrap"])
-    assert rc == 1
-
-
-def test_check_regression_names_unseeded_paths_in_recipe(tmp_path, capsys):
-    """Introducing a path without --bootstrap must not fail the gate,
-    but the printed recipe names the unseeded entry explicitly — it
-    cannot linger as an ignorable info line."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    (base_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"fused_full": {"wall_us": 100.0}})))
-    (fresh_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"fused_full": {"wall_us": 100.0},
-                    "jedi_linear_full": {"wall_us": 40.0}})))
-    for d in (base_dir, fresh_dir):
-        (d / "BENCH_serving.json").write_text(json.dumps(
-            {"schema": 1, "backend": "cpu", "configs": {}}))
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir)])
-    out = capsys.readouterr().out
-    assert rc == 0                          # growth is not a regression
-    assert "30p/jedi_linear_full" in out    # ...but it IS named
-    assert "--bootstrap" in out
-
-
-def test_check_regression_missing_baseline_fails_with_recipe(tmp_path,
-                                                             capsys):
-    """No committed baseline and no --bootstrap: the gate must FAIL (a
-    silently green gate hides regressions forever) and print the exact
-    bootstrap command instead of a raw traceback."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    for name in ("BENCH_fused.json", "BENCH_serving.json"):
-        (fresh_dir / name).write_text(json.dumps(_fused_doc({})))
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "no committed baseline" in out
-    assert "--bootstrap" in out          # the remedy, spelled out
-    assert "Traceback" not in out
-
-
-def test_check_regression_corrupt_baseline_warns_and_fails(tmp_path,
-                                                           capsys):
-    """A truncated/garbage baseline file is a clear verdict with a
-    regeneration recipe, never a JSONDecodeError traceback."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    for name in ("BENCH_fused.json", "BENCH_serving.json"):
-        (fresh_dir / name).write_text(json.dumps(_fused_doc({})))
-        (base_dir / name).write_text("{ not json")
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "not valid JSON" in out and "benchmarks.run" in out
-
-
-def test_check_regression_warns_on_calibration_mismatch(tmp_path, capsys):
-    """Calibration stamps >1.5x apart mean the two payloads were NOT
-    measured in the same quiet window — the gate still runs (the
-    yardstick normalizes), but it must warn LOUDLY with the regenerate
-    recipe rather than quietly leaning on the normalization."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    (base_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"sr": {"wall_us": 100.0}}, calibration=100.0)))
-    (fresh_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"sr": {"wall_us": 250.0}}, calibration=250.0)))
-    for d in (base_dir, fresh_dir):
-        (d / "BENCH_serving.json").write_text(json.dumps(
-            {"schema": 1, "backend": "cpu", "configs": {}}))
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir)])
-    out = capsys.readouterr().out
-    assert rc == 0                    # normalized 250/2.5 = 100: no regress
-    assert "WARN: calibration stamps differ by 2.50x" in out
-    assert "SAME QUIET WINDOW" in out
-
-
-def test_check_regression_quiet_when_calibration_close(tmp_path, capsys):
-    """Stamps within 1.5x: no banner — the warning must stay a signal,
-    not ambient noise on every healthy run."""
-    check_regression = pytest.importorskip("benchmarks.check_regression")
-    fresh_dir, base_dir = tmp_path / "fresh", tmp_path / "base"
-    fresh_dir.mkdir(), base_dir.mkdir()
-    (base_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"sr": {"wall_us": 100.0}}, calibration=100.0)))
-    (fresh_dir / "BENCH_fused.json").write_text(json.dumps(
-        _fused_doc({"sr": {"wall_us": 120.0}}, calibration=120.0)))
-    for d in (base_dir, fresh_dir):
-        (d / "BENCH_serving.json").write_text(json.dumps(
-            {"schema": 1, "backend": "cpu", "configs": {}}))
-    rc = check_regression.main(["--fresh-dir", str(fresh_dir),
-                                "--baseline-dir", str(base_dir)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "SAME QUIET WINDOW" not in out

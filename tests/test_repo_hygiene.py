@@ -44,7 +44,7 @@ def test_no_tracked_bytecode(tracked_files):
 
 def test_gitignore_covers_bytecode_and_bench_scratch():
     patterns = (REPO / ".gitignore").read_text().splitlines()
-    for required in ("__pycache__/", "*.pyc", "bench_out/"):
+    for required in ("__pycache__/", "*.pyc", ".jax_cache/", "chiprun_out/"):
         assert required in patterns, (
             f".gitignore lost the {required!r} rule — bytecode/scratch "
             "would start showing up in git status (and risk being added)")

@@ -24,8 +24,10 @@ from repro.serving import (
     FaultInjector,
     ResilientEngine,
     SentinelConfig,
+    ServingLoop,
     ServingMetrics,
 )
+from repro.serving.trigger import serve_ticks
 
 pytestmark = pytest.mark.sentinel
 
@@ -155,6 +157,31 @@ def test_quarantined_bucket_never_probes_live_traffic(jedi8):
         t[0] += 10.0                                 # way past any backoff
     assert eng.health()["state"] == "quarantined"
     assert "probes" not in eng.metrics.counters
+
+
+def test_sentinel_quarantines_weight_corrupt_under_serving_loop(jedi8):
+    """The CLI's path: ticks through the serving loop, several plans in
+    flight, a persistent weight corruption on the primary.  The first
+    canary quarantines the bucket; plans dispatched before the trip and
+    realized after it do not lift the quarantine, so every later plan is
+    served right by the fallback."""
+    cfg, params, _, _ = jedi8
+    inj = FaultInjector()
+    inj.arm("weight_corrupt", path="fused_full", factor=8.0)
+    eng = _engine(jedi8, inj)
+    rng = np.random.RandomState(5)
+    xs = [rng.normal(0, 1, (16, 8, 16)).astype(np.float32)
+          for _ in range(12)]
+    futures = serve_ticks(ServingLoop(eng), xs)
+    h = eng.health()
+    assert h["state"] == "quarantined"
+    (b,) = h["buckets"].values()
+    assert b["quarantined_path"] == "fused_full" and b["path"] == "sr_split"
+    assert h["counters"]["quarantines"] == 1
+    assert "promotions" not in h["counters"]
+    for fut, x in zip(futures[-6:], xs[-6:]):
+        ref = np.asarray(forward_sr(params, cfg, x))
+        assert np.abs(fut.result() - ref).max() < 1e-3
 
 
 # -- shadow re-execution --------------------------------------------------

@@ -62,6 +62,37 @@ def test_engine_compile_cache_warm(jedi30, engine30):
     assert engine30.cache_size == n_compiled
 
 
+def test_compiled_for_runs_a_new_rung_once_before_caching(jedi30,
+                                                         monkeypatch):
+    """A ladder rung's new executable is run once on its placeholder as
+    it is built (its one-time set-up paid there); a cache hit runs
+    nothing, and each request is one more run."""
+    from repro.serving.engine import TriggerWorkload
+
+    cfg, params = jedi30
+    build, runs = TriggerWorkload.build, []
+
+    def counting(self, bucket=None):
+        fn = build(self, bucket)
+
+        def call(x):
+            runs.append(x.shape[0])
+            return fn(x)
+        return call
+
+    monkeypatch.setattr(TriggerWorkload, "build", counting)
+    eng = ServingEngine(params, cfg, forward="sr", max_batch=16)
+    top = eng.bucket_sizes[-1]
+    eng.compiled_for(top)
+    assert runs == [top]
+    eng.compiled_for(top)
+    assert runs == [top]
+    x = np.zeros((top - 1, 30, 16), np.float32)
+    assert eng.infer(x).shape == (top - 1, cfg.n_targets)
+    assert runs == [top, top]
+    assert eng.metrics.counter("compiles") == 1
+
+
 def test_engine_chunks_oversized_requests(jedi30, engine30):
     cfg, params = jedi30
     top = engine30.bucket_sizes[-1]
@@ -73,18 +104,28 @@ def test_engine_chunks_oversized_requests(jedi30, engine30):
     assert np.abs(got - ref).max() < 1e-5
 
 
-def test_engine_run_stream_pads_and_counts_valid_events(jedi30):
+def test_engine_run_plan_pads_and_counts_valid_events(jedi30):
+    """Plans of 13 events ride the 16 bucket; after two warm-up plans
+    the metrics are reset, and they count the valid events of the
+    other three, not the padded rows."""
     cfg, params = jedi30
     eng = ServingEngine(params, cfg, forward="sr", max_batch=32)
+    bat = DeadlineBatcher(eng.bucket_sizes, clock=lambda: 0.0)
     stream = [np.random.RandomState(i).normal(0, 1, (13, 30, 16))
               .astype(np.float32) for i in range(5)]
-    res = eng.run_stream(stream, warmup=2)
-    assert res["bucket"] == eng.bucket_for(13)
-    assert len(res["latencies"]) == 3
-    assert res["events"] == 3 * 13            # valid events, not padded rows
+    for rid, x in enumerate(stream):
+        if rid == 2:
+            eng.metrics = ServingMetrics()   # the first two are warm-up
+        bat.submit(rid, x)
+        (plan,) = bat.flush()
+        assert plan.bucket == eng.bucket_for(13)
+        assert eng.run_plan(plan)[rid].shape == (13, cfg.n_targets)
     snap = eng.metrics.snapshot()
-    assert snap["events"] == 3 * 13
+    assert snap["events"] == 3 * 13            # valid events, not padded rows
     assert snap["batches"] == 3
+    assert snap["buckets"] == [eng.bucket_for(13)]
+    assert eng.metrics.counter("rows_valid") == 3 * 13
+    assert eng.metrics.counter("rows_bucket") == 3 * eng.bucket_for(13)
 
 
 def test_engine_rejects_unknown_path(jedi30):

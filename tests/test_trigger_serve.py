@@ -1,84 +1,102 @@
-"""launch.trigger_serve: the double-buffered serve_stream loop edge cases
-and the thin-CLI-over-engine entry point."""
+"""launch.trigger_serve: the thin CLI over the serving loop — ticks
+submitted to a ServingLoop over the fault-tolerant engine, warm-up left
+out of the report, every tick answered in order."""
 
 import jax
 import numpy as np
 
+from repro.configs.registry import get_arch
+from repro.core.interaction_net import forward_sr, init
 from repro.launch import trigger_serve
-from repro.launch.trigger_serve import make_stream, serve_stream
-from repro.serving import ServingMetrics
+from repro.serving import ResilientEngine, ServingLoop
+from repro.serving.trigger import make_stream, serve_ticks
 
 
-def _identity_fwd():
-    """A jitted async-dispatch stand-in for a forward path."""
-    return jax.jit(lambda x: x * 2.0)
+def _counter(out, name):
+    """A counter's value in the --health report (0 when not printed)."""
+    line = next(ln for ln in out.splitlines() if "counters:" in ln)
+    for item in line.split()[1:]:
+        key, _, value = item.partition("=")
+        if key == name:
+            return int(value)
+    return 0
 
 
-def _stream(n_batches, batch=4):
-    return [np.full((batch, 3), float(i), np.float32)
-            for i in range(n_batches)]
+def test_cli_stream_excludes_warmup_ticks(capsys):
+    """The warm-up ticks compile and are served, then the metrics are
+    reset: the report and its counters hold the other ticks only."""
+    rc = trigger_serve.main(["--forward", "sr", "--batch", "8",
+                             "--batches", "5", "--warmup", "2", "--health"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert "ticks      3 after 2 warm-up: served=3 shed=0" in out
+    assert "(24 events /" in out
+    assert _counter(out, "loop_requests") == 3
+    assert _counter(out, "rows_valid") == 24
+    assert _counter(out, "compiles") == 0      # warm-up compiled it all
 
 
-def test_serve_stream_warmup_longer_than_stream_is_empty_stats():
-    """warmup >= stream length: every batch is warmup — empty stats, no
-    crash, and the degenerate wall stays 0 (callers print 'too short')."""
-    for n in (0, 1, 2):
-        lat, events, wall = serve_stream(_identity_fwd(), _stream(n),
-                                         warmup=2)
-        assert lat == []
-        assert events == 0
-        if n == 0:
-            assert wall == 0.0
+def test_cli_stream_answers_every_tick_in_order():
+    """The driver drops and reorders nothing: ticks that straddle plan
+    cuts (5 events into buckets of 8) come back whole, each the
+    reference logits of its own tick."""
+    cfg = get_arch("jedinet-30p").model
+    params = init(jax.random.PRNGKey(0), cfg)
+    engine = ResilientEngine(params, cfg, forward="sr", max_batch=8,
+                             mesh=None)
+    ticks = make_stream(np.random.RandomState(1), 7, 5, cfg.n_objects,
+                        cfg.n_features)
+    futures = serve_ticks(ServingLoop(engine), ticks)
+    assert [f.rid for f in futures] == list(range(7))
+    for fut, tick in zip(futures, ticks):
+        assert fut.done and not fut.shed
+        ref = np.asarray(forward_sr(params, cfg, tick))
+        assert np.abs(fut.result() - ref).max() < 1e-5
+    assert engine.metrics.counter("loop_completed") == 7
 
 
-def test_serve_stream_excludes_warmup_from_accounting():
-    fwd = _identity_fwd()
-    lat, events, wall = serve_stream(fwd, _stream(7, batch=5), warmup=2)
-    assert len(lat) == 5                  # 7 batches - 2 warmup
-    assert events == 5 * 5                # KGPS accounting skips warmup rows
-    assert wall > 0
-    assert all(t > 0 for t in lat)
+def test_cli_stream_single_tick_no_warmup(capsys):
+    """A one-tick stream with no warm-up is measured: the tick is served
+    over a positive wall time, so the sustained rate is finite."""
+    rc = trigger_serve.main(["--forward", "sr", "--batch", "8",
+                             "--batches", "1", "--warmup", "0", "--health"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert "ticks      1 after 0 warm-up: served=1 shed=0" in out
+    kgps = float(out.split("sustained", 1)[1].split()[0])
+    wall = float(out.split("(8 events / ", 1)[1].split()[0])
+    assert np.isfinite(kgps) and wall > 0
+    assert _counter(out, "rows_valid") == 8
 
 
-def test_serve_stream_single_batch_stream():
-    """The prefetch loop must handle a 1-batch stream: the primed transfer
-    is the only batch, and with warmup=0 it is measured — including a
-    positive wall time so KGPS stays finite."""
-    fwd = _identity_fwd()
-    lat, events, wall = serve_stream(fwd, _stream(1, batch=3), warmup=0)
-    assert len(lat) == 1
-    assert events == 3
-    assert wall > 0.0
+def test_cli_drill_deadline_sheds_expired_ticks(capsys):
+    """A drill slows every dispatch (latency seam) and so holds the loop
+    while the rest of a tick waits in the batcher past its serve-by
+    budget: the engine sheds it, the ticks dispatched in time are
+    served, and the report counts both."""
+    rc = trigger_serve.main(["--forward", "sr", "--batch", "12",
+                             "--batches", "4", "--deadline-ms", "100",
+                             "--drill", "latency:99:0.5", "--health"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    line = next(ln for ln in out.splitlines() if ln.startswith("  ticks"))
+    served = int(line.split("served=")[1].split()[0])
+    shed = int(line.split("shed=")[1].split()[0])
+    assert served + shed == 4 and shed >= 1 and served >= 1
+    assert _counter(out, "shed_requests") >= 1
+    assert "[health] state=shedding" in out
 
 
-def test_serve_stream_records_into_metrics():
-    m = ServingMetrics()
-    serve_stream(_identity_fwd(), _stream(6, batch=4), warmup=2,
-                 metrics=m, bucket=8)
-    snap = m.snapshot()
-    assert snap["batches"] == 4
-    assert snap["events"] == 16
-    assert snap["buckets"] == [8]
-
-
-def test_serve_stream_computes_through_the_pipeline():
-    """Double buffering must not drop or reorder batches."""
-    fwd = _identity_fwd()
-    stream = _stream(4, batch=2)
-    outs = []
-    orig = jax.device_put
-
-    def capture(x):
-        d = orig(x)
-        outs.append(np.asarray(x)[0, 0])
-        return d
-
-    jax.device_put, saved = capture, jax.device_put
-    try:
-        serve_stream(fwd, stream, warmup=0)
-    finally:
-        jax.device_put = saved
-    assert outs == [0.0, 1.0, 2.0, 3.0]
+def test_cli_serves_through_serving_loop(capsys):
+    """Every tick is one request of the serving loop, and every one of
+    them completes."""
+    rc = trigger_serve.main(["--forward", "sr", "--batch", "8",
+                             "--batches", "6", "--warmup", "0", "--health"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert _counter(out, "loop_requests") == 6
+    assert _counter(out, "loop_completed") == 6
+    assert _counter(out, "loop_plans") >= 1
 
 
 def test_make_stream_shapes():
@@ -95,6 +113,7 @@ def test_cli_main_reports_stats_through_engine(capsys):
     assert "sustained" in out and "KGPS" in out
     assert "p50" in out and "p99" in out
     assert "roofline" in out and "level=none" in out
+    assert "ticks      4 after 1 warm-up" in out
 
 
 def test_cli_main_short_stream_prints_hint(capsys):
@@ -130,10 +149,11 @@ def test_cli_health_flag_reports_state(capsys):
     assert "[health] state=healthy" in out
     assert "chain=sr" in out
     assert "path=sr" in out                  # serving line + bucket detail
-    # the per-plan row counters and the compile-cache misses
-    for counter in ("rows_valid=32", "rows_bucket=32", "rows_kernel=32",
-                    "compiles="):
+    # the per-plan row counters of the four measured ticks; the
+    # compile-cache misses fell in the warm-up, which is left out
+    for counter in ("rows_valid=32", "rows_bucket=32", "rows_kernel=32"):
         assert counter in out, counter
+    assert "compiles=" not in out
 
 
 def test_cli_reports_serving_path_and_chain(capsys):
