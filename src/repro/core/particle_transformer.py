@@ -16,17 +16,16 @@ computed in exact forms free of cancellation (:func:`pair_features`).
 
 The program's form: the pair embedding's BatchNorms are folded into its
 Linears (the particle embedding's input BatchNorm, followed by a
-LayerNorm, stays an affine), the pair embedding is evaluated over all
-N x N ordered
-pairs (twice the 8,128 unordered pairs the model needs: U is symmetric,
-so the second half is redundant work that ``step_mfu`` shows), and the
-three stages carry the named scopes ``part.pair_embed``,
-``part.particle_blocks`` and ``part.class_blocks``.  Two paths serve it
-(registered below): ``part`` runs the pair embedding as one Pallas
-kernel (``kernels/part_pair``, :func:`pair_bias_kernel`) whose 64-wide
-activations never leave VMEM; ``part_xla``, its fallback, is the plain
-XLA program.  The other activations live in HBM, so both ladders are
-sized against an HBM budget (:func:`hbm_bytes_per_sample`,
+LayerNorm, stays an affine), and the three stages carry the named
+scopes ``part.pair_embed``, ``part.particle_blocks`` and
+``part.class_blocks``.  Two paths serve it (registered below): ``part``
+runs the pair embedding as one Pallas kernel (``kernels/part_pair``,
+:func:`pair_bias_kernel`) whose 64-wide activations never leave VMEM,
+over each unordered pair once (N^2/2 packed lanes a jet, the 8,128
+pairs i < j and 64 masked lanes at N = 128), and unfolds the symmetric
+U from them; ``part_xla``, its fallback, is the plain XLA program, over
+all N x N ordered pairs.  The other activations live in HBM, so both
+ladders are sized against an HBM budget (:func:`hbm_bytes_per_sample`,
 :func:`hbm_bytes_per_sample_xla`).
 """
 
@@ -217,24 +216,25 @@ def _fold_pair_layers(pair) -> list[tuple]:
     return out
 
 
-def _wrapped_abs_dphi(phi):
-    """|phi_i - phi_j| wrapped into [0, pi], (B, N, N).  Built from the
+def _wrapped_abs_dphi(phi_i, phi_j):
+    """|phi_i - phi_j| wrapped into [0, pi], (B, Ni, Nj).  Built from the
     exact |difference|, so it is exactly symmetric in i, j."""
-    d = jnp.abs(phi[:, :, None] - phi[:, None, :])
+    d = jnp.abs(phi_i[:, :, None] - phi_j[:, None, :])
     return jnp.abs(jnp.mod(d + jnp.pi, 2 * jnp.pi) - jnp.pi)
 
 
-def pair_features(x):
-    """ln kT, ln z, ln Delta, ln m^2 of every ordered pair, (B, N, N, 4).
+def pair_features(x, y=None):
+    """ln kT, ln z, ln Delta, ln m^2 of every ordered pair of a particle
+    of ``x`` with one of ``y`` (``x`` itself by default), (B, Ni, Nj, 4).
 
     Channels 0, 1, 2 of a particle are ln pT, eta and phi of a massless
     particle.  m^2 = 4 pT_i pT_j (sinh^2(deta/2) + sin^2(dphi/2)) is the
     massless (E_i + E_j)^2 - |p_i + p_j|^2 without its cancellation."""
-    pt = jnp.exp(x[..., 0])
-    eta, phi = x[..., 1], x[..., 2]
-    deta = eta[:, :, None] - eta[:, None, :]
-    dphi = _wrapped_abs_dphi(phi)
-    pt_i, pt_j = pt[:, :, None], pt[:, None, :]
+    y = x if y is None else y
+    deta = x[..., 1][:, :, None] - y[..., 1][:, None, :]
+    dphi = _wrapped_abs_dphi(x[..., 2], y[..., 2])
+    pt_i = jnp.exp(x[..., 0])[:, :, None]
+    pt_j = jnp.exp(y[..., 0])[:, None, :]
     pt_min = jnp.minimum(pt_i, pt_j)
     delta = jnp.sqrt(deta * deta + dphi * dphi)
     m2 = 4.0 * pt_i * pt_j * (jnp.square(jnp.sinh(0.5 * deta))
@@ -270,13 +270,18 @@ def pair_bias(params, cfg: ParticleTransformerConfig, x, mask):
     return jnp.transpose(u, (0, 3, 1, 2)) * pair_keep(mask)[:, None]
 
 
+def _pair_features_channel_major(xi, xj):
+    return jnp.moveaxis(pair_features(xi, xj), -1, 1)
+
+
 def pair_bias_kernel(params, cfg: ParticleTransformerConfig, x, mask, *,
                      interpret: bool = False):
     """:func:`pair_bias` through the ``part_pair_embed`` kernel: the pair
-    features channel-major (B, 4, N, N) in, U written once, masked."""
+    features of each unordered pair once, channel-major, in; U unfolded
+    by symmetry, masked."""
     tiles = part_pair.modeled_residency(cfg, params, x.shape[0])
     return part_pair.part_pair_embed(
-        jnp.moveaxis(pair_features(x), -1, 1), pair_keep(mask),
+        _pair_features_channel_major, x, mask,
         _fold_pair_layers(params["pair"]), block_b=tiles["block_b"],
         interpret=interpret)
 
@@ -390,19 +395,27 @@ def reference(params, cfg: ParticleTransformerConfig, x):
 
 
 def pairs_per_sample(cfg: ParticleTransformerConfig) -> int:
-    """Pairs the program's pair embedding evaluates per jet: all N x N
-    ordered pairs, the diagonal included."""
+    """Pairs the ``part`` path's pair kernel evaluates per jet: its
+    packed lanes, N^2/2 (8,192 at N = 128: the 8,128 unordered pairs
+    and 64 masked lanes)."""
+    return part_pair.padded_pairs(cfg.n_objects)
+
+
+def pairs_per_sample_xla(cfg: ParticleTransformerConfig) -> int:
+    """Pairs the ``part_xla`` path's pair embedding evaluates per jet:
+    all N x N ordered pairs, the diagonal included."""
     return cfg.n_objects * cfg.n_objects
 
 
-def flops_per_sample(cfg: ParticleTransformerConfig) -> int:
-    """Matmul FLOPs of one jet as the program computes it (the pair
-    embedding over :func:`pairs_per_sample` pairs)."""
+def flops_per_sample(cfg: ParticleTransformerConfig,
+                     pairs=pairs_per_sample) -> int:
+    """Matmul FLOPs of one jet as a path computes it: the pair embedding
+    over ``pairs(cfg)`` pairs, the ``part`` path's by default."""
     n, d, f, h = cfg.n_objects, cfg.d_model, cfg.ffn_dim, cfg.n_heads
     emb_w = (cfg.n_features, *cfg.embed_dims)
     embed = n * sum(2 * a * b for a, b in zip(emb_w[:-1], emb_w[1:]))
     pair_w = (N_PAIR_FEATURES, *cfg.pair_embed_dims)
-    pair = pairs_per_sample(cfg) * sum(
+    pair = pairs(cfg) * sum(
         2 * a * b for a, b in zip(pair_w[:-1], pair_w[1:]))
     block = n * 2 * (4 * d * d + 2 * d * f) + 2 * 2 * n * n * d
     cls = 2 * (2 * d * d + 2 * (n + 1) * d * d + 2 * d * f) \
@@ -413,12 +426,13 @@ def flops_per_sample(cfg: ParticleTransformerConfig) -> int:
 
 def hbm_bytes_per_sample(cfg: ParticleTransformerConfig) -> int:
     """HBM working set of one jet in the ``part`` step, whose pair MLP
-    runs in VMEM: the kernel's (5, N*N) input slab, U and the attention
-    logits, float32.  (Compiled for a v5e at the published widths, the
-    step's temporaries come to 1.2 MiB a jet at rung 1024; this gives
-    1.3.)"""
-    return 4 * part_pair.padded_pairs(cfg.n_objects) * (
-        part_pair.IN_ROWS + 2 * cfg.n_heads)
+    runs in VMEM: the kernel's packed (5, N^2/2) input slab and (H,
+    N^2/2) output, the unfolded U and the attention logits, float32.
+    (Compiled for a v5e at the published widths, the step's temporaries
+    come to 1.2 MiB a jet at rung 1024; this gives 1.4.)"""
+    n = cfg.n_objects
+    return 4 * (part_pair.padded_pairs(n) * (part_pair.IN_ROWS + cfg.n_heads)
+                + n * n * 2 * cfg.n_heads)
 
 
 def hbm_bytes_per_sample_xla(cfg: ParticleTransformerConfig) -> int:
@@ -431,17 +445,19 @@ def hbm_bytes_per_sample_xla(cfg: ParticleTransformerConfig) -> int:
     return 4 * n * n * (2 * max(cfg.pair_embed_dims) + cfg.n_heads)
 
 
-def _flops(cfg, batch):
-    return float(flops_per_sample(cfg) * batch)
+def _flops(cfg, batch, pairs=pairs_per_sample):
+    return float(flops_per_sample(cfg, pairs) * batch)
 
 
 def _hbm_traffic(cfg, batch, compute_bytes):
     """Least HBM traffic of one ``part`` step: the weights once, the
-    input in, the logits out, and the kernel's input slab and U each
-    written and read once."""
-    acts = (cfg.n_objects * cfg.n_features + cfg.n_targets
-            + 2 * part_pair.padded_pairs(cfg.n_objects)
-            * (part_pair.IN_ROWS + cfg.n_heads))
+    input in, the logits out, and the kernel's packed input slab, its
+    packed U and the unfolded U each written and read once."""
+    n = cfg.n_objects
+    acts = (n * cfg.n_features + cfg.n_targets
+            + 2 * part_pair.padded_pairs(n)
+            * (part_pair.IN_ROWS + cfg.n_heads)
+            + 2 * n * n * cfg.n_heads)
     return float(weight_count(cfg) * compute_bytes + acts * batch * 4)
 
 
@@ -471,7 +487,7 @@ def weight_count(cfg: ParticleTransformerConfig) -> int:
             + d + 2 * d + d * cfg.n_targets + cfg.n_targets)
 
 
-#: Fields the two ParT paths share: the model, its reference, work and
+#: Fields the two ParT paths share: the model, its reference, an
 #: HBM-sized ladder, and weights passed as a jit argument (8.6 MB of
 #: them: as constants they would fill every rung's program and its
 #: compile-cache entry).
@@ -485,8 +501,6 @@ _PART_PATH = dict(
     # TPU v5 lite at the published widths, ~1e-6 on the CPU
     tolerance=2e-4,
     memory_budget_bytes=HBM_BUDGET_BYTES,
-    pairs_per_sample=pairs_per_sample,
-    flops_model=_flops,
     params_as_args=True,
 )
 
@@ -498,6 +512,8 @@ _PART_PATH = dict(
     per_sample_bytes=lambda cfg, params: hbm_bytes_per_sample(cfg),
     bytes_model=_hbm_traffic,
     residency_model=part_pair.modeled_residency,
+    pairs_per_sample=pairs_per_sample,
+    flops_model=_flops,
     description="Particle Transformer, pair embedding in one Pallas "
                 "kernel (part_pair_embed)",
     **_PART_PATH,
@@ -514,6 +530,8 @@ def forward_part(params, cfg, x, *, interpret: bool = False):
     fallback=None,                    # terminal: plain XLA
     per_sample_bytes=lambda cfg, params: hbm_bytes_per_sample_xla(cfg),
     bytes_model=_hbm_traffic_xla,
+    pairs_per_sample=pairs_per_sample_xla,
+    flops_model=functools.partial(_flops, pairs=pairs_per_sample_xla),
     description="Particle Transformer (XLA), activations in HBM",
     **_PART_PATH,
 )

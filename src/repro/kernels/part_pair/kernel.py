@@ -5,14 +5,16 @@ each particle pair through Linear layers of widths 64, 64, 64 (exact
 GELU after each) and 8, giving one attention bias per head.  As XLA
 fusions, each layer reads and writes a ``(B, N, N, 64)`` float32 tensor
 in HBM.  This kernel runs the whole MLP per block of pairs in VMEM and
-writes U once.
+writes its output once.
 
 Channel-major layout
 --------------------
 Pairs run along the lanes and channels down the sublanes: a jet's input
-is one ``(5, N*N)`` slab (rows: the 4 pair features, then the pair's
-keep flag), every activation a ``(64, lanes)`` array and the output an
-``(8, lanes)`` slab that is already U's ``(heads, N*N)`` layout.  Each
+is one ``(5, P)`` slab (rows: the 4 pair features, then the pair's keep
+flag), every activation a ``(64, lanes)`` array and the output an
+``(8, P)`` slab, one bias per head and pair.  The wrapper (``ops``)
+gives each unordered pair of a jet one of its P = N^2/2 lanes and
+unfolds the output into the symmetric U.  Each
 64-wide layer is one MXU product ``W^T @ h`` whose 128-lane operand
 tiles hold 128 pairs, so the layers fill the MXU's lanes without
 packing or block-diagonal weights.  The first layer, 4 wide, is four

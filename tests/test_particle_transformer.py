@@ -165,7 +165,7 @@ def test_stable_m2_is_the_massless_invariant_mass():
 
 
 @pytest.mark.parametrize("path,model_bytes,per_jet", [
-    ("part", pt.hbm_bytes_per_sample, 1_376_256),        # 1.3 MiB
+    ("part", pt.hbm_bytes_per_sample, 1_474_560),        # 1.4 MiB
     ("part_xla", pt.hbm_bytes_per_sample_xla, 8_912_896),  # 8.5 MiB
 ])
 def test_ladder_respects_its_hbm_model(path, model_bytes, per_jet):
@@ -192,14 +192,18 @@ def test_ladder_respects_its_hbm_model(path, model_bytes, per_jet):
     assert top % 8 == 0 and top < 1024
 
 
-def test_pairs_kernel_counts_the_pairs_each_plan_evaluates(model):
+@pytest.mark.parametrize("path,pairs_per_jet", [
+    ("part", SMALL.n_objects ** 2 // 2),      # each unordered pair once
+    ("part_xla", SMALL.n_objects ** 2),       # every ordered pair
+])
+def test_pairs_kernel_counts_the_pairs_each_plan_evaluates(model, path,
+                                                           pairs_per_jet):
     cfg, params = model
-    engine = ServingEngine(params, cfg, forward="part", mesh=None,
+    engine = ServingEngine(params, cfg, forward=path, mesh=None,
                            bucket_sizes=[8, 16])
     engine.infer(_jets(5))
     engine.infer(_jets(12))
-    n2 = cfg.n_objects ** 2
-    assert engine.metrics.counter("pairs_kernel") == (8 + 16) * n2
+    assert engine.metrics.counter("pairs_kernel") == (8 + 16) * pairs_per_jet
     assert engine.metrics.counter("rows_kernel") == 8 + 16
 
 
@@ -213,12 +217,21 @@ def test_part_path_refuses_a_jedinet_config():
 
 def test_published_widths_count():
     """2,143,896 learned numbers; the program's FLOPs exceed the
-    model's 667,225,600 by the redundant half of the pair embedding."""
+    model's 667,225,600 by the pairs a path evaluates beyond the 8,128
+    unordered ones: on ``part`` the 64 masked lanes of its packing, on
+    ``part_xla`` the redundant half of all ordered pairs."""
     cfg = pt.ParticleTransformerConfig()
     params = jax.eval_shape(lambda k: pt.init_params(k, cfg),
                             jax.random.PRNGKey(0))
     assert sum(int(np.prod(v.shape)) for v in jax.tree.leaves(params)) \
         == pt.weight_count(cfg) == 2_143_896
     pair_layer_flops = 2 * (4 * 64 + 64 * 64 + 64 * 64 + 64 * 8)
+    unordered = 128 * 127 // 2
+    assert pt.pairs_per_sample(cfg) == 8192
     assert pt.flops_per_sample(cfg) == 667_225_600 + pair_layer_flops * (
-        128 * 128 - 128 * 127 // 2)
+        8192 - unordered)
+    assert pt.flops_per_sample(cfg, pt.pairs_per_sample_xla) \
+        == 667_225_600 + pair_layer_flops * (128 * 128 - unordered)
+    assert paths.get("part").flops_for(cfg, 2) == 2 * pt.flops_per_sample(cfg)
+    assert paths.get("part_xla").flops_for(cfg, 2) \
+        == 2 * pt.flops_per_sample(cfg, pt.pairs_per_sample_xla)

@@ -118,8 +118,10 @@ def test_part_step_names_scopes_and_memory_for_v5e(one_chip, path, bucket,
     at the published widths; on ``part`` its one Mosaic call is the pair
     embedding's ``part_pair_embed`` kernel, on ``part_xla`` it has none;
     the pair embedding and two attention stacks carry their named scopes
-    in the compiled program's op metadata, and the compiler's
-    temporaries stay within the path's HBM model."""
+    in the compiled program's op metadata (on ``part`` the pair
+    embedding's scope holds the packing of the kernel's input and the
+    unfold of its output), and the compiler's temporaries stay within
+    the path's HBM model."""
     from repro.core import particle_transformer as pt
     from repro.serving.engine import TriggerWorkload
 
@@ -140,6 +142,8 @@ def test_part_step_names_scopes_and_memory_for_v5e(one_chip, path, bucket,
     for scope in ("part.pair_embed", "part.particle_blocks",
                   "part.class_blocks"):
         assert f"/{scope}/" in hlo, scope
+    for stage in ("pack", "unfold"):
+        assert (f"/part.pair_embed/{stage}/" in hlo) == bool(kernels), stage
     # the weights are an argument of the step, not constants of it
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 4 * pt.weight_count(cfg)

@@ -217,7 +217,8 @@ def test_cli_serves_part_jetclass(capsys):
     """ParT at its published widths through the same CLI and engine: a
     short stream of 8-jet ticks on the ``part`` path (its pair embedding
     the ``part_pair_embed`` kernel, interpreted here), with the pairs the
-    step evaluates counted (128 x 128 per jet)."""
+    step evaluates counted (each unordered pair once: 128 x 128 / 2 lanes
+    per jet)."""
     rc = trigger_serve.main(["--arch", "part-jetclass", "--forward", "part",
                              "--batch", "8", "--batches", "3",
                              "--warmup", "1", "--health"])
@@ -226,7 +227,7 @@ def test_cli_serves_part_jetclass(capsys):
     assert "[trigger_serve] arch=part-jetclass" in out
     assert "forward=part n_objects=128" in out
     assert "path=part (chain part>part_xla)" in out
-    assert f"pairs_kernel={2 * 8 * 128 * 128}" in out
+    assert f"pairs_kernel={2 * 8 * 128 * 128 // 2}" in out
     assert "level=none" in out
 
 
@@ -236,7 +237,7 @@ def test_cli_list_paths_resolves_the_part_policy(capsys):
     out = capsys.readouterr().out
     policy = out.split("bucket policy", 1)[1]
     assert "part paths" in policy
-    for path, per_jet in (("part", "1376256"), ("part_xla", "8912896")):
+    for path, per_jet in (("part", "1474560"), ("part_xla", "8912896")):
         row = next(ln for ln in policy.splitlines()
                    if ln.startswith(path + " "))
         assert row.split()[1] == per_jet        # HBM bytes per jet
